@@ -97,7 +97,7 @@ def record_demo_comm() -> dict:
     if len(devs) >= 2:
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from ..base.jax_compat import shard_map
+        from jax import shard_map
 
         n = min(len(devs), 8)
         wire_data = (rs.randn(n, 17, 23) * 3).astype(np.float32)

@@ -260,15 +260,15 @@ def _var_bytes(var) -> int:
 
 def _sub_jaxprs(eqn):
     """Every ClosedJaxpr/Jaxpr reachable through one eqn's params."""
-    import jax
+    from jax.extend import core as jex_core
 
     out = []
     for v in eqn.params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for item in vs:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 out.append(item.jaxpr)
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex_core.Jaxpr):
                 out.append(item)
     return out
 
@@ -497,7 +497,7 @@ def _while_trip_count(eqn) -> int:
     single-iteration lower bound — trip counts are data)."""
     import math
 
-    import jax
+    from jax.extend import core as jex_core
 
     from ..base.flags import get_flag
 
@@ -512,7 +512,7 @@ def _while_trip_count(eqn) -> int:
         bn = int(eqn.params.get("body_nconsts", 0))
     except (KeyError, AttributeError, TypeError):
         return fallback
-    Literal = jax.core.Literal
+    Literal = jex_core.Literal
     carry_outer = list(eqn.invars)[cn + bn:]
     cond_const_outer = list(eqn.invars)[:cn]
     cond_const_vars = list(cond.invars)[:cn]
@@ -595,7 +595,7 @@ def _walk_jaxpr(jaxpr, axis_sizes: Optional[Dict[str, int]] = None,
     it through ``sharding_constraint`` equations and elementwise chains
     (minimum across non-scalar operands — conservative when sharded and
     replicated values mix)."""
-    import jax
+    from jax.extend import core as jex_core
 
     rep = CostReport(n_eqns=len(jaxpr.eqns))
 
@@ -603,18 +603,18 @@ def _walk_jaxpr(jaxpr, axis_sizes: Optional[Dict[str, int]] = None,
     last_use: Dict = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if isinstance(v, jax.core.Var):
+            if isinstance(v, jex_core.Var):
                 last_use[v] = i
     n = len(jaxpr.eqns)
     for v in jaxpr.outvars:
-        if isinstance(v, jax.core.Var):
+        if isinstance(v, jex_core.Var):
             last_use[v] = n  # live to the end
 
     # per-var residency divisors (see docstring)
     divs: Dict = {}
     if arg_divisors:
         for v, d in zip(jaxpr.invars, arg_divisors):
-            if isinstance(v, jax.core.Var) and d and d > 1.0:
+            if isinstance(v, jex_core.Var) and d and d > 1.0:
                 divs[v] = float(d)
 
     def _resident(v) -> float:
@@ -709,12 +709,12 @@ def _walk_jaxpr(jaxpr, axis_sizes: Optional[Dict[str, int]] = None,
             out_div = None  # container results: no propagation
         else:
             in_divs = [divs.get(v, 1.0) for v in eqn.invars
-                       if isinstance(v, jax.core.Var)
+                       if isinstance(v, jex_core.Var)
                        and _aval_numel(getattr(v, "aval", None)) > 1]
             out_div = min(in_divs) if in_divs else None
         if out_div is not None and out_div > 1.0:
             for v in eqn.outvars:
-                if isinstance(v, jax.core.Var) and \
+                if isinstance(v, jex_core.Var) and \
                         _aval_numel(getattr(v, "aval", None)) > 1:
                     divs[v] = out_div
 
@@ -724,14 +724,14 @@ def _walk_jaxpr(jaxpr, axis_sizes: Optional[Dict[str, int]] = None,
             rep.largest_intermediate_bytes = materialized
             rep.largest_intermediate_prim = pname
         for v in eqn.outvars:
-            if isinstance(v, jax.core.Var) and v in last_use and v not in live:
+            if isinstance(v, jex_core.Var) and v in last_use and v not in live:
                 b = 0 if _is_fused_expansion(eqn) else _resident(v)
                 live[v] = b
                 live_bytes += b
         peak = max(peak, live_bytes + sub_peak_extra)
         freed = set()
         for v in eqn.invars:
-            if (isinstance(v, jax.core.Var) and v not in freed
+            if (isinstance(v, jex_core.Var) and v not in freed
                     and last_use.get(v) == i):
                 freed.add(v)
                 live_bytes -= live.pop(v, 0)

@@ -105,7 +105,8 @@ _ANALYZER = "jaxpr"
 
 # primitives that escape to the host from inside a compiled program
 _CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
-                   "callback", "host_callback_call", "outside_call"}
+                   "debug_print", "callback", "host_callback_call",
+                   "outside_call"}
 _F64_DTYPES = {"float64", "complex128"}
 _I64_DTYPES = {"int64", "uint64"}
 
@@ -113,7 +114,7 @@ _I64_DTYPES = {"int64", "uint64"}
 def _iter_jaxprs(jaxpr):
     """Yield ``jaxpr`` and every sub-jaxpr reachable through eqn params
     (pjit/scan/while/cond bodies)."""
-    import jax
+    from jax.extend import core as jex_core
 
     seen = []
     stack = [jaxpr]
@@ -124,9 +125,9 @@ def _iter_jaxprs(jaxpr):
             for v in eqn.params.values():
                 vs = v if isinstance(v, (list, tuple)) else (v,)
                 for item in vs:
-                    if isinstance(item, jax.core.ClosedJaxpr):
+                    if isinstance(item, jex_core.ClosedJaxpr):
                         stack.append(item.jaxpr)
-                    elif isinstance(item, jax.core.Jaxpr):
+                    elif isinstance(item, jex_core.Jaxpr):
                         stack.append(item)
     return seen
 
@@ -150,7 +151,7 @@ def audit_jaxpr(closed_jaxpr, *, location: str = "",
     outvars are laid out ``[user outputs..., new cell values..., guard
     predicates...]`` with ``n_user_outs`` user leaves (None disables the
     segment-aware checks JX303-outputs/JX304)."""
-    import jax
+    from jax.extend import core as jex_core
 
     findings: List[Finding] = []
 
@@ -208,7 +209,7 @@ def audit_jaxpr(closed_jaxpr, *, location: str = "",
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if isinstance(v, jax.core.Var):
+            if isinstance(v, jex_core.Var):
                 used.add(v)
 
     cell_invars = list(jaxpr.invars[:n_cells])
@@ -219,7 +220,7 @@ def audit_jaxpr(closed_jaxpr, *, location: str = "",
 
     # JX303: user outputs that are trace-time constants
     for i, v in enumerate(user_outs):
-        if isinstance(v, jax.core.Literal) or v in constvars:
+        if isinstance(v, jex_core.Literal) or v in constvars:
             add("JX303", "warning",
                 f"output #{i} is a trace-time constant — it was baked in "
                 "during tracing (e.g. a live cell Tensor returned after its "
@@ -240,9 +241,9 @@ def audit_jaxpr(closed_jaxpr, *, location: str = "",
     # JX304: user-visible outputs aliasing donated cell buffers
     if donated:
         donated_vars = set(cell_invars)
-        cell_out_vars = {v for v in cell_outs if isinstance(v, jax.core.Var)}
+        cell_out_vars = {v for v in cell_outs if isinstance(v, jex_core.Var)}
         for i, v in enumerate(user_outs):
-            if not isinstance(v, jax.core.Var):
+            if not isinstance(v, jex_core.Var):
                 continue
             if v in donated_vars or v in cell_out_vars:
                 add("JX304", "error",

@@ -67,13 +67,10 @@ def fingerprint() -> dict:
     import jax
     import jaxlib
 
-    try:
-        devices = jax.devices()
-        platform = devices[0].platform
-        device_kind = getattr(devices[0], "device_kind", platform)
-        n_devices = len(devices)
-    except Exception:  # backend init failure: still fingerprintable
-        platform, device_kind, n_devices = "unknown", "unknown", 0
+    devices = jax.devices()
+    platform = devices[0].platform
+    device_kind = devices[0].device_kind
+    n_devices = len(devices)
     flags = {}
     for name in _FINGERPRINT_FLAGS:
         try:

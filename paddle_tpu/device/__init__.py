@@ -23,12 +23,20 @@ def _resolve_device(device):
     else:
         kind, idx = name, 0
     if kind in ("tpu", "gpu", "cuda", "xpu"):
-        accel = [d for d in jax.devices() if d.platform != "cpu"]
-        pool = accel or jax.devices()
-        return pool[idx % len(pool)]
-    if kind == "cpu":
-        return jax.devices("cpu")[idx % len(jax.devices("cpu"))]
-    return jax.devices()[idx % len(jax.devices())]
+        pool = [d for d in jax.devices() if d.platform != "cpu"]
+        if not pool:
+            raise RuntimeError(
+                f"device {device!r} requested but jax sees no accelerator "
+                f"(jax.devices() = {jax.devices()})")
+    elif kind == "cpu":
+        pool = jax.devices("cpu")
+    else:
+        pool = jax.devices()
+    if not 0 <= idx < len(pool):
+        raise ValueError(
+            f"device {device!r}: index {idx} out of range, "
+            f"{len(pool)} such device(s) visible")
+    return pool[idx]
 
 
 def get_device_object():
@@ -64,10 +72,7 @@ def is_compiled_with_xpu():
 
 
 def is_compiled_with_tpu():
-    try:
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:
-        return False
+    return any(d.platform != "cpu" for d in jax.devices())
 
 
 def device_count():

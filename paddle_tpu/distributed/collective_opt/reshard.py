@@ -155,7 +155,7 @@ def _route_program(route: ReshardRoute, jmesh, src_spec, dst_spec,
     import jax
     from jax import lax
 
-    from ...base.jax_compat import shard_map
+    from jax import shard_map
 
     try:
         key = (route.kind, route.axis, route.src_dim, route.dst_dim,

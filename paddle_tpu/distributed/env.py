@@ -96,7 +96,7 @@ class ParallelEnv:
         arr = np.array(devices).reshape(shape)
         self.mesh = Mesh(arr, HYBRID_AXES)
         self.axis_degrees = degrees
-        self.device_kind = devices[0].platform
+        self.device_kind = devices[0].device_kind
         return self.mesh
 
 

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...base import jax_compat
 from ...core.dispatch import primitive
 from .. import env as env_mod
 
@@ -113,12 +112,12 @@ def _cp_call(body_builder, q, k, v, axis: str, extra_check=None):
         from .mpu import _manual_axes
 
         manual = _manual_axes()
-        use_mesh = jax_compat.get_abstract_mesh() if manual else mesh
+        use_mesh = jax.sharding.get_abstract_mesh() if manual else mesh
         dp = mesh.shape.get("dp", 1)
         batch_axis = ("dp" if (dp > 1 and qv.shape[0] % dp == 0
                                and "dp" not in manual) else None)
         spec = P(batch_axis, axis, None, None)
-        shmap = jax_compat.shard_map(
+        shmap = jax.shard_map(
             body_builder,
             mesh=use_mesh,
             in_specs=(spec, spec, spec),

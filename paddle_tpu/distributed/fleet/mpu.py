@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...base import jax_compat
 from ...core.dispatch import primitive
 from ...core.tensor import Tensor
 from ...nn import functional as F
@@ -62,7 +61,7 @@ def _manual_axes() -> frozenset:
     """Axes the enclosing shard_map (if any) already made Manual — a
     sharding constraint inside that region must not mention them (the
     operand is already per-shard along them)."""
-    ctx = jax_compat.get_abstract_mesh()
+    ctx = jax.sharding.get_abstract_mesh()
     if getattr(ctx, "axis_names", None):
         from jax.sharding import AxisType
 

@@ -41,8 +41,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ...base import jax_compat
 from ...core.dispatch import primitive
 from ...core.tensor import Tensor
 from ...nn.layer.layers import Layer
@@ -278,7 +276,7 @@ def pipeline_spmd(
         # parallel layers inside the pipelined template keep their sharding
         # semantics — pp×mp composes in one program
         manual = {axis} | ({batch_axis} if batch_axis else set())
-        shmap = jax_compat.shard_map(
+        shmap = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(x_spec,) + rng_specs + leaf_specs,
@@ -578,11 +576,11 @@ def _pipeline_1f1b(apply_layer, stacked_leaves, x, *, p, m, mesh, axis,
             bwd_body = bwd_body_zb
 
         manual = {axis} | ({batch_axis} if batch_axis else set())
-        fwd_shmap = jax_compat.shard_map(
+        fwd_shmap = jax.shard_map(
             fwd_body, mesh=mesh,
             in_specs=(x_spec, P()) + leaf_specs, out_specs=x_spec,
             axis_names=frozenset(manual), check_vma=False)
-        bwd_shmap = jax_compat.shard_map(
+        bwd_shmap = jax.shard_map(
             bwd_body, mesh=mesh,
             in_specs=(x_spec, x_spec, P()) + leaf_specs,
             out_specs=(x_spec,) + leaf_specs,
@@ -774,11 +772,11 @@ def _pipeline_vpp_1f1b(apply_layer, stacked_leaves, x, *, p, v, m, mesh,
             return (dxout, *gout)
 
         manual = {axis} | ({batch_axis} if batch_axis else set())
-        fwd_shmap = jax_compat.shard_map(
+        fwd_shmap = jax.shard_map(
             fwd_body, mesh=mesh,
             in_specs=(x_spec, P()) + leaf_specs, out_specs=x_spec,
             axis_names=frozenset(manual), check_vma=False)
-        bwd_shmap = jax_compat.shard_map(
+        bwd_shmap = jax.shard_map(
             bwd_body, mesh=mesh,
             in_specs=(x_spec, x_spec, P()) + leaf_specs,
             out_specs=(x_spec,) + leaf_specs,

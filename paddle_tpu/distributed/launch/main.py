@@ -41,7 +41,11 @@ def _parse_args(argv=None):
     p.add_argument("--log_dir", default=None, help="per-rank log directory")
     p.add_argument("--max_restarts", type=int, default=0,
                    help="relaunch failed workers up to N times")
-    p.add_argument("--devices", default=None, help="visible device selection")
+    p.add_argument("--devices", default=None,
+                   help="accepted for reference-CLI compatibility; has NO "
+                        "effect on a TPU host, where one process drives every "
+                        "local chip and the runtime reads no device mask from "
+                        "the launcher")
     p.add_argument("--elastic_level", type=int, default=0,
                    help="0: off; >=1: run the Master KV rendezvous + elastic "
                         "manager; worker relaunch is driven by its decisions")
@@ -70,8 +74,6 @@ def _spawn(args, local_rank: int, generation: int = 0):
         PADDLE_RESTART_GEN=str(generation),
         PADDLE_JOB_ID=str(getattr(args, "job_id", "default")),
     )
-    if args.devices:
-        env["JAX_VISIBLE_DEVICES"] = args.devices
     cmd = [sys.executable, args.training_script] + list(args.training_script_args)
     stdout = stderr = None
     if args.log_dir:

@@ -17,9 +17,9 @@ import threading
 from typing import Optional, Sequence
 
 import jax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..base.jax_compat import shard_map as _shard_map
 from . import env as env_mod
 
 _tls = threading.local()

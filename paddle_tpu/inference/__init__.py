@@ -101,10 +101,7 @@ class Config:
     def enable_tpu(self):
         import jax
 
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:
-            platform = "unknown"
+        platform = jax.devices()[0].platform
         if platform != "tpu":
             _warn(f"enable_tpu: active backend is '{platform}', not TPU; "
                   "execution stays on that backend")
@@ -229,10 +226,7 @@ class _BatchProgram:
         # buffers are dead after the call — donate them so XLA reuses the
         # staging memory across steps. Params are NOT donated (shared state).
         n_in = len(layer._meta.get("input_shapes") or []) or 1
-        try:
-            backend = jax.devices()[0].platform
-        except Exception:
-            backend = "cpu"
+        backend = jax.devices()[0].platform
         donate = tuple(range(1, 1 + n_in)) if backend == "tpu" else ()
         self._donate = donate
         self._jitted = jax.jit(_fwd, donate_argnums=donate)

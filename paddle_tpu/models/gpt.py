@@ -281,16 +281,30 @@ class GPTPretrainingCriterion(Layer):
         # pass the raw token ids as labels; the shift happens here so the
         # objective is a real causal-LM loss, not a copy task.
         v = logits.shape[-1]
-        logits = logits[:, :-1, :]
-        labels = labels[:, 1:]
         flat = manipulation.reshape(logits, [-1, v])
-        flat_labels = manipulation.reshape(labels, [-1])
+        flat_labels = manipulation.reshape(shift_labels(labels), [-1])
         if self._parallel_ce is not None:
-            loss = self._parallel_ce(flat, flat_labels)
             from ..ops import math as ops_math
 
-            return ops_math.mean(loss)
+            loss = self._parallel_ce(flat, flat_labels)  # 0 where ignored
+            b, s = labels.shape
+            return ops_math.sum(loss) / float(b * (s - 1))
         return F.cross_entropy(flat, flat_labels, reduction="mean")
+
+
+IGNORE_INDEX = -100  # F.cross_entropy's default ignore_index
+
+
+def shift_labels(labels):
+    """[B, S] token ids -> [B, S] next-token targets, the last position
+    (which has no next token) set to ``IGNORE_INDEX``. The logits keep their
+    full [B, S, V] shape this way: dropping their last position instead
+    leaves S-1 rows per sequence, off the TPU's sublane tiling, and
+    flattening that is a relayout of the largest tensor in the step, one
+    XLA's TPU compiler took minutes over (three compiles of 167-285 s at
+    8 x 1023 x 50304 on a v5e)."""
+    pad = creation.full([labels.shape[0], 1], IGNORE_INDEX, dtype=labels.dtype)
+    return manipulation.concat([labels[:, 1:], pad], axis=1)
 
 
 # ---------------------------------------------------------------- presets

@@ -27,6 +27,7 @@ from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.layer.layers import Layer
 from ..ops import creation, manipulation
+from .gpt import shift_labels
 
 
 @dataclass
@@ -260,10 +261,9 @@ class LlamaPretrainingCriterion(Layer):
         self.config = config
 
     def forward(self, logits, labels):
-        shifted = logits[:, :-1, :]
-        targets = labels[:, 1:]
-        flat = manipulation.reshape(shifted, [-1, self.config.vocab_size])
-        return F.cross_entropy(flat, manipulation.reshape(targets, [-1]))
+        flat = manipulation.reshape(logits, [-1, self.config.vocab_size])
+        return F.cross_entropy(
+            flat, manipulation.reshape(shift_labels(labels), [-1]))
 
 
 def llama_tiny(**overrides) -> LlamaConfig:

@@ -2,9 +2,12 @@
 flash_attention.py — flash_attention :195, scaled_dot_product_attention :976).
 
 TPU-native: the fused path is a Pallas flash-attention kernel
-(paddle_tpu/ops/pallas/flash_attention.py); off-TPU or when disabled, an XLA
-composition (which XLA still fuses well) is used. Layout follows paddle:
-[batch, seqlen, num_heads, head_dim].
+(paddle_tpu/ops/pallas/flash_attention.py), taken whenever
+``ops.pallas.enabled()`` says so — a lowering error there propagates, it
+never downgrades the step. Elsewhere an XLA composition (which XLA still
+fuses well) is used. The primitive's name says which ran: ``sdpa_flash`` /
+``flash_attention`` against ``sdpa_xla`` / ``flash_attention_xla``. Layout
+follows paddle: [batch, seqlen, num_heads, head_dim].
 """
 from __future__ import annotations
 
@@ -53,6 +56,7 @@ def flash_attention(
 ):
     """paddle.nn.functional.flash_attention.flash_attention parity."""
     from ...base import global_state
+    from ...ops import pallas
     from ...ops.pallas import flash_attention as pallas_fa
 
     scale = 1.0 / math.sqrt(unwrap(query).shape[-1])
@@ -78,7 +82,7 @@ def flash_attention(
         out, probs = primitive("flash_attention_xla", fn, [query, key, value])
         return out, probs
 
-    if pallas_fa.available():
+    if pallas.enabled():
         drop_eff = dropout if training else 0.0
         seed = _seed_from_key(dkey) if drop_eff > 0.0 else None
         out = primitive(
@@ -105,10 +109,11 @@ def scaled_dot_product_attention(
     """paddle.nn.functional.scaled_dot_product_attention parity
     (q/k/v: [B, S, H, D]; attn_mask broadcastable to [B, H, S, T])."""
     from ...base import global_state
+    from ...ops import pallas
     from ...ops.pallas import flash_attention as pallas_fa
 
     scale = 1.0 / math.sqrt(unwrap(query).shape[-1])
-    if attn_mask is None and pallas_fa.available():
+    if attn_mask is None and pallas.enabled():
         drop_eff = dropout_p if training else 0.0
         seed = (_seed_from_key(global_state.default_generator.split())
                 if drop_eff > 0.0 else None)

@@ -100,7 +100,7 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
     path is the Pallas flashmask kernel (ops/pallas/flashmask.py); fallback
     composes the dense mask in XLA.
     """
-    from ...ops.pallas import flash_attention as pallas_fa
+    from ...ops import pallas
 
     scale = 1.0 / math.sqrt(unwrap(query).shape[-1])
     if startend_row_indices is None:
@@ -112,7 +112,7 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
 
     idx = jnp.asarray(unwrap(startend_row_indices))
 
-    if pallas_fa.available() and dropout == 0.0:
+    if pallas.enabled() and dropout == 0.0:
         from ...ops.pallas.flashmask import flashmask_value
 
         return primitive(

@@ -49,10 +49,10 @@ def jax_rsqrt(v):
 
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     """RMSNorm (TPU fusion tier; Pallas kernel when enabled)."""
-    from ...ops.pallas import rms_norm as pallas_rms
+    from ...ops import pallas
 
-    if pallas_rms.available() and weight is not None:
-        return pallas_rms.rms_norm(x, weight, epsilon)
+    if pallas.enabled() and weight is not None:
+        return pallas.rms_norm.rms_norm(x, weight, epsilon)
 
     def fn(v, *w):
         ms = jnp.mean(jnp.square(v), axis=-1, keepdims=True)

@@ -1,40 +1,34 @@
 """Pallas TPU kernels for the fusion tier (reference analog:
-paddle/phi/kernels/fusion/*.cu). Each module exposes ``available()`` plus the
-op; callers fall back to XLA compositions when unavailable (CPU tests).
+paddle/phi/kernels/fusion/*.cu).
 
-``self_test(name, probe)`` is the shared once-per-process hardware probe:
-kernels gate on a tiny real-device run so a Mosaic lowering/toolchain
-failure downgrades to the XLA path instead of killing the training step.
+``enabled()`` is the one gate the functional layer asks. It answers from
+what the process can observe — the flag, the platform, the mesh — never
+from a caught exception: where it says yes the kernel is used, and a Mosaic
+lowering or compile error propagates to the caller. Interpret mode is
+reachable only through the explicit ``interpret=True`` arguments the tests
+pass.
 """
-from typing import Callable, Dict
-
-_SELF_TESTS: Dict[str, bool] = {}
-
-
-def self_test(name: str, probe: Callable[[], None]) -> bool:
-    """Run ``probe`` once on the real device; cache pass/fail per process."""
-    if name in _SELF_TESTS:
-        return _SELF_TESTS[name]
-    try:
-        probe()
-        _SELF_TESTS[name] = True
-    except Exception as e:  # pragma: no cover - hardware/toolchain specific
-        from ...base.log import get_logger
-
-        get_logger().warning(
-            "pallas %s self-test failed (%s); falling back to XLA",
-            name, str(e).split("\n")[0])
-        _SELF_TESTS[name] = False
-    return _SELF_TESTS[name]
+from ...base.flags import get_flag
 
 
 def on_tpu() -> bool:
     import jax
 
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
+    return jax.devices()[0].platform == "tpu"
+
+
+def enabled() -> bool:
+    """True when fused ops should take their Pallas kernel: the flag is on,
+    the default backend is a TPU, and the program is single-device. jax
+    refuses to auto-partition a Mosaic custom call ("wrap the call in a
+    shard_map"), so under a multi-device fleet mesh the XLA composition,
+    which GSPMD can partition, is the path that runs."""
+    if not (get_flag("use_pallas_kernels") and on_tpu()):
         return False
+    from ...distributed import env
+
+    mesh = env.instance().mesh
+    return mesh is None or mesh.size == 1
 
 
 from . import flash_attention, flashmask, rms_norm  # noqa: F401,E402

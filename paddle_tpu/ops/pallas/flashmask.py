@@ -7,12 +7,17 @@ j, `startend_row_indices` gives the query-row interval(s) that are masked.
 This covers causal-document masks, sliding windows, shared prefixes and
 arbitrary block layouts at O(S) mask storage instead of O(S²).
 
-Kernels mirror ops/pallas/flash_attention.py (streamed K/V blocks over a
-(batch, heads, row-blocks, col-blocks) grid, VMEM scratch accumulators,
-online-softmax forward saving lse; two-pass recompute backward) with the
-interval mask applied per tile: the (block_k × ncol) start/end slab loads
-as a VMEM tile and the mask is an elementwise compare — no O(S²) mask
-tensor ever exists in HBM, and K/V never load whole-sequence.
+Kernels mirror ops/pallas/flash_attention.py and share its helpers:
+heads-major [B, H, S, D] operands (the block layout Mosaic accepts for any
+H), streamed K/V blocks over a (batch, heads, row-blocks, col-blocks) grid,
+VMEM scratch accumulators, online-softmax forward saving lse as a
+[B, H, 1, S] row, and a two-pass recompute backward whose dk/dv pass works
+on transposed tiles. The interval bounds reach each pass in the
+orientation its tiles need: the forward and dq passes read
+[B, Hm, ncol, Sk] (one lane-major row of bounds per column kind), the
+dk/dv pass reads [B, Hm, Sk, ncol] (one column per kind). The mask is an
+elementwise compare per tile — no O(S²) mask tensor ever exists in HBM,
+and K/V never load whole-sequence.
 
 Index layouts (matching the reference contract):
 - causal, last dim 1: [LTS]            — rows >= LTS[j] masked (plus causal)
@@ -26,27 +31,33 @@ import functools
 import jax
 import jax.numpy as jnp
 
-NEG_INF = -1e30
+from .flash_attention import (
+    _NN,
+    _NT,
+    NEG_INF,
+    _block,
+    _block_spec,
+    _col_to_row,
+    _dot,
+    _hm,
+    _positions,
+    _qk_specs,
+    _row_to_col,
+    _tile_live,
+)
 
 
-def _tile_mask(idx_blk, q_pos, causal, ncol):
-    """Disallowed-mask for one (block_q, block_k) tile from the column
-    intervals idx_blk [block_k, ncol]."""
+def _disallowed(bounds, q_pos, k_pos, causal):
+    """Disallowed-mask for one score tile. ``bounds`` holds the tile's
+    interval bounds, one array per index column, each broadcastable against
+    the tile along the key axis."""
     if causal:
-        if ncol == 1:
-            lts = idx_blk[:, 0][None, :]
-            masked = q_pos >= lts
-        else:
-            lts = idx_blk[:, 0][None, :]
-            lte = idx_blk[:, 1][None, :]
-            masked = (q_pos >= lts) & (q_pos < lte)
-    else:
-        lts = idx_blk[:, 0][None, :]
-        lte = idx_blk[:, 1][None, :]
-        uts = idx_blk[:, 2][None, :]
-        ute = idx_blk[:, 3][None, :]
-        masked = ((q_pos >= lts) & (q_pos < lte)) | ((q_pos >= uts) & (q_pos < ute))
-    return masked
+        masked = q_pos >= bounds[0]
+        if len(bounds) > 1:
+            masked = masked & (q_pos < bounds[1])
+        return masked | (q_pos < k_pos)
+    lts, lte, uts, ute = bounds
+    return ((q_pos >= lts) & (q_pos < lte)) | ((q_pos >= uts) & (q_pos < ute))
 
 
 def _fm_fwd_kernel(q_ref, k_ref, v_ref, idx_ref, o_ref, lse_ref,
@@ -62,42 +73,33 @@ def _fm_fwd_kernel(q_ref, k_ref, v_ref, idx_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    @pl.when((ik * block_k <= iq * block_q + block_q - 1) if causal else (ik >= 0))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
         idx = idx_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        disallowed = _tile_mask(idx, q_rows, causal, ncol)
-        if causal:
-            disallowed = disallowed | (q_rows < k_pos)
-        s = jnp.where(disallowed, NEG_INF, s)
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        q_pos, k_pos = _positions(iq, ik, block_q, block_k, 0, 0)
+        disallowed = _disallowed([idx[j:j + 1, :] for j in range(ncol)],
+                                 q_pos, k_pos, causal)
+        s = jnp.where(disallowed, NEG_INF, _dot(q, k, _NT) * scale)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # fully-masked rows: m stays NEG_INF, exp(NEG_INF - NEG_INF)=1 would
         # poison l; zero those columns explicitly
-        p = jnp.where(disallowed, 0.0, p)
+        p = jnp.where(disallowed, 0.0, jnp.exp(s - m_new))
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
-        m_scr[:, 0] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + _dot(p.astype(v.dtype), v, _NN)
+
+    if causal:
+        pl.when(_tile_live(iq, ik, block_q, block_k, 0))(_body)
+    else:
+        _body()
 
     @pl.when(ik == nk - 1)
     def _emit():
-        l = l_scr[:, 0]
-        o_ref[0, :, 0, :] = (acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-                             ).astype(o_ref.dtype)
-        lse_ref[0, 0, 0, :] = m_scr[:, 0] + jnp.log(jnp.maximum(l, 1e-30))
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = _col_to_row(m_scr[...] + jnp.log(l))
 
 
 def _fm_bwd_dq_kernel(q_ref, k_ref, v_ref, idx_ref, do_ref, lse_ref, delta_ref,
@@ -111,40 +113,34 @@ def _fm_bwd_dq_kernel(q_ref, k_ref, v_ref, idx_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q_rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    @pl.when((ik * block_k <= iq * block_q + block_q - 1) if causal else (ik >= 0))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         idx = idx_ref[0, 0]
-        lse = lse_ref[0, 0, 0, :]
-        delta = delta_ref[0, 0, 0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        disallowed = _tile_mask(idx, q_rows, causal, ncol)
-        if causal:
-            disallowed = disallowed | (q_rows < k_pos)
-        p = jnp.where(disallowed, 0.0, jnp.exp(s - lse[:, None]))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        lse = _row_to_col(lse_ref[0, 0])
+        delta = _row_to_col(delta_ref[0, 0])
+        q_pos, k_pos = _positions(iq, ik, block_q, block_k, 0, 0)
+        disallowed = _disallowed([idx[j:j + 1, :] for j in range(ncol)],
+                                 q_pos, k_pos, causal)
+        s = _dot(q, k, _NT) * scale
+        p = jnp.where(disallowed, 0.0, jnp.exp(s - lse))
+        ds = p * (_dot(do, v, _NT) - delta)
+        dq_scr[...] += _dot(ds.astype(k.dtype), k, _NN)
+
+    if causal:
+        pl.when(_tile_live(iq, ik, block_q, block_k, 0))(_body)
+    else:
+        _body()
 
     @pl.when(ik == nk - 1)
     def _emit():
-        dq_ref[0, :, 0, :] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        dq_ref[0, 0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _fm_bwd_dkv_kernel(q_ref, k_ref, v_ref, idx_ref, do_ref, lse_ref,
                        delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
                        causal, ncol, block_q, block_k, nq):
+    """Transposed [block_k, block_q] tiles, as in flash_attention's dk/dv
+    pass: lse/Δ rows and the per-key bound columns broadcast as they are."""
     from jax.experimental import pallas as pl
 
     ik, iq = pl.program_id(2), pl.program_id(3)
@@ -154,87 +150,71 @@ def _fm_bwd_dkv_kernel(q_ref, k_ref, v_ref, idx_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q_rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    @pl.when((iq * block_q + block_q - 1 >= ik * block_k) if causal else (iq >= 0))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         idx = idx_ref[0, 0]
-        lse = lse_ref[0, 0, 0, :]
-        delta = delta_ref[0, 0, 0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        disallowed = _tile_mask(idx, q_rows, causal, ncol)
-        if causal:
-            disallowed = disallowed | (q_rows < k_pos)
-        p = jnp.where(disallowed, 0.0, jnp.exp(s - lse[:, None]))
-        dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        lse, delta = lse_ref[0, 0], delta_ref[0, 0]
+        q_pos, k_pos = _positions(iq, ik, block_q, block_k, 0, 1)
+        disallowed = _disallowed([idx[:, j:j + 1] for j in range(ncol)],
+                                 q_pos, k_pos, causal)
+        st = _dot(k, q, _NT) * scale
+        pt = jnp.where(disallowed, 0.0, jnp.exp(st - lse))
+        dv_scr[...] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta)
+        dk_scr[...] += _dot(dst.astype(q.dtype), q, _NN)
+
+    if causal:
+        pl.when(_tile_live(iq, ik, block_q, block_k, 0))(_body)
+    else:
+        _body()
 
     @pl.when(iq == nq - 1)
     def _emit():
-        dk_ref[0, :, 0, :] = (dk_scr[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _prep_idx(idx, b, h, sk):
-    """idx (B, Hm, Sk, ncol) with Hm in {1, h} → int32, kept 4-D; the
-    BlockSpec index map broadcasts Hm==1 across heads."""
-    ncol = idx.shape[-1]
-    return idx.astype(jnp.int32), idx.shape[1], ncol
+def _fm_specs(idx, block_q, block_k, d, q_minor):
+    """flash_attention's q/k/row specs plus the bounds spec: a
+    (ncol, block_k) slab of [B, Hm, ncol, Sk] for the forward/dq grid, a
+    (block_k, ncol) slab of [B, Hm, Sk, ncol] for the dk/dv grid
+    (``q_minor``). Hm == 1 broadcasts one index set across heads."""
+    hm, ncol = idx.shape[1], idx.shape[3]
 
+    def head(ih):
+        return ih if hm > 1 else 0
 
-def _fm_blocks(sq, sk, block_q=256, block_k=512):
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    while sq % block_q:
-        block_q //= 2
-    while sk % block_k:
-        block_k //= 2
-    return max(block_q, 1), max(block_k, 1)
+    if q_minor:
+        ispec = _block_spec((1, 1, block_k, ncol),
+                            lambda ib, ih, iq, ik: (ib, head(ih), ik, 0), True)
+    else:
+        ispec = _block_spec((1, 1, ncol, block_k),
+                            lambda ib, ih, iq, ik: (ib, head(ih), 0, ik), False)
+    return (*_qk_specs(block_q, block_k, d, q_minor), ispec)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
-def _fm_fwd(q, k, v, idx, causal, scale, interpret=False):
+def _fm_fwd_hm(q, k, v, idx, causal, scale, interpret=False):
+    """Heads-major forward: q [B, H, Sq, D], k/v [B, H, Sk, D], idx
+    [B, Hm, Sk, ncol] -> (out [B, H, Sq, D], lse [B, H, 1, Sq])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    it, hm, ncol = _prep_idx(idx, b, h, sk)
-    block_q, block_k = _fm_blocks(sq, sk)
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    idx = idx.astype(jnp.int32)
+    block_q, block_k = _block(sq, 256), _block(sk, 512)
     nq, nk = sq // block_q, sk // block_k
-
-    def idx_map(ib, ih, iq, ik):
-        return (ib, ih if hm > 1 else 0, ik, 0)
-
-    out, lse = pl.pallas_call(
+    qspec, kspec, rowspec, ispec = _fm_specs(idx, block_q, block_k, d, False)
+    return pl.pallas_call(
         functools.partial(_fm_fwd_kernel, scale=scale, causal=causal,
-                          ncol=ncol, block_q=block_q, block_k=block_k, nk=nk),
+                          ncol=idx.shape[3], block_q=block_q, block_k=block_k,
+                          nk=nk),
         grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, 1, block_k, ncol), idx_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
-        ],
+        in_specs=[qspec, kspec, kspec, ispec],
+        out_specs=[qspec, rowspec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
@@ -243,64 +223,63 @@ def _fm_fwd(q, k, v, idx, causal, scale, interpret=False):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, it)
-    return out, lse
+    )(q, k, v, jnp.swapaxes(idx, 2, 3))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
-def _fm_bwd(q, k, v, idx, o, lse, do, causal, scale, interpret=False):
+def _fm_bwd_hm(q, k, v, idx, o, lse, do, causal, scale, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    it, hm, ncol = _prep_idx(idx, b, h, sk)
-    block_q, block_k = _fm_blocks(sq, sk)
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    idx = idx.astype(jnp.int32)
+    block_q, block_k = _block(sq, 256), _block(sk, 512)
     nq, nk = sq // block_q, sk // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-    delta = jnp.transpose(delta, (0, 2, 1))[:, :, None, :]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)[:, :, None, :]
+    static = dict(scale=scale, causal=causal, ncol=idx.shape[3],
+                  block_q=block_q, block_k=block_k)
 
-    qspec = pl.BlockSpec((1, block_q, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0))
-    kspec = pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih, 0))
-    rowspec = pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, iq, ik: (ib, ih, 0, iq))
-    ispec = pl.BlockSpec((1, 1, block_k, ncol),
-                         lambda ib, ih, iq, ik: (ib, ih if hm > 1 else 0, ik, 0))
-
+    qspec, kspec, rowspec, ispec = _fm_specs(idx, block_q, block_k, d, False)
     dq = pl.pallas_call(
-        functools.partial(_fm_bwd_dq_kernel, scale=scale, causal=causal,
-                          ncol=ncol, block_q=block_q, block_k=block_k, nk=nk),
+        functools.partial(_fm_bwd_dq_kernel, nk=nk, **static),
         grid=(b, h, nq, nk),
         in_specs=[qspec, kspec, kspec, ispec, qspec, rowspec, rowspec],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, it, do, lse, delta)
+    )(q, k, v, jnp.swapaxes(idx, 2, 3), do, lse, delta)
 
-    qspec2 = pl.BlockSpec((1, block_q, 1, d), lambda ib, ih, ik, iq: (ib, iq, ih, 0))
-    kspec2 = pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, ik, iq: (ib, ik, ih, 0))
-    rowspec2 = pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, ik, iq: (ib, ih, 0, iq))
-    ispec2 = pl.BlockSpec((1, 1, block_k, ncol),
-                          lambda ib, ih, ik, iq: (ib, ih if hm > 1 else 0, ik, 0))
+    qspec, kspec, rowspec, ispec = _fm_specs(idx, block_q, block_k, d, True)
     dk, dv = pl.pallas_call(
-        functools.partial(_fm_bwd_dkv_kernel, scale=scale, causal=causal,
-                          ncol=ncol, block_q=block_q, block_k=block_k, nq=nq),
+        functools.partial(_fm_bwd_dkv_kernel, nq=nq, **static),
         grid=(b, h, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, ispec2, qspec2, rowspec2, rowspec2],
-        out_specs=[
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, ik, iq: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, ik, iq: (ib, ik, ih, 0)),
-        ],
+        in_specs=[qspec, kspec, kspec, ispec, qspec, rowspec, rowspec],
+        out_specs=[kspec, kspec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sk, h, d), k.dtype),
-            jax.ShapeDtypeStruct((b, sk, h, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, it, do, lse, delta)
+    )(q, k, v, idx, do, lse, delta)
     return dq, dk, dv
+
+
+def _fm_fwd(q, k, v, idx, causal, scale, interpret=False):
+    """[B, S, H, D] forward -> (out [B, Sq, H, D], lse [B, H, 1, Sq])."""
+    out, lse = _fm_fwd_hm(_hm(q), _hm(k), _hm(v), idx, causal, scale,
+                          interpret=interpret)
+    return _hm(out), lse
+
+
+def _fm_bwd(q, k, v, idx, o, lse, do, causal, scale, interpret=False):
+    """[B, S, H, D] backward -> (dq, dk, dv) in the same layout."""
+    grads = _fm_bwd_hm(_hm(q), _hm(k), _hm(v), idx, _hm(o), lse, _hm(do),
+                       causal, scale, interpret=interpret)
+    return tuple(_hm(g) for g in grads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -311,15 +290,16 @@ def flashmask_value(q, k, v, startend_row_indices, causal=True, scale=1.0,
 
 
 def _fm_vjp_fwd(q, k, v, idx, causal, scale, interpret):
-    out, lse = _fm_fwd(q, k, v, idx, causal, scale, interpret=interpret)
-    return out, (q, k, v, idx, out, lse)
+    qt, kt, vt = _hm(q), _hm(k), _hm(v)
+    ot, lse = _fm_fwd_hm(qt, kt, vt, idx, causal, scale, interpret=interpret)
+    return _hm(ot), (qt, kt, vt, idx, ot, lse)
 
 
 def _fm_vjp_bwd(causal, scale, interpret, res, g):
-    q, k, v, idx, out, lse = res
-    dq, dk, dv = _fm_bwd(q, k, v, idx, out, lse, g, causal, scale,
-                         interpret=interpret)
-    return dq, dk, dv, None
+    qt, kt, vt, idx, ot, lse = res
+    dq, dk, dv = _fm_bwd_hm(qt, kt, vt, idx, ot, lse, _hm(g), causal, scale,
+                            interpret=interpret)
+    return _hm(dq), _hm(dk), _hm(dv), None
 
 
 flashmask_value.defvjp(_fm_vjp_fwd, _fm_vjp_bwd)

@@ -2,8 +2,9 @@
 paddle/phi/kernels/fusion/gpu/rms_norm_kernel.cu).
 
 The kernel fuses mean-of-squares + rsqrt + scale in VMEM, one row-block per
-grid step. Falls back to the XLA composition off-TPU (pallas interpret mode
-is used in tests).
+grid step. ``nn.functional.rms_norm`` takes it when ``ops.pallas.enabled()``
+says so and the XLA composition otherwise; interpret mode is for the tests,
+through ``rms_norm_value(..., interpret=True)``.
 """
 from __future__ import annotations
 
@@ -11,28 +12,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from ...base.flags import get_flag
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def _probe():
-    x = jnp.ones((8, 128), jnp.bfloat16)
-    w = jnp.ones((128,), jnp.bfloat16)
-    jax.block_until_ready(rms_norm_value(x, w))
-
-
-def available() -> bool:
-    from . import self_test
-
-    return (get_flag("use_pallas_kernels") and _on_tpu()
-            and self_test("rms_norm", _probe))
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -50,13 +29,15 @@ def _rms_norm_fwd(x, w, eps=1e-6, interpret=False):
     d = x.shape[-1]
     xr = x.reshape(-1, d)
     rows = xr.shape[0]
-    block_rows = max(1, min(rows, 512 * 1024 // max(d * x.dtype.itemsize, 1)))
-    while rows % block_rows:
-        block_rows -= 1
+    # ~512 KiB of input per step, on the sublane tiling (16 rows covers bf16
+    # and f32). Rows are independent, so a ragged last block is harmless:
+    # its out-of-range rows are computed on padding and never written back.
+    target = 512 * 1024 // (d * x.dtype.itemsize)
+    block_rows = min(rows, max(16, target // 16 * 16))
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
-        grid=(rows // block_rows,),
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
@@ -105,6 +86,6 @@ def rms_norm(x, weight, epsilon=1e-6):
 
     return primitive(
         "pallas_rms_norm",
-        lambda v, w: rms_norm_value(v, w, epsilon, interpret=not _on_tpu()),
+        lambda v, w: rms_norm_value(v, w, epsilon),
         [x, weight],
     )

@@ -136,10 +136,7 @@ class DecodePrograms:
         self.restored: List[tuple] = []
         self._aot: Dict[tuple, object] = {}
         self._lock = named_lock("serving.decode.programs")
-        try:
-            backend = jax.devices()[0].platform
-        except Exception:
-            backend = "cpu"
+        backend = jax.devices()[0].platform
         # serving-step donation idiom: the pool buffers are dead after the
         # call (the scheduler commits the outputs), so donate them and XLA
         # updates the KV cache in place. CPU ignores donation — skip the

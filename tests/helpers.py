@@ -4,66 +4,12 @@
 ``propagate=False`` on the framework logger — pytest's ``caplog`` fixture
 hooks the root logger, so it silently captures NOTHING from the
 framework. Every test that asserts on framework log output must attach a
-handler directly; this context manager is that idiom in one place.
-
-``partition_id_supported`` is the capability probe for the
-jaxlib-0.4.36 PartitionId-under-SPMD limit: partial-manual shard_map
-regions (``axis_names`` a strict subset of the mesh axes — the pipeline
-schedules' pp ring) lower ``axis_index``/``ppermute`` to a PartitionId
-instruction the SPMD partitioner of this jaxlib rejects on CPU
-(``UNIMPLEMENTED: PartitionId instruction is not supported for SPMD
-partitioning``). Tests that need that lowering skip on the probe —
-capability-gated, so a jaxlib that fixes it re-enables them
-automatically instead of hiding a real regression behind a blanket
-skip."""
+handler directly; this context manager is that idiom in one place."""
 from __future__ import annotations
 
 import contextlib
 import io
 import logging
-
-PARTITION_ID_SKIP_REASON = (
-    "jaxlib 0.4.36 limit: PartitionId instruction is not supported for "
-    "SPMD partitioning on this backend (partial-manual shard_map regions "
-    "— the pipeline pp ring — cannot compile); capability probe "
-    "tests.helpers.partition_id_supported")
-
-_partition_id_probe: dict = {}
-
-
-def partition_id_supported() -> bool:
-    """True when this jax/jaxlib can compile a partial-manual shard_map
-    region that materializes the partition id (see module docstring).
-    Probed once per process with a 2-device toy ring; single-device
-    processes report True (nothing to partition)."""
-    if "ok" in _partition_id_probe:
-        return _partition_id_probe["ok"]
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from paddle_tpu.base import jax_compat
-
-    devs = jax.devices()
-    if len(devs) < 4:
-        _partition_id_probe["ok"] = True
-        return True
-    # the failing lowering needs a real auto (non-manual) axis next to
-    # the manual ring: SPMD partitions over "mp" while "pp" is manual
-    mesh = Mesh(np.array(devs[:4]).reshape(2, 2), ("pp", "mp"))
-    f = jax_compat.shard_map(
-        lambda x: jax.lax.ppermute(
-            x + jax.lax.axis_index("pp"), "pp", [(0, 1), (1, 0)]),
-        mesh=mesh, in_specs=P("pp"), out_specs=P("pp"),
-        axis_names=frozenset({"pp"}), check_vma=False)
-    try:
-        jax.jit(f).lower(jnp.ones((2, 2), jnp.float32)).compile()
-        _partition_id_probe["ok"] = True
-    except Exception as e:  # jaxlib raises XlaRuntimeError UNIMPLEMENTED
-        _partition_id_probe["ok"] = "PartitionId" not in str(e)
-    return _partition_id_probe["ok"]
 
 
 @contextlib.contextmanager

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu.base.flags import get_flags, set_flags
-from paddle_tpu.base.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed import collective_opt as copt
 
 N_DEV = len(jax.devices())

@@ -35,6 +35,21 @@ def test_forward_shape_and_grad():
     assert np.isfinite(float(loss.numpy()))
 
 
+def test_criterion_is_next_token_cross_entropy():
+    """The criterion shifts the labels and ignores the last position; the
+    value is the mean cross entropy of logits[:, :-1] against labels[:, 1:]."""
+    cfg = gpt_tiny()
+    rs = np.random.RandomState(3)
+    logits = rs.randn(2, 16, cfg.vocab_size).astype(np.float32)
+    ids = _batch(cfg)
+    got = float(GPTPretrainingCriterion(cfg)(paddle.Tensor(logits), ids).numpy())
+    z = logits[:, :-1].reshape(-1, cfg.vocab_size).astype(np.float64)
+    y = ids.numpy()[:, 1:].reshape(-1)
+    logp = z - z.max(-1, keepdims=True)
+    logp -= np.log(np.exp(logp).sum(-1, keepdims=True))
+    np.testing.assert_allclose(got, -logp[np.arange(len(y)), y].mean(), rtol=1e-5)
+
+
 @pytest.mark.slow
 def test_train_step_loss_decreases():
     cfg = gpt_tiny()
@@ -83,9 +98,10 @@ def test_tensor_parallel_parity():
 
 @pytest.mark.slow
 def test_graft_entry_single_and_multichip():
+    import os
     import sys
 
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     try:
         import __graft_entry__ as ge
     finally:

@@ -5,8 +5,6 @@ stage-parallel, which the reference gets for free from separate processes)."""
 import numpy as np
 import pytest
 
-from tests import helpers
-
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 from paddle_tpu.distributed import fleet
@@ -160,8 +158,6 @@ def test_1f1b_dropout_trains_and_masks_replay():
     np.testing.assert_allclose(e1.numpy(), e2.numpy(), rtol=1e-6)
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_1f1b_memory_bounded_vs_rotation():
     """The 1f1b backward must NOT stack per-tick residuals: at m >> p the
     grad program's temp memory stays flat vs the rotation schedule's
@@ -201,8 +197,6 @@ def test_1f1b_memory_bounded_vs_rotation():
         mem_f.temp_size_in_bytes, mem_r.temp_size_in_bytes)
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_schedule_is_stage_parallel():
     """The compiled schedule must rotate activations over the pp ring
     (collective-permute in HLO) with one tick loop of m·v + p - 1 chunk
@@ -324,8 +318,6 @@ def test_pipelined_stack_dropout_trains():
     np.testing.assert_allclose(e1.numpy(), e2.numpy(), rtol=1e-6)
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_pipelined_stack_dropout_masks_differ_per_stage():
     """With p=0.5 on an all-ones input, each layer (stage) must draw a
     different mask: if stages shared one mask the zero pattern of the layer-1
@@ -379,8 +371,6 @@ def test_pipeline_layer_heterogeneous_segments():
     assert np.isfinite(x.grad.numpy()).all()
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_pipeline_layer_shared_desc_ties_weights():
     from paddle_tpu.distributed.fleet.pipeline import (
         PipelineLayer,
@@ -486,8 +476,6 @@ def test_zb_bubble_accounting():
     assert r["useful_units"] == 32
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_zb_memory_bounded_vs_rotation():
     """ZB keeps 1F1B's O(p) activation property: its grad program's temp
     memory stays well under the rotation schedule's O(m) residuals."""
@@ -582,8 +570,6 @@ def test_vpp_1f1b_dropout_trains_and_replays():
     np.testing.assert_allclose(e1.numpy(), e2.numpy(), rtol=1e-6)
 
 
-@pytest.mark.skipif(not helpers.partition_id_supported(),
-                    reason=helpers.PARTITION_ID_SKIP_REASON)
 def test_vpp_1f1b_memory_bounded_vs_rotation():
     """The interleaved combined scan must NOT stack per-tick residuals: at
     m >> p its grad program's temp memory stays well under the rotation

@@ -507,11 +507,11 @@ class TestJaxprAuditor:
         assert "JX301" in _codes(f.audit())
 
     def test_f64_literal_in_step_fn_flagged(self):
-        from jax.experimental import enable_x64
+        import jax
 
         from paddle_tpu.jit.functionalize import functionalize
 
-        with enable_x64():
+        with jax.enable_x64():
             def step_fn(x):
                 return x * np.float64(2.0)  # seeded f64 leak
 

@@ -621,7 +621,7 @@ class TestPlannerPricing:
         from jax.sharding import Mesh, PartitionSpec as P
 
         from paddle_tpu.analysis.cost_model import cost_jaxpr
-        from paddle_tpu.base.jax_compat import shard_map
+        from jax import shard_map
 
         n, numel = 8, 512 * 64
         mesh = Mesh(np.array(jax.devices()[:n]).reshape(n), ("dp",))
