@@ -1,0 +1,52 @@
+"""The names of the program's regions: one vocabulary, kept here.
+
+A region is a ``jax.named_scope`` around a stretch of a traced program.
+The name is HLO metadata only (the program computes the same); a device
+trace carries it as each operation's ``tf_op``, and ``benchmark/scopes.py``
+reduces a capture to seconds per region by these names. They are a
+contract: a change that moves work between regions keeps the names, and a
+reader names a region by its constant here, never by a string of its own.
+
+    with region(MLP): ...            jax.named_scope("mlp")
+
+Training (``models/gpt.py``, ``nn/functional/attention.py``, the Pallas
+kernels' wrappers, ``optimizer.step``) uses ``TRAINING``; backward
+operations keep their forward region (``core/autograd.py`` re-enters it
+around each pullback). A serving program (``serving/decode.py``,
+``serving/kv_cache.py``) runs under one of ``ROOTS`` and uses ``SERVING``
+inside it. ``KERNELS`` are the ``pl.pallas_call(name=...)`` of
+``ops/pallas/flash_attention.py``.
+"""
+from __future__ import annotations
+
+from jax import named_scope as region  # noqa: F401  (the one way to enter one)
+
+EMBED = "embed"
+LN = "ln"
+ATTN_QKV = "attn/qkv"
+ATTN_LAYOUT = "attn/layout"        # heads-major transposes around the kernels
+ATTN_CORE = "attn/core"            # the attention itself (the kernel calls)
+ATTN_OUT = "attn/out"
+ATTN_KV_WRITE = "attn/kv_write"    # every write into the KV pool
+ATTN_KV_GATHER = "attn/kv_gather"  # a lane's pages gathered into one view
+MLP = "mlp"
+LM_HEAD = "lm_head"
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+SAMPLE = "sample"
+
+PREFILL = "prefill"
+DECODE = "decode"
+DRAFT = "draft"
+VERIFY = "verify"
+
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+
+TRAINING = (EMBED, LN, ATTN_QKV, ATTN_LAYOUT, ATTN_CORE, ATTN_OUT, MLP,
+            LM_HEAD, LOSS, OPTIMIZER)
+SERVING = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KV_GATHER, ATTN_CORE,
+           ATTN_OUT, MLP, LM_HEAD, SAMPLE)
+ROOTS = (PREFILL, DECODE, DRAFT, VERIFY)
+KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)

@@ -26,6 +26,7 @@ path.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional
 
 import jax
@@ -33,7 +34,33 @@ import jax.numpy as jnp
 
 from ..base import global_state
 from ..base.enforce import enforce
+from ..base.log import get_logger
 from .tensor import Tensor
+
+try:  # private, and the only reader of jax's ambient name stack
+    from jax._src.source_info_util import current_name_stack as _name_stack
+except ImportError:  # moved: backward ops lose their region, nothing else
+    _name_stack = None
+    get_logger().warning(
+        "jax._src.source_info_util.current_name_stack is gone: backward "
+        "operations carry no region (base/regions.py), so a device trace "
+        "read by region charges them to 'unscoped'")
+
+
+def _active_scope() -> Optional[str]:
+    """The ``jax.named_scope`` path open right now, or None. jax names an
+    op's backward after the scopes entered INSIDE the differentiated
+    function; a tape op is differentiated one primitive at a time, inside
+    the model's scopes, so its pullback would run unnamed. The node records
+    the path at forward time and re-enters it around ``vjp_fn``: a device
+    trace then charges backward ops to their forward region."""
+    if _name_stack is None:
+        return None
+    stack = _name_stack()
+    return str(stack) if stack.stack else None
+
+
+_NO_SCOPE = contextlib.nullcontext()
 
 
 class Edge:
@@ -59,6 +86,7 @@ class GradNode:
         "n_outputs",
         "out_specs",
         "recompute",
+        "scope",
         "_out_grads",
     )
 
@@ -72,13 +100,23 @@ class GradNode:
         self.n_outputs = n_outputs
         self.out_specs = out_specs  # (shape, dtype) per output for zero-fill
         self.recompute = recompute  # (fn, values, attrs, diff_idx) for create_graph
+        self.scope = _active_scope()  # named_scope path of the forward op
         self._out_grads: Optional[list] = None
+
+    def _in_scope(self):
+        """The forward op's region, re-entered for its backward work."""
+        return (_NO_SCOPE if self.scope is None
+                else jax.named_scope(self.scope))
 
     def accumulate(self, index: int, grad: Tensor):
         if self._out_grads is None:
             self._out_grads = [None] * self.n_outputs
         cur = self._out_grads[index]
-        self._out_grads[index] = grad if cur is None else cur + grad
+        if cur is None:
+            self._out_grads[index] = grad
+        else:
+            with self._in_scope():
+                self._out_grads[index] = cur + grad
 
     def _is_int_output(self, i: int) -> bool:
         _, dt = self.out_specs[i]
@@ -111,7 +149,7 @@ class GradNode:
         enforce(self.vjp_fn is not None, f"grad node '{self.name}' was already released; "
                 "pass retain_graph=True to backward() to keep it")
         cotans = tuple(self._raw_cotangent(i, g) for i, g in enumerate(gouts))
-        with global_state.no_grad_guard():
+        with global_state.no_grad_guard(), self._in_scope():
             raw = self.vjp_fn(cotans if self.n_outputs > 1 else cotans[0])
         if not isinstance(raw, (tuple, list)):
             raw = (raw,)

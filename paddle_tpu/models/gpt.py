@@ -18,12 +18,20 @@ TPU-native design:
   sharding constraint instead of explicit scatter/gather ops.
 - the whole model is a pytree of Parameters, so one `jit` over the train step
   compiles embedding→blocks→loss into a single XLA program.
+- the forward runs under the regions of `base/regions.py` (`embed`, `ln`,
+  `attn/qkv`, `attn/out`, `mlp`, `lm_head`, `loss`; the attention op adds
+  `attn/layout` and `attn/core`, the optimizer `optimizer`). They are HLO
+  metadata only; backward operations keep their forward region
+  (`transpose(jvp(mlp))`), and a device trace names every operation's.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
 
+
+from ..base import regions
+from ..base.regions import region
 from ..nn import functional as F
 from .. import nn
 from ..nn.initializer import Constant, Normal
@@ -100,13 +108,15 @@ class GPTAttention(Layer):
     def forward(self, x):
         cfg = self.config
         b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        # [B, S, 3H] -> [B, S, H_local, 3, D]; under mp the head dim is sharded.
-        heads = qkv.shape[-1] // (3 * cfg.head_dim)
-        qkv = manipulation.reshape(qkv, [b, s, heads, 3, cfg.head_dim])
-        q = qkv[:, :, :, 0, :]
-        k = qkv[:, :, :, 1, :]
-        v = qkv[:, :, :, 2, :]
+        with region(regions.ATTN_QKV):
+            qkv = self.qkv_proj(x)
+            # [B, S, 3H] -> [B, S, H_local, 3, D]; under mp the head dim is
+            # sharded.
+            heads = qkv.shape[-1] // (3 * cfg.head_dim)
+            qkv = manipulation.reshape(qkv, [b, s, heads, 3, cfg.head_dim])
+            q = qkv[:, :, :, 0, :]
+            k = qkv[:, :, :, 1, :]
+            v = qkv[:, :, :, 2, :]
         if cfg.context_parallel:
             from ..distributed.fleet.context_parallel import (
                 ring_attention,
@@ -114,14 +124,17 @@ class GPTAttention(Layer):
             )
 
             cp = ring_attention if cfg.context_parallel == "ring" else ulysses_attention
-            out = cp(q, k, v, causal=True)
+            with region(regions.ATTN_CORE):
+                out = cp(q, k, v, causal=True)
         else:
+            # names its own regions: attn/layout and attn/core
             out = F.scaled_dot_product_attention(
                 q, k, v, is_causal=True,
                 dropout_p=cfg.attention_dropout_prob, training=self.training,
             )
-        out = manipulation.reshape(out, [b, s, heads * cfg.head_dim])
-        return self.out_proj(out)
+        with region(regions.ATTN_OUT):
+            out = manipulation.reshape(out, [b, s, heads * cfg.head_dim])
+            return self.out_proj(out)
 
 
 class GPTMLP(Layer):
@@ -140,7 +153,8 @@ class GPTMLP(Layer):
             self.fc2 = nn.Linear(ffn, h, weight_attr=_init_attr(config, config.num_hidden_layers))
 
     def forward(self, x):
-        return self.fc2(F.gelu(self.fc1(x), approximate=True))
+        with region(regions.MLP):
+            return self.fc2(F.gelu(self.fc1(x), approximate=True))
 
 
 def _seq_constrain(x, config: GPTConfig):
@@ -171,12 +185,18 @@ class GPTDecoderLayer(Layer):
 
     def forward(self, x):
         cfg = self.config
-        h = self.attn(self.ln_1(x))
-        h = F.dropout(h, cfg.hidden_dropout_prob, training=self.training)
-        x = _seq_constrain(x + h, cfg)
-        h = self.mlp(self.ln_2(x))
-        h = F.dropout(h, cfg.hidden_dropout_prob, training=self.training)
-        return _seq_constrain(x + h, cfg)
+        with region(regions.LN):
+            h = self.ln_1(x)
+        h = self.attn(h)
+        with region(regions.ATTN_OUT):  # its dropout and residual
+            h = F.dropout(h, cfg.hidden_dropout_prob, training=self.training)
+            x = _seq_constrain(x + h, cfg)
+        with region(regions.LN):
+            h = self.ln_2(x)
+        h = self.mlp(h)
+        with region(regions.MLP):       # its dropout and residual
+            h = F.dropout(h, cfg.hidden_dropout_prob, training=self.training)
+            return _seq_constrain(x + h, cfg)
 
 
 class GPTEmbeddings(Layer):
@@ -195,13 +215,14 @@ class GPTEmbeddings(Layer):
             config.max_position_embeddings, config.hidden_size, weight_attr=_init_attr(config))
 
     def forward(self, input_ids, position_ids=None):
-        if position_ids is None:
-            s = input_ids.shape[-1]
-            position_ids = creation.arange(0, s, dtype="int64")
-            position_ids = manipulation.expand(
-                manipulation.unsqueeze(position_ids, 0), [input_ids.shape[0], s])
-        x = self.word_embeddings(input_ids) + self.position_embeddings(position_ids)
-        return F.dropout(x, self.config.hidden_dropout_prob, training=self.training)
+        with region(regions.EMBED):
+            if position_ids is None:
+                s = input_ids.shape[-1]
+                position_ids = creation.arange(0, s, dtype="int64")
+                position_ids = manipulation.expand(
+                    manipulation.unsqueeze(position_ids, 0), [input_ids.shape[0], s])
+            x = self.word_embeddings(input_ids) + self.position_embeddings(position_ids)
+            return F.dropout(x, self.config.hidden_dropout_prob, training=self.training)
 
 
 class GPTModel(Layer):
@@ -238,7 +259,8 @@ class GPTModel(Layer):
         else:
             for block in self.h:
                 x = block(x)
-        return self.ln_f(x)
+        with region(regions.LN):
+            return self.ln_f(x)
 
 
 class GPTForCausalLM(Layer):
@@ -256,10 +278,11 @@ class GPTForCausalLM(Layer):
 
     def forward(self, input_ids, position_ids=None):
         x = self.gpt(input_ids, position_ids)
-        if self.config.tie_word_embeddings:
-            w = self.gpt.embeddings.word_embeddings.weight  # [V, H]
-            return F.linear(x, manipulation.transpose(w, [1, 0]))
-        return self.lm_head(x)
+        with region(regions.LM_HEAD):
+            if self.config.tie_word_embeddings:
+                w = self.gpt.embeddings.word_embeddings.weight  # [V, H]
+                return F.linear(x, manipulation.transpose(w, [1, 0]))
+            return self.lm_head(x)
 
 
 class GPTPretrainingCriterion(Layer):
@@ -280,16 +303,17 @@ class GPTPretrainingCriterion(Layer):
         # Next-token shift: logits at position i predict token i+1. Callers
         # pass the raw token ids as labels; the shift happens here so the
         # objective is a real causal-LM loss, not a copy task.
-        v = logits.shape[-1]
-        flat = manipulation.reshape(logits, [-1, v])
-        flat_labels = manipulation.reshape(shift_labels(labels), [-1])
-        if self._parallel_ce is not None:
-            from ..ops import math as ops_math
+        with region(regions.LOSS):
+            v = logits.shape[-1]
+            flat = manipulation.reshape(logits, [-1, v])
+            flat_labels = manipulation.reshape(shift_labels(labels), [-1])
+            if self._parallel_ce is not None:
+                from ..ops import math as ops_math
 
-            loss = self._parallel_ce(flat, flat_labels)  # 0 where ignored
-            b, s = labels.shape
-            return ops_math.sum(loss) / float(b * (s - 1))
-        return F.cross_entropy(flat, flat_labels, reduction="mean")
+                loss = self._parallel_ce(flat, flat_labels)  # 0 where ignored
+                b, s = labels.shape
+                return ops_math.sum(loss) / float(b * (s - 1))
+            return F.cross_entropy(flat, flat_labels, reduction="mean")
 
 
 IGNORE_INDEX = -100  # F.cross_entropy's default ignore_index

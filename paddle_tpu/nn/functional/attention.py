@@ -16,6 +16,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ...base import regions
 from ...core.dispatch import primitive
 from ...core.tensor import unwrap
 
@@ -26,8 +27,10 @@ def _seed_from_key(key):
     return jax.random.randint(key, (1,), 0, 2**31 - 1, jnp.int32)
 
 
+@regions.region(regions.ATTN_CORE)
 def _xla_attention(q, k, v, *, causal, scale, bias=None, dropout=0.0, dropout_key=None):
-    # q,k,v: [B, S, H, D] -> einsum over head dim
+    # q,k,v: [B, S, H, D] -> einsum over head dim. The whole composition is
+    # one region; the Pallas path names its layout and core regions itself.
     logits = jnp.einsum("bshd,bthd->bhst", q, k) * scale
     if bias is not None:
         logits = logits + bias

@@ -14,7 +14,18 @@ fragments. Three pieces:
   phases (prefetch wait, step, metric flush) and per-request serving
   spans onto one chrome://tracing / Perfetto timeline with correlated
   track ids. :func:`span` / :func:`export_trace` are the entry points;
-  ``FLAGS_telemetry_trace`` gates recording.
+  ``FLAGS_telemetry_trace`` gates recording. Every event has an ``id``
+  and a ``parent`` (the innermost span open on its thread, or the one
+  ``emit(parent=)`` names). The decode scheduler's beat is one
+  ``serving.beat`` span tiled by ``serving.admit`` / ``serving.build`` /
+  ``serving.decode`` (holding ``serving.dispatch`` and ``serving.read``)
+  / ``serving.absorb``; a request's phases are ``serving.request.queue``
+  / ``.prefill`` (or ``.failed``) sharing ``request=<id>``, from its
+  ``t_first_token`` stamp. On the device side the programs name their
+  regions with ``jax.named_scope`` from one vocabulary,
+  :mod:`paddle_tpu.base.regions` (``attn/qkv``, ``attn/kv_gather``,
+  ``mlp``, ``optimizer``, ...; README "Program regions"), and their
+  Pallas kernels (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``).
 - :mod:`memory` — a device-memory telemetry sampler (jax ``live_arrays``
   + backend ``memory_stats`` watermarks) sampled at step/batch
   boundaries only, never forcing a device sync, feeding gauges
@@ -33,8 +44,9 @@ Egress + forensics (ISSUE 8) sit on top:
   dumping a bounded, rate-limited forensic bundle (last-N spans + full
   snapshot + verdict + step window) to ``FLAGS_telemetry_dump_dir``.
 - ``SpanTracer.capture_device`` — ``jax.profiler`` windows fused into
-  the SAME chrome-trace export as the host spans (``device.*`` tracks,
-  clock-aligned at capture boundaries).
+  the SAME chrome-trace export as the host spans (``device.*`` tracks),
+  on the host's clock: aligned on a ``paddle_tpu.sync`` annotation
+  emitted inside the capture, whose host time is read inside it.
 - :mod:`locks` — the named-lock registry + runtime lock-order witness
   (concurrency lint family, CX10xx): every runtime lock/condition is a
   ``named_lock``/``named_condition``; ``FLAGS_concurrency_witness``
@@ -91,7 +103,7 @@ try:
     from ..base.flags import on_flag_change as _on_flag_change
 
     _on_flag_change("telemetry_trace",
-                    lambda v: setattr(tracer, "enabled", bool(v)))
+                    lambda v: tracer.enable() if v else tracer.disable())
     _on_flag_change("telemetry_anomaly",
                     lambda v: setattr(monitor, "enabled", bool(v)))
     from .locks import set_witness as _set_witness

@@ -23,6 +23,15 @@ Cost discipline: ``FLAGS_telemetry_trace`` gates recording. Disabled
 (``tracer.enabled``); there is no allocation, no lock, no clock read.
 Enabled, a span costs two ``perf_counter`` calls + one locked append.
 
+Identity: every recorded event carries ``id`` (one process-wide counter,
+shared by all tracers) and ``parent`` — the id of the innermost span open
+*on the same thread* when it started, or what ``emit(parent=...)`` was
+given; ``None`` for a root. Both ride as TOP-LEVEL fields of the chrome
+event (``{"ph": "X", ..., "id": 7, "parent": 3}``), never inside ``args``:
+``args`` belong to the instrumented site, and chrome/Perfetto ignore
+unknown top-level keys on complete events. The thread-local stack behind
+``parent`` is touched only when ``enabled``.
+
 Open-span accounting feeds the OB600 telemetry audit: exporting a trace
 while spans are still open means an instrumented region leaked its
 ``end()`` (an exception path without a ``with`` block) and its wall time
@@ -31,10 +40,14 @@ is silently missing from the timeline.
 **Device-trace fusion** (ISSUE 8, the ROADMAP telemetry leftover): XLA's
 own profiler exports on a separate timeline. ``SpanTracer.capture_device``
 wraps ``jax.profiler.start_trace``/``stop_trace`` around a window, parses
-the chrome-trace JSON the profile run wrote, clock-aligns it at the
-capture boundary (the earliest device event is pinned to the host
-``perf_counter`` stamp taken right before ``start_trace``) and ingests
-the events under ``device.<thread>`` tracks — so ONE ``to_chrome_trace``
+the chrome-trace JSON the profile run wrote, and puts it on the host's
+clock: right after ``start_trace`` returns it emits a
+``jax.profiler.TraceAnnotation("paddle_tpu.sync")`` and reads
+``perf_counter`` inside it, so the capture holds one event whose host time
+is known; every ingested event is shifted by (that stamp - the
+annotation's own timestamp). A capture without the annotation is not
+ingested: there is nothing to align it on. The events land under
+``device.<thread>`` tracks — so ONE ``to_chrome_trace``
 export shows host spans and XLA's device lanes side by side. The merged
 set is bounded by ``FLAGS_telemetry_device_trace_max_events`` (most
 recent kept) and the whole path degrades to a logged no-op when the
@@ -43,26 +56,45 @@ without the plugin).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import threading
 import time
 from typing import List, Optional
 
 from .locks import named_lock
 
-__all__ = ["SpanTracer", "tracer"]
+__all__ = ["SpanTracer", "tracer", "SYNC_NAME", "sync_annotation"]
+
+# the annotation that ties a jax.profiler capture to time.perf_counter
+SYNC_NAME = "paddle_tpu.sync"
+
+_ids = itertools.count(1)   # span ids: process-wide, next() is atomic
+
+
+def sync_annotation() -> float:
+    """Emit the ``paddle_tpu.sync`` annotation into the running
+    ``jax.profiler`` capture and return ``perf_counter`` (microseconds)
+    read inside it: the one point both clocks name."""
+    import jax
+
+    with jax.profiler.TraceAnnotation(SYNC_NAME):
+        return time.perf_counter() * 1e6
 
 
 class _Span:
     """One open span; ``with tracer.span(...)`` closes it."""
 
-    __slots__ = ("tracer", "name", "track", "args", "t0_us")
+    __slots__ = ("tracer", "name", "track", "args", "t0_us", "id", "parent")
 
-    def __init__(self, tracer_, name, track, args):
+    def __init__(self, tracer_, name, track, args, parent):
         self.tracer = tracer_
         self.name = name
         self.track = track
         self.args = args
+        self.id = next(_ids)
+        self.parent = parent
         self.t0_us = time.perf_counter() * 1e6
 
     def end(self) -> None:
@@ -79,6 +111,7 @@ class _NullSpan:
     """The disabled-tracer span: a shared, stateless no-op."""
 
     __slots__ = ()
+    id = None
 
     def end(self) -> None:
         pass
@@ -113,14 +146,15 @@ def _load_xla_chrome_trace(log_dir: str) -> Optional[dict]:
         return json.load(f)
 
 
-def _normalize_device_events(trace: dict, t0_us: float,
+def _normalize_device_events(trace: dict, t_sync_us: float,
                              include_python: bool = False) -> List[tuple]:
     """XLA chrome-trace events → this tracer's event tuples on
-    ``device.<thread>`` tracks, clock-aligned so the earliest device
-    event lands at ``t0_us`` (the host ``perf_counter`` stamp taken at
-    the capture boundary). The profiler's python-callstack lane
-    duplicates what the host tracks already carry; it is dropped unless
-    ``include_python``."""
+    ``device.<thread>`` tracks, on the host's clock: the capture's
+    ``paddle_tpu.sync`` annotation happened at host time ``t_sync_us``
+    (``perf_counter`` read inside it), so every event moves by the
+    difference. A capture without the annotation yields nothing, with a
+    warning. The profiler's python-callstack lane duplicates what the
+    host tracks already carry; it is dropped unless ``include_python``."""
     events = trace.get("traceEvents", []) if trace else []
     threads = {}
     for e in events:
@@ -128,19 +162,27 @@ def _normalize_device_events(trace: dict, t0_us: float,
             threads[(e.get("pid"), e.get("tid"))] = (
                 e.get("args") or {}).get("name", "")
     xs = [e for e in events if e.get("ph") == "X" and "ts" in e]
-    if not xs:
+    sync = next((e for e in xs if e.get("name") == SYNC_NAME), None)
+    if sync is None:
+        if xs:  # a clock nobody can place: say so, fuse nothing
+            from ..base.log import get_logger
+
+            get_logger().warning(
+                "device trace holds no '%s' annotation: %d event(s) not "
+                "fused into the timeline", SYNC_NAME, len(xs))
         return []
-    ts_min = min(float(e["ts"]) for e in xs)
+    shift = t_sync_us - float(sync["ts"])
     out = []
     for e in xs:
+        if e is sync:
+            continue
         tname = threads.get((e.get("pid"), e.get("tid")),
                             f"tid{e.get('tid')}")
         if not include_python and tname == "python":
             continue
-        args = e.get("args") or None
         out.append(("X", e.get("name", "?"), f"device.{tname}",
-                    t0_us + (float(e["ts"]) - ts_min),
-                    float(e.get("dur", 0.0)), args))
+                    float(e["ts"]) + shift, float(e.get("dur", 0.0)),
+                    e.get("args") or None, None, None))
     out.sort(key=lambda ev: ev[3])
     return out
 
@@ -157,7 +199,7 @@ class _DeviceCapture:
         self._own_dir = log_dir is None
         self._include_python = include_python
         self._active = False
-        self._t0_us = 0.0
+        self._t_sync_us = 0.0
 
     def __enter__(self) -> "_DeviceCapture":
         import tempfile
@@ -166,12 +208,12 @@ class _DeviceCapture:
 
         if self._log_dir is None:
             self._log_dir = tempfile.mkdtemp(prefix="paddle_device_trace_")
-        self._t0_us = time.perf_counter() * 1e6
         try:
             import jax
 
             jax.profiler.start_trace(self._log_dir)
             self._active = True
+            self._t_sync_us = sync_annotation()
         except Exception as e:
             get_logger().info("device trace capture unavailable "
                               "(degrading to host-only): %s", e)
@@ -192,7 +234,7 @@ class _DeviceCapture:
                     get_logger().info("device trace stop failed: %s", e)
                     return
                 n = self.tracer.ingest_device_trace_dir(
-                    self._log_dir, self._t0_us,
+                    self._log_dir, self._t_sync_us,
                     include_python=self._include_python)
                 get_logger().info("device trace fused: %d event(s) from %s",
                                   n, self._log_dir)
@@ -208,12 +250,15 @@ class SpanTracer:
     def __init__(self, enabled: Optional[bool] = None,
                  max_events: Optional[int] = None):
         self._lock = named_lock("tracing.spans")
-        self._events: List[tuple] = []   # (ph, name, track, ts_us, dur_us, args)
+        # (ph, name, track, ts_us, dur_us, args, id, parent)
+        self._events: List[tuple] = []
         self._device_events: List[tuple] = []  # same tuples, device.* tracks
-        self._open: dict = {}            # id(_Span) -> _Span
+        self._open: dict = {}            # span id -> _Span
         self._tids: dict = {}            # track name -> tid
         self._dropped = 0
         self._max_events = max_events
+        self._cap_now: Optional[int] = None  # the ring bound, read per enable()
+        self._stack = threading.local()  # .ids: this thread's open span ids
         if enabled is None:
             try:
                 from ..base.flags import get_flag
@@ -225,6 +270,7 @@ class SpanTracer:
 
     # ------------------------------------------------------------ lifecycle
     def enable(self) -> "SpanTracer":
+        self._cap_now = None  # FLAGS_telemetry_trace_max_events is re-read
         self.enabled = True
         return self
 
@@ -238,16 +284,27 @@ class SpanTracer:
             self._device_events.clear()
             self._open.clear()
             self._dropped = 0
+        # the calling thread's stack only: another thread's still-open
+        # spans close against their own (a stale id is just never matched)
+        self._thread_stack().clear()
 
     def _cap(self) -> int:
-        if self._max_events is not None:
-            return int(self._max_events)
-        try:
-            from ..base.flags import get_flag
+        """The host ring's bound: the constructor's, else the flag as it
+        stood at the last ``enable()`` (one flag read per enable, not one
+        per appended event)."""
+        cap = self._cap_now
+        if cap is None:
+            if self._max_events is not None:
+                cap = int(self._max_events)
+            else:
+                try:
+                    from ..base.flags import get_flag
 
-            return int(get_flag("telemetry_trace_max_events"))
-        except Exception:
-            return 65536
+                    cap = int(get_flag("telemetry_trace_max_events"))
+                except Exception:
+                    cap = 65536
+            self._cap_now = cap
+        return cap
 
     def capacity(self) -> int:
         """The ring bound currently in force (<=0 = unbounded — the
@@ -269,35 +326,59 @@ class SpanTracer:
         event. The no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        s = _Span(self, name, track, args or None)
+        stack = self._thread_stack()
+        s = _Span(self, name, track, args or None,
+                  stack[-1] if stack else None)
+        stack.append(s.id)
         with self._lock:
-            self._open[id(s)] = s
+            self._open[s.id] = s
         return s
+
+    def _thread_stack(self) -> list:
+        try:
+            return self._stack.ids
+        except AttributeError:
+            self._stack.ids = []
+            return self._stack.ids
 
     def _close(self, s: _Span) -> None:
         t1 = time.perf_counter() * 1e6
+        stack = self._thread_stack()
+        if stack and stack[-1] == s.id:
+            stack.pop()
+        elif s.id in stack:      # ended out of order, or on another thread
+            stack.remove(s.id)
         with self._lock:
-            self._open.pop(id(s), None)
-            self._append(("X", s.name, s.track, s.t0_us, t1 - s.t0_us, s.args))
+            self._open.pop(s.id, None)
+            self._append(("X", s.name, s.track, s.t0_us, t1 - s.t0_us, s.args,
+                          s.id, s.parent))
 
     def emit(self, name: str, t0_s: float, dur_s: float,
-             track: str = "host", **args) -> None:
+             track: str = "host", parent: Optional[int] = None,
+             **args) -> Optional[int]:
         """Record a complete span from already-measured ``perf_counter``
         timestamps (seconds) — the retroactive path for events whose
-        phases were stamped elsewhere (serving requests, RecordEvent)."""
+        phases were stamped elsewhere (serving requests, RecordEvent).
+        ``parent`` names the span that caused it (a retroactive span has
+        no place on the thread's stack, so nothing is inferred). Returns
+        the new span's id, None when disabled."""
         if not self.enabled:
-            return
+            return None
+        sid = next(_ids)
         with self._lock:
             self._append(("X", name, track, t0_s * 1e6, dur_s * 1e6,
-                          args or None))
+                          args or None, sid, parent))
+        return sid
 
     def instant(self, name: str, track: str = "host", **args) -> None:
         """Zero-duration marker (cache hit, sample tick, rejection)."""
         if not self.enabled:
             return
+        stack = self._thread_stack()
         with self._lock:
             self._append(("i", name, track, time.perf_counter() * 1e6, 0.0,
-                          args or None))
+                          args or None, next(_ids),
+                          stack[-1] if stack else None))
 
     def _append(self, event: tuple) -> None:
         # caller holds self._lock
@@ -321,14 +402,16 @@ class SpanTracer:
         additionally keep the TensorBoard/XProf artifacts."""
         return _DeviceCapture(self, log_dir, include_python)
 
-    def ingest_device_trace_dir(self, log_dir: str, t0_us: float,
+    def ingest_device_trace_dir(self, log_dir: str, t_sync_us: float,
                                 include_python: bool = False) -> int:
         """Parse an XLA profile run under ``log_dir`` and merge its
-        events (see module docstring). Returns how many landed; 0 —
-        never an exception — when the run wrote nothing parseable."""
+        events, aligned on the run's ``paddle_tpu.sync`` annotation
+        (``t_sync_us``: what :func:`sync_annotation` returned for it).
+        Returns how many landed; 0 — never an exception — when the run
+        wrote nothing parseable or holds no such annotation."""
         try:
             trace = _load_xla_chrome_trace(log_dir)
-            events = _normalize_device_events(trace, t0_us,
+            events = _normalize_device_events(trace, t_sync_us,
                                               include_python=include_python)
         except Exception as e:
             from ..base.log import get_logger
@@ -380,13 +463,16 @@ class SpanTracer:
             return tid
 
     def _event_dict(self, event: tuple, pid: int) -> dict:
-        ph, name, track, ts, dur, args = event
+        ph, name, track, ts, dur, args, sid, parent = event
         ev = {"ph": ph, "name": name, "pid": pid,
               "tid": self._tid(track), "ts": ts, "cat": track}
         if ph == "X":
             ev["dur"] = dur
         else:
             ev["s"] = "t"  # instant scope: thread
+        if sid is not None:  # fused device events have no identity here
+            ev["id"] = sid
+            ev["parent"] = parent
         if args:
             ev["args"] = dict(args)
         return ev

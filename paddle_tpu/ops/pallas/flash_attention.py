@@ -43,6 +43,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...base import regions
+
 NEG_INF = -1e30
 _LANES = 128
 
@@ -292,9 +294,16 @@ def _qk_specs(block_q, block_k, d, q_minor=False):
     )
 
 
-def _hm(x):
-    """[B, S, H, D] <-> heads-major [B, H, S, D]."""
-    return jnp.swapaxes(x, 1, 2)
+def _hm(*xs):
+    """[B, S, H, D] <-> heads-major [B, H, S, D], a region of their own:
+    the transposes into and out of the kernels."""
+    with regions.region(regions.ATTN_LAYOUT):
+        out = tuple(jnp.swapaxes(x, 1, 2) for x in xs)
+    return out if len(out) > 1 else out[0]
+
+
+# the kernel calls themselves (and the backward's row-sum)
+_core = functools.partial(regions.region, regions.ATTN_CORE)
 
 
 _STATIC = ("causal", "scale", "dropout", "block_q", "block_k", "interpret")
@@ -333,6 +342,7 @@ def _flash_fwd_hm(q, k, v, seed, causal, scale, dropout=0.0, block_q=256,
             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
         interpret=interpret,
+        name=regions.FLASH_FWD,
     )(seed, q, k, v)
 
 
@@ -364,6 +374,7 @@ def _flash_bwd_hm(q, k, v, o, lse, do, seed, causal, scale, dropout=0.0,
             [pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         interpret=interpret,
+        name=regions.FLASH_BWD_DQ,
     )(seed, q, k, v, do, lse, delta)
 
     qspec2, kspec2, rowspec2 = _qk_specs(block_q, block_k, d, q_minor=True)
@@ -380,6 +391,7 @@ def _flash_bwd_hm(q, k, v, o, lse, do, seed, causal, scale, dropout=0.0,
             jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
         ],
         interpret=interpret,
+        name=regions.FLASH_BWD_DKV,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -387,17 +399,20 @@ def _flash_bwd_hm(q, k, v, o, lse, do, seed, causal, scale, dropout=0.0,
 def _flash_fwd(q, k, v, seed, causal, scale, dropout=0.0, block_q=256,
                block_k=512, interpret=False):
     """[B, S, H, D] forward -> (out [B, Sq, H, D], lse [B, H, 1, Sq])."""
-    out, lse = _flash_fwd_hm(_hm(q), _hm(k), _hm(v), seed, causal, scale,
-                             dropout, block_q, block_k, interpret)
+    with _core():
+        out, lse = _flash_fwd_hm(*_hm(q, k, v), seed, causal, scale,
+                                 dropout, block_q, block_k, interpret)
     return _hm(out), lse
 
 
 def _flash_bwd(q, k, v, o, lse, do, seed, causal, scale, dropout=0.0,
                block_q=256, block_k=512, interpret=False):
     """[B, S, H, D] backward -> (dq, dk, dv) in the same layout."""
-    grads = _flash_bwd_hm(_hm(q), _hm(k), _hm(v), _hm(o), lse, _hm(do), seed,
-                          causal, scale, dropout, block_q, block_k, interpret)
-    return tuple(_hm(g) for g in grads)
+    qt, kt, vt, ot, dot = _hm(q, k, v, o, do)
+    with _core():
+        grads = _flash_bwd_hm(qt, kt, vt, ot, lse, dot, seed, causal, scale,
+                              dropout, block_q, block_k, interpret)
+    return _hm(*grads)
 
 
 def _xla_reference(q, k, v, causal, scale):
@@ -423,18 +438,21 @@ def _fa_value(q, k, v, causal, scale, dropout, seed, interpret):
 
 
 def _fa_fwd(q, k, v, causal, scale, dropout, seed, interpret):
-    qt, kt, vt = _hm(q), _hm(k), _hm(v)
-    ot, lse = _flash_fwd_hm(qt, kt, vt, seed, causal, scale, dropout,
-                            interpret=interpret)
+    qt, kt, vt = _hm(q, k, v)
+    with _core():
+        ot, lse = _flash_fwd_hm(qt, kt, vt, seed, causal, scale, dropout,
+                                interpret=interpret)
     return _hm(ot), (qt, kt, vt, ot, lse, seed)
 
 
 def _fa_bwd(causal, scale, dropout, interpret, res, g):
     qt, kt, vt, ot, lse, seed = res
-    dq, dk, dv = _flash_bwd_hm(qt, kt, vt, ot, lse, _hm(g), seed, causal,
-                               scale, dropout, interpret=interpret)
+    gt = _hm(g)
+    with _core():
+        dq, dk, dv = _flash_bwd_hm(qt, kt, vt, ot, lse, gt, seed, causal,
+                                   scale, dropout, interpret=interpret)
     dseed = np.zeros((1,), jax.dtypes.float0)
-    return _hm(dq), _hm(dk), _hm(dv), dseed
+    return (*_hm(dq, dk, dv), dseed)
 
 
 _fa_value.defvjp(_fa_fwd, _fa_bwd)

@@ -12,8 +12,10 @@ from __future__ import annotations
 from collections import defaultdict
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 
+from ..base import regions
 from ..base.enforce import enforce
 from ..core.tensor import Tensor
 from .lr import LRScheduler
@@ -90,34 +92,36 @@ class Optimizer:
         return out
 
     def step(self):
-        pgs = self._collect_params_grads()
-        pg_for_clip = [(p, g) for p, g, _ in pgs if g is not None]
-        if self._grad_clip is not None:
-            clipped = self._grad_clip(pg_for_clip)
-        else:
-            clipped = pg_for_clip
-        clip_map = {id(p): g for p, g in clipped}
-        self._step_tensor._replace_value(self._step_tensor._value + 1)
-        lr = self._lr_override if self._lr_override is not None else self.get_lr()
-        # zero1 sharded weight update: when engaged (TrainStep override /
-        # FLAGS_sharding_stage / group_sharded_parallel) every eligible
-        # parameter's update runs in its 1/dp shard space — grad clipping
-        # above stays on the full gradients, so clip semantics are
-        # identical across tiers
-        from ..distributed.sharding import zero1 as _zero1
-
-        spec = _zero1.step_spec(self)
-        strategy = _zero1.ensure_strategy(self) if spec is not None else None
-        for p, _, group in pgs:
-            g = clip_map.get(id(p))
-            if g is None:
-                continue
-            group_lr = lr * p.optimize_attr.get("learning_rate", 1.0) * group.get("learning_rate", 1.0)
-            wd = group.get("weight_decay", self._weight_decay)
-            if strategy is not None:
-                strategy.apply_one(self, p, g, group_lr, wd, spec)
+        # one region: clipping and every parameter's update rule
+        with regions.region(regions.OPTIMIZER):
+            pgs = self._collect_params_grads()
+            pg_for_clip = [(p, g) for p, g, _ in pgs if g is not None]
+            if self._grad_clip is not None:
+                clipped = self._grad_clip(pg_for_clip)
             else:
-                self._apply_one(p, g, group_lr, wd)
+                clipped = pg_for_clip
+            clip_map = {id(p): g for p, g in clipped}
+            self._step_tensor._replace_value(self._step_tensor._value + 1)
+            lr = self._lr_override if self._lr_override is not None else self.get_lr()
+            # zero1 sharded weight update: when engaged (TrainStep override /
+            # FLAGS_sharding_stage / group_sharded_parallel) every eligible
+            # parameter's update runs in its 1/dp shard space — grad clipping
+            # above stays on the full gradients, so clip semantics are
+            # identical across tiers
+            from ..distributed.sharding import zero1 as _zero1
+
+            spec = _zero1.step_spec(self)
+            strategy = _zero1.ensure_strategy(self) if spec is not None else None
+            for p, _, group in pgs:
+                g = clip_map.get(id(p))
+                if g is None:
+                    continue
+                group_lr = lr * p.optimize_attr.get("learning_rate", 1.0) * group.get("learning_rate", 1.0)
+                wd = group.get("weight_decay", self._weight_decay)
+                if strategy is not None:
+                    strategy.apply_one(self, p, g, group_lr, wd, spec)
+                else:
+                    self._apply_one(p, g, group_lr, wd)
 
     def _apply_one(self, p: Tensor, g: Tensor, lr, weight_decay):
         raise NotImplementedError

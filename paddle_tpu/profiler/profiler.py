@@ -175,11 +175,14 @@ class Profiler:
 
             self._device_trace_dir = self._device_trace_dir or os.path.join(
                 os.getcwd(), "profiler_log", f"xla_{int(time.time())}")
-            # capture-boundary stamp for the unified-timeline fusion below
-            self._device_t0_us = time.perf_counter() * 1e6
             try:
                 jax.profiler.start_trace(self._device_trace_dir)
                 self._device_active = True
+                # the one point both clocks name, for the unified-timeline
+                # fusion below
+                from ..observability.tracing import sync_annotation
+
+                self._device_sync_us = sync_annotation()
             except Exception as e:  # already-active tracer etc.
                 get_logger().warning("jax trace not started: %s", e)
 
@@ -200,7 +203,7 @@ class Profiler:
             if tracer.enabled:
                 tracer.ingest_device_trace_dir(
                     self._device_trace_dir,
-                    getattr(self, "_device_t0_us", 0.0))
+                    getattr(self, "_device_sync_us", 0.0))
 
     # -------------------------------------------------------------- state
     def _sync_op_hook(self):

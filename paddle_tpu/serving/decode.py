@@ -24,6 +24,12 @@ Decoding is greedy (argmax), which makes the bit-exactness contract
 testable: the tokens a request receives are identical whether it decoded
 alone or joined a full batch mid-flight (per-lane math touches only the
 lane's own slot; masked pad columns contribute exact zeros).
+
+Every program body runs under the regions of ``base/regions.py`` — a root
+per program (``prefill``/``decode``/``draft``/``verify``) and, inside, the
+serving vocabulary (``embed`` ... ``attn/kv_gather`` ... ``sample``). The
+names are HLO metadata only (the program computes the same); a device
+trace carries them as each operation's ``tf_op``.
 """
 from __future__ import annotations
 
@@ -32,7 +38,9 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..base import regions
 from ..base.flags import get_flag
+from ..base.regions import region
 from ..observability.locks import named_lock
 from ..profiler.pipeline import serving_stats
 from . import kv_cache as kvc
@@ -89,6 +97,16 @@ def _ln(x, w, b, eps):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     return (x - mean) * lax.rsqrt(var + eps) * w + b
+
+
+def _mlp(x, blk, eps):
+    """The block's second half: LN, the two projections, the residual."""
+    import jax
+
+    with region(regions.MLP):
+        h2 = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
+        return x + jax.nn.gelu(h2 @ blk["fc1_w"] + blk["fc1_b"],
+                               approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
 
 
 class DecodePrograms:
@@ -161,10 +179,11 @@ class DecodePrograms:
 
     # ------------------------------------------------------------ programs
     def _logits_head(self, params, x):
-        import jax.numpy as jnp
-
-        w = params["wte"].T if self._tied else params["head_w"]
-        return x @ w
+        """Final LN + the (tied) output projection of ``x``."""
+        with region(regions.LM_HEAD):
+            hfin = _ln(x, params["lnf_w"], params["lnf_b"], self._eps)
+            w = params["wte"].T if self._tied else params["head_w"]
+            return hfin @ w
 
     def _prefill_trunk(self, params, tokens, lengths):
         """The prefill transformer body shared by the slot and paged
@@ -178,86 +197,95 @@ class DecodePrograms:
         self.traces += 1  # runs under trace only: the recompile proof
         B, S = tokens.shape
         eps = self._eps
-        x = params["wte"][tokens] + params["wpe"][:S][None, :, :]
+        with region(regions.EMBED):
+            x = params["wte"][tokens] + params["wpe"][:S][None, :, :]
         ks, vs = [], []
         for blk in params["blocks"]:
-            h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-            qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
-                B, S, self._heads, 3, self._head_dim)
-            q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+            with region(regions.ATTN_QKV):
+                h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
+                qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
+                    B, S, self._heads, 3, self._head_dim)
+                q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
             ks.append(k)
             vs.append(v)
-            logits = jnp.einsum("bshd,bthd->bhst", q, k) * self._scale
-            causal = jnp.tril(jnp.ones((S, S), bool))
-            logits = jnp.where(causal[None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(x.dtype)
-            att = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(
-                B, S, self._hidden)
-            x = x + att @ blk["out_w"] + blk["out_b"]
-            h2 = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-            x = x + jax.nn.gelu(h2 @ blk["fc1_w"] + blk["fc1_b"],
-                                approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
+            with region(regions.ATTN_CORE):
+                logits = jnp.einsum("bshd,bthd->bhst", q, k) * self._scale
+                causal = jnp.tril(jnp.ones((S, S), bool))
+                logits = jnp.where(causal[None, None], logits, -1e30)
+                probs = jax.nn.softmax(logits.astype(jnp.float32),
+                                       axis=-1).astype(x.dtype)
+                att = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(
+                    B, S, self._hidden)
+            with region(regions.ATTN_OUT):
+                x = x + att @ blk["out_w"] + blk["out_b"]
+            x = _mlp(x, blk, eps)
         # each lane's next token comes from its LAST REAL position (rows
         # past the prompt are garbage, never attended by real rows)
-        idx = (lengths - 1).astype(jnp.int32)
-        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-        hfin = _ln(x_last, params["lnf_w"], params["lnf_b"], eps)
-        head = self._logits_head(params, hfin)
-        krows = jnp.stack(ks)  # [layers, B, S, heads, head_dim]
-        vrows = jnp.stack(vs)
+        with region(regions.LM_HEAD):
+            idx = (lengths - 1).astype(jnp.int32)
+            x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        head = self._logits_head(params, x_last)
+        with region(regions.ATTN_KV_WRITE):
+            krows = jnp.stack(ks)  # [layers, B, S, heads, head_dim]
+            vrows = jnp.stack(vs)
         return head, krows, vrows
 
     def _prefill_fn(self, params, ck, cv, tokens, lengths, slot_ids):
         import jax.numpy as jnp
 
-        B = tokens.shape[0]
-        head, krows, vrows = self._prefill_trunk(params, tokens, lengths)
-        next_tok = jnp.argmax(head, axis=-1).astype(jnp.int32)
-        if B == 1:
-            # interactive path: one dynamic_update_slice per buffer
-            ck = kvc.write_prompt(ck, slot_ids[0], krows[:, 0])
-            cv = kvc.write_prompt(cv, slot_ids[0], vrows[:, 0])
-        else:
-            ck = kvc.write_prompt_batch(ck, slot_ids, krows)
-            cv = kvc.write_prompt_batch(cv, slot_ids, vrows)
-        return ck, cv, next_tok
+        with region(regions.PREFILL):
+            B = tokens.shape[0]
+            head, krows, vrows = self._prefill_trunk(params, tokens, lengths)
+            with region(regions.SAMPLE):
+                next_tok = jnp.argmax(head, axis=-1).astype(jnp.int32)
+            if B == 1:
+                # interactive path: one dynamic_update_slice per buffer
+                ck = kvc.write_prompt(ck, slot_ids[0], krows[:, 0])
+                cv = kvc.write_prompt(cv, slot_ids[0], vrows[:, 0])
+            else:
+                ck = kvc.write_prompt_batch(ck, slot_ids, krows)
+                cv = kvc.write_prompt_batch(cv, slot_ids, vrows)
+            return ck, cv, next_tok
 
     def _decode_fn(self, params, ck, cv, tokens, slot_ids, positions):
         import jax
         import jax.numpy as jnp
 
         self.traces += 1
-        B = tokens.shape[0]
-        eps = self._eps
-        x = params["wte"][tokens] + params["wpe"][positions]
-        col = jnp.arange(self.pool.max_seq)
-        for li, blk in enumerate(params["blocks"]):
-            h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-            qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
-                B, self._heads, 3, self._head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            # write this token's K/V at (layer, slot, position), then
-            # attend over the slot's rows 0..position inclusive
-            ck = kvc.append_token(ck, li, slot_ids, positions, k)
-            cv = kvc.append_token(cv, li, slot_ids, positions, v)
-            keys = ck[li, slot_ids]    # [B, max_seq, heads, head_dim]
-            vals = cv[li, slot_ids]
-            logits = jnp.einsum("bhd,bthd->bht", q, keys) * self._scale
-            mask = col[None, None, :] <= positions[:, None, None]
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(x.dtype)
-            att = jnp.einsum("bht,bthd->bhd", probs, vals).reshape(
-                B, self._hidden)
-            x = x + att @ blk["out_w"] + blk["out_b"]
-            h2 = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-            x = x + jax.nn.gelu(h2 @ blk["fc1_w"] + blk["fc1_b"],
-                                approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
-        hfin = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-        next_tok = jnp.argmax(self._logits_head(params, hfin),
-                              axis=-1).astype(jnp.int32)
-        return ck, cv, next_tok
+        with region(regions.DECODE):
+            B = tokens.shape[0]
+            eps = self._eps
+            with region(regions.EMBED):
+                x = params["wte"][tokens] + params["wpe"][positions]
+            col = jnp.arange(self.pool.max_seq)
+            for li, blk in enumerate(params["blocks"]):
+                with region(regions.ATTN_QKV):
+                    h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
+                    qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
+                        B, self._heads, 3, self._head_dim)
+                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                # write this token's K/V at (layer, slot, position), then
+                # attend over the slot's rows 0..position inclusive
+                ck = kvc.append_token(ck, li, slot_ids, positions, k)
+                cv = kvc.append_token(cv, li, slot_ids, positions, v)
+                with region(regions.ATTN_KV_GATHER):
+                    keys = ck[li, slot_ids]  # [B, max_seq, heads, head_dim]
+                    vals = cv[li, slot_ids]
+                with region(regions.ATTN_CORE):
+                    logits = jnp.einsum("bhd,bthd->bht", q, keys) * self._scale
+                    mask = col[None, None, :] <= positions[:, None, None]
+                    logits = jnp.where(mask, logits, -1e30)
+                    probs = jax.nn.softmax(logits.astype(jnp.float32),
+                                           axis=-1).astype(x.dtype)
+                    att = jnp.einsum("bht,bthd->bhd", probs, vals).reshape(
+                        B, self._hidden)
+                with region(regions.ATTN_OUT):
+                    x = x + att @ blk["out_w"] + blk["out_b"]
+                x = _mlp(x, blk, eps)
+            head = self._logits_head(params, x)
+            with region(regions.SAMPLE):
+                next_tok = jnp.argmax(head, axis=-1).astype(jnp.int32)
+            return ck, cv, next_tok
 
     # ------------------------------------------------------------- rungs
     @property
@@ -521,45 +549,48 @@ class PagedDecodePrograms(DecodePrograms):
         import jax
         import jax.numpy as jnp
 
-        greedy = jnp.argmax(head, axis=-1).astype(jnp.int32)
-        V = head.shape[-1]
+        with region(regions.SAMPLE):
+            greedy = jnp.argmax(head, axis=-1).astype(jnp.int32)
+            V = head.shape[-1]
 
-        def lane(lg, temp, tk, tp, key):
-            lg = lg.astype(jnp.float32)
-            scaled = lg / jnp.where(temp > 0, temp, 1.0)
-            srt = jnp.sort(scaled)[::-1]  # descending
-            rank = jnp.arange(V)
-            k_eff = jnp.clip(jnp.where(tk > 0, tk, V), 1, V)
-            probs = jax.nn.softmax(srt)
-            p_eff = jnp.where((tp > 0.0) & (tp < 1.0), tp, 1.0)
-            # both filters are prefixes of the sort: kept set = prefix,
-            # cutoff = the smallest kept value (rank 0 is always kept)
-            keep = (rank < k_eff) & (jnp.cumsum(probs) - probs < p_eff)
-            cutoff = jnp.min(jnp.where(keep, srt, jnp.inf))
-            filtered = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
-            return jax.random.categorical(key, filtered).astype(jnp.int32)
+            def lane(lg, temp, tk, tp, key):
+                lg = lg.astype(jnp.float32)
+                scaled = lg / jnp.where(temp > 0, temp, 1.0)
+                srt = jnp.sort(scaled)[::-1]  # descending
+                rank = jnp.arange(V)
+                k_eff = jnp.clip(jnp.where(tk > 0, tk, V), 1, V)
+                probs = jax.nn.softmax(srt)
+                p_eff = jnp.where((tp > 0.0) & (tp < 1.0), tp, 1.0)
+                # both filters are prefixes of the sort: kept set = prefix,
+                # cutoff = the smallest kept value (rank 0 is always kept)
+                keep = (rank < k_eff) & (jnp.cumsum(probs) - probs < p_eff)
+                cutoff = jnp.min(jnp.where(keep, srt, jnp.inf))
+                filtered = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+                return jax.random.categorical(key, filtered).astype(jnp.int32)
 
-        sampled = jax.vmap(lane)(head, temps, top_ks, top_ps, rkeys)
-        return jnp.where(temps > 0, sampled, greedy)
+            sampled = jax.vmap(lane)(head, temps, top_ks, top_ps, rkeys)
+            return jnp.where(temps > 0, sampled, greedy)
 
     # ----------------------------------------------------------- programs
     def _prefill_fn(self, params, ck, cv, tokens, lengths, tables,
                     temps, top_ks, top_ps, rkeys):
         import jax.numpy as jnp
 
-        head, krows, vrows = self._prefill_trunk(params, tokens, lengths)
-        next_tok = self._choose_tokens(head, temps, top_ks, top_ps, rkeys)
-        # pad the prompt rows up to whole pages; the surplus rows route
-        # through table entries past the lane's real pages (pad page 0)
-        S = krows.shape[2]
-        want = tables.shape[1] * self.pool.page_size
-        if want > S:
-            padw = ((0, 0), (0, 0), (0, want - S), (0, 0), (0, 0))
-            krows = jnp.pad(krows, padw)
-            vrows = jnp.pad(vrows, padw)
-        ck = kvc.write_prompt_pages(ck, tables, krows)
-        cv = kvc.write_prompt_pages(cv, tables, vrows)
-        return ck, cv, next_tok
+        with region(regions.PREFILL):
+            head, krows, vrows = self._prefill_trunk(params, tokens, lengths)
+            next_tok = self._choose_tokens(head, temps, top_ks, top_ps, rkeys)
+            # pad the prompt rows up to whole pages; the surplus rows route
+            # through table entries past the lane's real pages (pad page 0)
+            S = krows.shape[2]
+            want = tables.shape[1] * self.pool.page_size
+            if want > S:
+                padw = ((0, 0), (0, 0), (0, want - S), (0, 0), (0, 0))
+                with region(regions.ATTN_KV_WRITE):
+                    krows = jnp.pad(krows, padw)
+                    vrows = jnp.pad(vrows, padw)
+            ck = kvc.write_prompt_pages(ck, tables, krows)
+            cv = kvc.write_prompt_pages(cv, tables, vrows)
+            return ck, cv, next_tok
 
     def _paged_step_trunk(self, params, ck, cv, tokens, tables, positions,
                           *, bounded=False):
@@ -583,52 +614,58 @@ class PagedDecodePrograms(DecodePrograms):
         B, T = tables.shape
         ps = self.pool.page_size
         eps = self._eps
-        if bounded:
-            x = (params["wte"][tokens]
-                 + params["wpe"][jnp.minimum(positions, self._max_pos - 1)])
-        else:
-            x = params["wte"][tokens] + params["wpe"][positions]
+        with region(regions.EMBED):
+            if bounded:
+                x = (params["wte"][tokens]
+                     + params["wpe"][jnp.minimum(positions,
+                                                 self._max_pos - 1)])
+            else:
+                x = params["wte"][tokens] + params["wpe"][positions]
         # the traced table maps token position -> page: column j of the
         # gathered view IS position j, so the slot program's mask and
         # softmax carry over unchanged (bit-exact greedy contract)
         col = jnp.arange(T * ps)
-        page_idx = (positions // ps).astype(jnp.int32)
-        if bounded:
-            page_idx = jnp.minimum(page_idx, T - 1)
-        pages = jnp.take_along_axis(tables, page_idx[:, None], axis=1)[:, 0]
-        if bounded:
-            pages = jnp.where(positions < self.max_seq, pages, 0)
-        offsets = (positions % ps).astype(jnp.int32)
+        with region(regions.ATTN_KV_WRITE):
+            page_idx = (positions // ps).astype(jnp.int32)
+            if bounded:
+                page_idx = jnp.minimum(page_idx, T - 1)
+            pages = jnp.take_along_axis(tables, page_idx[:, None],
+                                        axis=1)[:, 0]
+            if bounded:
+                pages = jnp.where(positions < self.max_seq, pages, 0)
+            offsets = (positions % ps).astype(jnp.int32)
         for li, blk in enumerate(params["blocks"]):
-            h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-            qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
-                B, self._heads, 3, self._head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            with region(regions.ATTN_QKV):
+                h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
+                qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
+                    B, self._heads, 3, self._head_dim)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             ck = kvc.append_token_paged(ck, li, pages, offsets, k)
             cv = kvc.append_token_paged(cv, li, pages, offsets, v)
             keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h, d]
             vals = kvc.gather_pages(cv, li, tables)
-            logits = jnp.einsum("bhd,bthd->bht", q, keys) * self._scale
-            mask = col[None, None, :] <= positions[:, None, None]
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(x.dtype)
-            att = jnp.einsum("bht,bthd->bhd", probs, vals).reshape(
-                B, self._hidden)
-            x = x + att @ blk["out_w"] + blk["out_b"]
-            h2 = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-            x = x + jax.nn.gelu(h2 @ blk["fc1_w"] + blk["fc1_b"],
-                                approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
-        hfin = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-        return ck, cv, self._logits_head(params, hfin)
+            with region(regions.ATTN_CORE):
+                logits = jnp.einsum("bhd,bthd->bht", q, keys) * self._scale
+                mask = col[None, None, :] <= positions[:, None, None]
+                logits = jnp.where(mask, logits, -1e30)
+                probs = jax.nn.softmax(logits.astype(jnp.float32),
+                                       axis=-1).astype(x.dtype)
+                att = jnp.einsum("bht,bthd->bhd", probs, vals).reshape(
+                    B, self._hidden)
+            with region(regions.ATTN_OUT):
+                x = x + att @ blk["out_w"] + blk["out_b"]
+            x = _mlp(x, blk, eps)
+        return ck, cv, self._logits_head(params, x)
 
     def _decode_fn(self, params, ck, cv, tokens, tables, positions,
                    temps, top_ks, top_ps, rkeys):
         self.traces += 1
-        ck, cv, head = self._paged_step_trunk(params, ck, cv, tokens,
-                                              tables, positions)
-        next_tok = self._choose_tokens(head, temps, top_ks, top_ps, rkeys)
-        return ck, cv, next_tok
+        with region(regions.DECODE):
+            ck, cv, head = self._paged_step_trunk(params, ck, cv, tokens,
+                                                  tables, positions)
+            next_tok = self._choose_tokens(head, temps, top_ks, top_ps,
+                                           rkeys)
+            return ck, cv, next_tok
 
     @staticmethod
     def _shift_keys(rkeys, j):
@@ -654,15 +691,16 @@ class PagedDecodePrograms(DecodePrograms):
         import jax.numpy as jnp
 
         self.traces += 1
-        tok, pos, drafts = tokens, positions, []
-        for j in range(self.speculate_k):
-            ck, cv, head = self._paged_step_trunk(
-                params, ck, cv, tok, tables, pos, bounded=True)
-            tok = self._choose_tokens(head, temps, top_ks, top_ps,
-                                      self._shift_keys(rkeys, j))
-            drafts.append(tok)
-            pos = pos + 1
-        return ck, cv, jnp.stack(drafts, axis=1)
+        with region(regions.DRAFT):
+            tok, pos, drafts = tokens, positions, []
+            for j in range(self.speculate_k):
+                ck, cv, head = self._paged_step_trunk(
+                    params, ck, cv, tok, tables, pos, bounded=True)
+                tok = self._choose_tokens(head, temps, top_ks, top_ps,
+                                          self._shift_keys(rkeys, j))
+                drafts.append(tok)
+                pos = pos + 1
+            return ck, cv, jnp.stack(drafts, axis=1)
 
     def _verify_fn(self, params, ck, cv, tokens, tables, positions,
                    temps, top_ks, top_ps, rkeys):
@@ -679,45 +717,48 @@ class PagedDecodePrograms(DecodePrograms):
         import jax.numpy as jnp
 
         self.traces += 1
-        B, K1 = tokens.shape
-        T = tables.shape[1]
-        ps = self.pool.page_size
-        eps = self._eps
-        pos = positions[:, None] + jnp.arange(K1, dtype=jnp.int32)[None, :]
-        x = (params["wte"][tokens]
-             + params["wpe"][jnp.minimum(pos, self._max_pos - 1)])
-        col = jnp.arange(T * ps)
-        page_idx = jnp.minimum((pos // ps).astype(jnp.int32), T - 1)
-        pages = jnp.take_along_axis(tables, page_idx, axis=1)
-        pages = jnp.where(pos < self.max_seq, pages, 0)  # pad-page spill
-        offsets = (pos % ps).astype(jnp.int32)
-        # [B, heads, K1 queries, T*ps cols]: query j sees cols <= p+j
-        mask = col[None, None, None, :] <= pos[:, None, :, None]
-        for li, blk in enumerate(params["blocks"]):
-            h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-            qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
-                B, K1, self._heads, 3, self._head_dim)
-            q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-            ck = kvc.append_token_paged(ck, li, pages, offsets, k)
-            cv = kvc.append_token_paged(cv, li, pages, offsets, v)
-            keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h, d]
-            vals = kvc.gather_pages(cv, li, tables)
-            logits = jnp.einsum("bshd,bthd->bhst", q, keys) * self._scale
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(x.dtype)
-            att = jnp.einsum("bhst,bthd->bshd", probs, vals).reshape(
-                B, K1, self._hidden)
-            x = x + att @ blk["out_w"] + blk["out_b"]
-            h2 = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-            x = x + jax.nn.gelu(h2 @ blk["fc1_w"] + blk["fc1_b"],
-                                approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
-        hfin = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-        head = self._logits_head(params, hfin)  # [B, K1, V]
-        vtoks = [self._choose_tokens(head[:, j], temps, top_ks, top_ps,
-                                     self._shift_keys(rkeys, j))
-                 for j in range(K1)]
-        return ck, cv, jnp.stack(vtoks, axis=1)
+        with region(regions.VERIFY):
+            B, K1 = tokens.shape
+            T = tables.shape[1]
+            ps = self.pool.page_size
+            eps = self._eps
+            pos = positions[:, None] + jnp.arange(K1, dtype=jnp.int32)[None, :]
+            with region(regions.EMBED):
+                x = (params["wte"][tokens]
+                     + params["wpe"][jnp.minimum(pos, self._max_pos - 1)])
+            col = jnp.arange(T * ps)
+            with region(regions.ATTN_KV_WRITE):
+                page_idx = jnp.minimum((pos // ps).astype(jnp.int32), T - 1)
+                pages = jnp.take_along_axis(tables, page_idx, axis=1)
+                pages = jnp.where(pos < self.max_seq, pages, 0)  # pad-page spill
+                offsets = (pos % ps).astype(jnp.int32)
+            # [B, heads, K1 queries, T*ps cols]: query j sees cols <= p+j
+            mask = col[None, None, None, :] <= pos[:, None, :, None]
+            for li, blk in enumerate(params["blocks"]):
+                with region(regions.ATTN_QKV):
+                    h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
+                    qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
+                        B, K1, self._heads, 3, self._head_dim)
+                    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+                ck = kvc.append_token_paged(ck, li, pages, offsets, k)
+                cv = kvc.append_token_paged(cv, li, pages, offsets, v)
+                keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h, d]
+                vals = kvc.gather_pages(cv, li, tables)
+                with region(regions.ATTN_CORE):
+                    logits = jnp.einsum("bshd,bthd->bhst", q, keys) * self._scale
+                    logits = jnp.where(mask, logits, -1e30)
+                    probs = jax.nn.softmax(logits.astype(jnp.float32),
+                                           axis=-1).astype(x.dtype)
+                    att = jnp.einsum("bhst,bthd->bshd", probs, vals).reshape(
+                        B, K1, self._hidden)
+                with region(regions.ATTN_OUT):
+                    x = x + att @ blk["out_w"] + blk["out_b"]
+                x = _mlp(x, blk, eps)
+            head = self._logits_head(params, x)  # [B, K1, V]
+            vtoks = [self._choose_tokens(head[:, j], temps, top_ks, top_ps,
+                                         self._shift_keys(rkeys, j))
+                     for j in range(K1)]
+            return ck, cv, jnp.stack(vtoks, axis=1)
 
     # -------------------------------------------------------------- rungs
     def _prefill_table_cols(self, seq_rung: int) -> int:

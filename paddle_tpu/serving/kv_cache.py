@@ -28,6 +28,8 @@ from typing import Iterable, List, Optional
 
 import numpy as np
 
+from ..base import regions
+from ..base.regions import region
 from ..observability.locks import named_lock
 
 __all__ = ["KVSlotPool", "KVPagePool", "write_prompt",
@@ -44,11 +46,12 @@ def write_prompt(cache, slot, rows):
     import jax.lax as lax
     import jax.numpy as jnp
 
-    return lax.dynamic_update_slice(
-        cache, rows[:, None].astype(cache.dtype),
-        (jnp.zeros((), jnp.int32), jnp.asarray(slot, jnp.int32),
-         jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
-         jnp.zeros((), jnp.int32)))
+    with region(regions.ATTN_KV_WRITE):
+        return lax.dynamic_update_slice(
+            cache, rows[:, None].astype(cache.dtype),
+            (jnp.zeros((), jnp.int32), jnp.asarray(slot, jnp.int32),
+             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
+             jnp.zeros((), jnp.int32)))
 
 
 def write_prompt_batch(cache, slot_ids, rows):
@@ -58,15 +61,17 @@ def write_prompt_batch(cache, slot_ids, rows):
     safe by construction: decode overwrites position ``len`` before any
     step attends to it."""
     S = rows.shape[2]
-    return cache.at[:, slot_ids, :S].set(rows.astype(cache.dtype))
+    with region(regions.ATTN_KV_WRITE):
+        return cache.at[:, slot_ids, :S].set(rows.astype(cache.dtype))
 
 
 def append_token(cache, layer, slot_ids, positions, rows):
     """One decode step's write for one layer: ``rows`` is ``[B, heads,
     dim]`` landing at ``(layer, slot_ids[b], positions[b])``. Pad lanes
     point at the pool's pad slot so the scatter needs no mask."""
-    return cache.at[layer, slot_ids, positions].set(
-        rows.astype(cache.dtype))
+    with region(regions.ATTN_KV_WRITE):
+        return cache.at[layer, slot_ids, positions].set(
+            rows.astype(cache.dtype))
 
 
 # ------------------------------------------------- paged functional updates
@@ -80,8 +85,9 @@ def write_prompt_pages(cache, tables, rows):
     L, B, _, H, D = rows.shape
     T = tables.shape[1]
     ps = cache.shape[2]
-    paged = rows.astype(cache.dtype).reshape(L, B, T, ps, H, D)
-    return cache.at[:, tables].set(paged)
+    with region(regions.ATTN_KV_WRITE):
+        paged = rows.astype(cache.dtype).reshape(L, B, T, ps, H, D)
+        return cache.at[:, tables].set(paged)
 
 
 def append_token_paged(cache, layer, pages, offsets, rows):
@@ -89,7 +95,8 @@ def append_token_paged(cache, layer, pages, offsets, rows):
     heads, dim]`` landing at ``(layer, pages[b], offsets[b])`` where
     ``pages[b] = table[b, pos // page_size]`` and ``offsets[b] = pos %
     page_size`` — both traced. Pad lanes carry page 0."""
-    return cache.at[layer, pages, offsets].set(rows.astype(cache.dtype))
+    with region(regions.ATTN_KV_WRITE):
+        return cache.at[layer, pages, offsets].set(rows.astype(cache.dtype))
 
 
 def gather_pages(cache, layer, tables):
@@ -100,7 +107,8 @@ def gather_pages(cache, layer, tables):
     compiled program serves ANY page map because the table is data."""
     B, T = tables.shape
     ps, H, D = cache.shape[2], cache.shape[3], cache.shape[4]
-    return cache[layer][tables].reshape(B, T * ps, H, D)
+    with region(regions.ATTN_KV_GATHER):
+        return cache[layer][tables].reshape(B, T * ps, H, D)
 
 
 # --------------------------------------------------------------- the pool

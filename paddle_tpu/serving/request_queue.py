@@ -10,7 +10,8 @@ requests: a tenant streaming batch-32 requests spends its budget 32x
 faster than one sending singletons.
 
 Every request carries its phase timestamps (enqueue → admit → dispatch →
-complete, ``time.perf_counter`` space); completion hands them to
+complete, ``time.perf_counter`` space; a :class:`DecodeRequest` also its
+first token's); completion hands them to
 ``profiler.pipeline.serving_stats`` so the latency accounting rides the
 same observability channel as the train-loop pipeline stats.
 """
@@ -128,7 +129,8 @@ class DecodeRequest(Request):
 
     __slots__ = ("prompt", "max_new_tokens", "generated", "slot", "seq_rung",
                  "pages", "temperature", "top_k", "top_p", "seed",
-                 "speculate", "spec_live", "spec_proposed", "spec_accepted")
+                 "speculate", "spec_live", "spec_proposed", "spec_accepted",
+                 "t_first_token")
 
     def __init__(self, tenant: str, prompt, max_new_tokens: int,
                  temperature: float = 0.0, top_k: int = 0,
@@ -141,6 +143,10 @@ class DecodeRequest(Request):
         self.prompt = prompt
         self.max_new_tokens = max(int(max_new_tokens), 1)
         self.generated: List[int] = []
+        # host time (perf_counter) at which the first entry of
+        # ``generated`` reached the host (the prefill beat's stamp):
+        # t_enqueue <= t_dispatch <= t_first_token <= t_complete.
+        self.t_first_token: Optional[float] = None
         self.slot = None          # KV slot, assigned at admission-to-slot
         self.seq_rung = None      # prefill seq-ladder rung (scheduler set)
         self.pages: List[int] = []  # block table (paged pools only)

@@ -21,7 +21,11 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability.tracing import _NULL_SPAN
 from .request_queue import Request, RequestQueue
+
+_TRACK = "serving.scheduler"     # the decode scheduler's beat spans
+_REQUESTS = "serving.requests"   # request phases, sharing request=<id>
 
 
 def stack_requests(requests: Sequence[Request], bucket: int,
@@ -266,6 +270,11 @@ class DecodeScheduler:
         self._active: Dict[int, object] = {}    # slot -> DecodeRequest
         self._pending: List[object] = []        # slot held, prefill due
         self._step_lanes: List[object] = []     # lanes riding the current call
+        self.shed_count = 0
+        self._beat = 0            # running index of scheduler beats
+        self._beat_kind = "idle"  # what this beat ran (set by the step)
+        self._trace = None        # the tracer while this beat records, else None
+        self._beat_id = None      # this beat's span id (parent of request phases)
         self._thread: Optional[threading.Thread] = None
         self._stopped = threading.Event()
 
@@ -315,18 +324,53 @@ class DecodeScheduler:
     def _admit_and_step(self, monitor) -> bool:
         """One scheduler beat: admit queued requests into free slots,
         then run one prefill-or-decode call. Returns False when fully
-        idle (nothing admitted, nothing to step)."""
+        idle (nothing admitted, nothing to step).
+
+        Traced (ONE ``tracer.enabled`` read per beat), a beat is one
+        ``serving.beat`` span (``beat`` = running index, ``kind`` =
+        prefill/decode/speculate/idle) whose children tile it:
+        ``serving.admit`` -> ``serving.build`` -> ``serving.decode``
+        (holding ``serving.dispatch`` and ``serving.read``) ->
+        ``serving.absorb``."""
+        from ..observability.tracing import tracer
+
+        self._beat += 1
+        self._beat_kind = "idle"
+        if not tracer.enabled:
+            self._trace = self._beat_id = None
+            self._admit()
+            return self._step(monitor)
+        self._trace = tracer
+        with tracer.span("serving.beat", track=_TRACK, beat=self._beat,
+                         kind="idle") as beat:
+            self._beat_id = beat.id
+            shed = self.shed_count
+            with tracer.span("serving.admit", track=_TRACK, taken=0,
+                             shed=0) as sp:
+                taken = self._admit()
+                if sp.id is not None:
+                    sp.args.update(taken=taken, shed=self.shed_count - shed)
+            stepped = self._step(monitor)
+            if beat.id is not None:
+                beat.args["kind"] = self._beat_kind
+        return stepped
+
+    def _admit(self) -> int:
+        """Move queued requests into free slots; returns how many."""
         free = self.pool.free_count()
-        if free > 0:
-            idle = not self._active and not self._pending
-            taken = self.queue.take_slots(
-                free, timeout=0.05 if idle else 0.0)
-            now = time.perf_counter()
-            for r in taken:
-                r.slot = self.pool.alloc()
-                r.seq_rung = self._seq_rung(r)
-                r.t_dispatch = now
-                self._pending.append(r)
+        if free <= 0:
+            return 0
+        idle = not self._active and not self._pending
+        taken = self.queue.take_slots(free, timeout=0.05 if idle else 0.0)
+        now = time.perf_counter()
+        for r in taken:
+            r.slot = self.pool.alloc()
+            r.seq_rung = self._seq_rung(r)
+            r.t_dispatch = now
+            self._pending.append(r)
+        return len(taken)
+
+    def _step(self, monitor) -> bool:
         if self._pending:
             self._guarded(self._prefill_step, monitor)
             return True
@@ -334,6 +378,43 @@ class DecodeScheduler:
             self._guarded(self._decode_step, monitor)
             return True
         return False
+
+    def _span(self, name: str, **args):
+        """A child span of this beat on the scheduler's track; the shared
+        no-op when the beat is not recording."""
+        if self._trace is None:
+            return _NULL_SPAN
+        return self._trace.span(name, track=_TRACK, **args)
+
+    def _step_span(self, kind: str, rung, lanes, **args):
+        """The ``serving.decode`` span of one step. Its name and its
+        ``kind``/``rung``/``lanes`` arguments are what the benchmark's
+        readers filter on; ``requests`` (the lane request ids) is built
+        only when recording."""
+        self._beat_kind = kind
+        if self._trace is None:
+            return _NULL_SPAN
+        return self._span("serving.decode", kind=kind, rung=rung,
+                          lanes=len(lanes), requests=[r.id for r in lanes],
+                          **args)
+
+    def _call_and_read(self, program: str, call):
+        """One program call, its pool commit and the host read of its
+        tokens: ``serving.dispatch`` lasts until the call returns (the
+        device may still be running), ``serving.read`` is the wait for
+        the tokens."""
+        with self._span("serving.dispatch", program=program):
+            ck, cv, toks = self._program_call(call)
+        self.pool.commit(ck, cv)
+        with self._span("serving.read", program=program):
+            return np.asarray(toks)
+
+    def _absorb_traced(self, lanes, absorb, *args, **kwargs) -> None:
+        """Run one of the absorb methods under ``serving.absorb``."""
+        with self._span("serving.absorb", retired=0) as sp:
+            absorb(lanes, *args, **kwargs)
+            if sp.id is not None:
+                sp.args["retired"] = sum(1 for r in lanes if r.done())
 
     def _seq_rung(self, r) -> int:
         from ..jit.bucketing import bucket_for
@@ -361,6 +442,40 @@ class DecodeScheduler:
                 self._free_lane(r)
                 self.queue.admission.on_complete(r.tenant, r.n)
                 r._fail(e)
+                self._trace_failed(r, type(e).__name__)
+
+    # ------------------------------------------------- stamps and phases
+    def _first_token(self, r, now: float) -> None:
+        """The request's first token reached the host at ``now`` (always
+        stamped: it is what a TTFT histogram reads). Traced, the request's
+        queue and prefill phases are emitted here — so a request still
+        decoding when a trace window closes has them."""
+        r.t_first_token = now
+        if self._trace is not None:
+            self._trace_queue(r)
+            self._trace.emit(
+                "serving.request.prefill", r.t_dispatch, now - r.t_dispatch,
+                track=_REQUESTS, parent=self._beat_id, request=r.id,
+                prompt=int(r.prompt.size), seq_rung=r.seq_rung)
+
+    def _trace_queue(self, r) -> None:
+        self._trace.emit("serving.request.queue", r.t_enqueue,
+                         r.t_dispatch - r.t_enqueue, track=_REQUESTS,
+                         parent=self._beat_id, request=r.id, tenant=r.tenant)
+
+    def _trace_failed(self, r, reason: str) -> None:
+        """A shed or failed request closes with ``serving.request.failed``
+        from the last phase boundary it reached (its queue phase first,
+        if it never saw a token): a reader of first-token times learns
+        from it how many requests its population lacks, and why."""
+        if self._trace is None:
+            return
+        if r.t_first_token is None and r.t_dispatch is not None:
+            self._trace_queue(r)
+        t0 = r.t_first_token or r.t_dispatch or r.t_enqueue
+        self._trace.emit("serving.request.failed", t0, r.t_complete - t0,
+                         track=_REQUESTS, parent=self._beat_id,
+                         request=r.id, reason=reason)
 
     def _free_lane(self, r) -> None:
         """Detach one request from its KV residency — the single cleanup
@@ -398,59 +513,57 @@ class DecodeScheduler:
     # ------------------------------------------------------------- steps
     def _prefill_step(self) -> None:
         from ..jit.bucketing import bucket_for
-        from ..observability.tracing import tracer
 
-        rung = self._pending[0].seq_rung  # oldest request anchors the rung
-        group = [r for r in self._pending
-                 if r.seq_rung == rung][: self.prefill_max_batch]
-        for r in group:
-            self._pending.remove(r)
-        self._step_lanes = list(group)  # the fault wall's blast radius
-        b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
-        pad = self.pool.pad_slot
-        tokens = np.zeros((b_rung, rung), np.int32)
-        lengths = np.ones(b_rung, np.int32)
-        slots = np.full(b_rung, pad, np.int32)
-        for i, r in enumerate(group):
-            L = int(r.prompt.size)
-            tokens[i, :L] = r.prompt
-            lengths[i] = L
-            slots[i] = r.slot
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            rung = self._pending[0].seq_rung  # oldest request anchors the rung
+            group = [r for r in self._pending
+                     if r.seq_rung == rung][: self.prefill_max_batch]
+            for r in group:
+                self._pending.remove(r)
+            self._step_lanes = list(group)  # the fault wall's blast radius
+            b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
+            pad = self.pool.pad_slot
+            tokens = np.zeros((b_rung, rung), np.int32)
+            lengths = np.ones(b_rung, np.int32)
+            slots = np.full(b_rung, pad, np.int32)
+            for i, r in enumerate(group):
+                L = int(r.prompt.size)
+                tokens[i, :L] = r.prompt
+                lengths[i] = L
+                slots[i] = r.slot
+            if sp.id is not None:
+                sp.args.update(lanes=len(group), rung=(b_rung, rung))
         t0 = time.perf_counter()
-        with tracer.span("serving.decode", track="serving.scheduler",
-                         kind="prefill", rung=(b_rung, rung),
-                         lanes=len(group)):
-            ck, cv, toks = self._program_call(lambda: self.programs.prefill(
+        with self._step_span("prefill", (b_rung, rung), group):
+            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
                 self.pool.k, self.pool.v, tokens, lengths, slots))
-            self.pool.commit(ck, cv)
-            toks = np.asarray(toks)
-        self._absorb(group, toks, kind="prefill",
-                     seconds=time.perf_counter() - t0, rung=(b_rung, rung))
+        self._absorb_traced(group, self._absorb, toks, kind="prefill",
+                            seconds=time.perf_counter() - t0,
+                            rung=(b_rung, rung))
 
     def _decode_step(self) -> None:
         from ..jit.bucketing import bucket_for
-        from ..observability.tracing import tracer
 
-        lanes = sorted(self._active.values(), key=lambda r: r.id)
-        self._step_lanes = list(lanes)  # the fault wall's blast radius
-        b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
-        pad = self.pool.pad_slot
-        tokens = np.zeros(b_rung, np.int32)
-        slots = np.full(b_rung, pad, np.int32)
-        positions = np.zeros(b_rung, np.int32)
-        for i, r in enumerate(lanes):
-            tokens[i] = r.generated[-1]
-            slots[i] = r.slot
-            positions[i] = r.position
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            lanes = sorted(self._active.values(), key=lambda r: r.id)
+            self._step_lanes = list(lanes)  # the fault wall's blast radius
+            b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
+            pad = self.pool.pad_slot
+            tokens = np.zeros(b_rung, np.int32)
+            slots = np.full(b_rung, pad, np.int32)
+            positions = np.zeros(b_rung, np.int32)
+            for i, r in enumerate(lanes):
+                tokens[i] = r.generated[-1]
+                slots[i] = r.slot
+                positions[i] = r.position
+            if sp.id is not None:
+                sp.args.update(lanes=len(lanes), rung=b_rung)
         t0 = time.perf_counter()
-        with tracer.span("serving.decode", track="serving.scheduler",
-                         kind="decode", rung=b_rung, lanes=len(lanes)):
-            ck, cv, toks = self._program_call(lambda: self.programs.decode(
+        with self._step_span("decode", b_rung, lanes):
+            toks = self._call_and_read("decode", lambda: self.programs.decode(
                 self.pool.k, self.pool.v, tokens, slots, positions))
-            self.pool.commit(ck, cv)
-            toks = np.asarray(toks)
-        self._absorb(lanes, toks, kind="decode",
-                     seconds=time.perf_counter() - t0, rung=b_rung)
+        self._absorb_traced(lanes, self._absorb, toks, kind="decode",
+                            seconds=time.perf_counter() - t0, rung=b_rung)
 
     def _absorb(self, lanes, toks, *, kind: str, seconds: float,
                 rung) -> None:
@@ -458,12 +571,15 @@ class DecodeScheduler:
         retire finished sequences (slot released, future resolved), keep
         the rest active for the next step."""
         self._step_lanes = []  # the call succeeded: nothing to fail
+        now = time.perf_counter()  # the first-token stamp of new lanes
         if self.breakers is not None:
             for tenant in {r.tenant for r in lanes}:
                 self.breakers.record_success(tenant)
         for i, r in enumerate(lanes):
             tok = int(toks[i])
             r.generated.append(tok)
+            if r.t_first_token is None:
+                self._first_token(r, now)
             self.pool.lengths[r.slot] = r.position
             done = (len(r.generated) >= r.max_new_tokens
                     or (self.eos_id is not None and tok == self.eos_id)
@@ -544,51 +660,45 @@ class PagedDecodeScheduler(DecodeScheduler):
             if spec_min_accept is None else spec_min_accept)
         self.spec_enabled = self.speculate_k > 0
         # _active is keyed by request id here (no slot identity exists)
-        self.shed_count = 0
         self._starved = set()  # lane ids waiting on a page (gate admission)
 
     # ---------------------------------------------------------- admission
-    def _admit_and_step(self, monitor) -> bool:
+    def _admit(self) -> int:
         free = self.max_lanes - self.active_count()
         # starved active lanes get first claim on freed pages: admitting
         # new prompts while a running lane waits for growth would steal
         # its pages and starve it forever
-        if free > 0 and self.pool.free_count() > 0 and not self._starved:
-            idle = not self._active and not self._pending
-            # page-budget admission gate: a request is taken only when
-            # its PROMPT pages fit the free list right now — one that
-            # merely has to wait for a retirement stays queued (FIFO,
-            # never shed); growth past the prompt is overcommitted by
-            # design and sheds only on true mid-flight exhaustion
-            budget = [self.pool.free_count()]
+        if free <= 0 or self.pool.free_count() <= 0 or self._starved:
+            return 0
+        idle = not self._active and not self._pending
+        # page-budget admission gate: a request is taken only when
+        # its PROMPT pages fit the free list right now — one that
+        # merely has to wait for a retirement stays queued (FIFO,
+        # never shed); growth past the prompt is overcommitted by
+        # design and sheds only on true mid-flight exhaustion
+        budget = [self.pool.free_count()]
 
-            def fits(r):
-                need = -(-int(r.prompt.size) // self.pool.page_size)
-                if need > budget[0]:
-                    return False
-                budget[0] -= need
-                return True
+        def fits(r):
+            need = -(-int(r.prompt.size) // self.pool.page_size)
+            if need > budget[0]:
+                return False
+            budget[0] -= need
+            return True
 
-            taken = self.queue.take_slots(
-                free, timeout=0.05 if idle else 0.0, budget_fn=fits)
-            now = time.perf_counter()
-            for r in taken:
-                r.seq_rung = self._seq_rung(r)
-                r.t_dispatch = now
-                need = -(-int(r.prompt.size) // self.pool.page_size)
-                try:
-                    r.pages = self.pool.alloc(need)
-                except Exception as e:  # noqa: BLE001 — shed, don't crash
-                    self._shed(r, e)
-                    continue
-                self._pending.append(r)
-        if self._pending:
-            self._guarded(self._prefill_step, monitor)
-            return True
-        if self._active:
-            self._guarded(self._decode_step, monitor)
-            return True
-        return False
+        taken = self.queue.take_slots(
+            free, timeout=0.05 if idle else 0.0, budget_fn=fits)
+        now = time.perf_counter()
+        for r in taken:
+            r.seq_rung = self._seq_rung(r)
+            r.t_dispatch = now
+            need = -(-int(r.prompt.size) // self.pool.page_size)
+            try:
+                r.pages = self.pool.alloc(need)
+            except Exception as e:  # noqa: BLE001 — shed, don't crash
+                self._shed(r, e)
+                continue
+            self._pending.append(r)
+        return len(taken)
 
     def _shed(self, r, cause) -> None:
         """Page-allocation failure sheds ONE request: its pages return
@@ -615,6 +725,7 @@ class PagedDecodeScheduler(DecodeScheduler):
         r._fail(AdmissionError(
             "kv_pages",
             f"request {r.id} shed: KV page allocation failed ({cause})"))
+        self._trace_failed(r, "kv_pages")
 
     def _free_lane(self, r) -> None:
         self._active.pop(r.id, None)
@@ -684,70 +795,81 @@ class PagedDecodeScheduler(DecodeScheduler):
 
     def _prefill_step(self) -> None:
         from ..jit.bucketing import bucket_for
-        from ..observability.tracing import tracer
 
-        rung = self._pending[0].seq_rung  # oldest request anchors the rung
-        group = [r for r in self._pending
-                 if r.seq_rung == rung][: self.prefill_max_batch]
-        for r in group:
-            self._pending.remove(r)
-        self._step_lanes = list(group)  # the fault wall's blast radius
-        b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
-        t_cols = self.programs._prefill_table_cols(rung)
-        tokens = np.zeros((b_rung, rung), np.int32)
-        lengths = np.ones(b_rung, np.int32)
-        tables = np.zeros((b_rung, t_cols), np.int32)  # 0 = pad page
-        for i, r in enumerate(group):
-            L = int(r.prompt.size)
-            tokens[i, :L] = r.prompt
-            lengths[i] = L
-            tables[i, :len(r.pages)] = r.pages
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            rung = self._pending[0].seq_rung  # oldest request anchors the rung
+            group = [r for r in self._pending
+                     if r.seq_rung == rung][: self.prefill_max_batch]
+            for r in group:
+                self._pending.remove(r)
+            self._step_lanes = list(group)  # the fault wall's blast radius
+            b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
+            t_cols = self.programs._prefill_table_cols(rung)
+            tokens = np.zeros((b_rung, rung), np.int32)
+            lengths = np.ones(b_rung, np.int32)
+            tables = np.zeros((b_rung, t_cols), np.int32)  # 0 = pad page
+            for i, r in enumerate(group):
+                L = int(r.prompt.size)
+                tokens[i, :L] = r.prompt
+                lengths[i] = L
+                tables[i, :len(r.pages)] = r.pages
+            if sp.id is not None:
+                sp.args.update(lanes=len(group), rung=(b_rung, rung))
         t0 = time.perf_counter()
-        with tracer.span("serving.decode", track="serving.scheduler",
-                         kind="prefill", rung=(b_rung, rung),
-                         lanes=len(group)):
-            ck, cv, toks = self._program_call(lambda: self.programs.prefill(
+        with self._step_span("prefill", (b_rung, rung), group):
+            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
                 self.pool.k, self.pool.v, tokens, lengths, tables,
                 *self._sample_args(group, b_rung)))
-            self.pool.commit(ck, cv)
-            toks = np.asarray(toks)
-        self._absorb(group, toks, kind="prefill",
-                     seconds=time.perf_counter() - t0, rung=(b_rung, rung))
+        self._absorb_traced(group, self._absorb, toks, kind="prefill",
+                            seconds=time.perf_counter() - t0,
+                            rung=(b_rung, rung))
+
+    def _step_inputs(self, lookahead: int = 0):
+        """The lanes ready to step and their program inputs — (lanes,
+        rung, tokens, tables, positions), or None when every active lane
+        sits this beat out. Under ``serving.build``. The sampling
+        arguments are not built here: a plain step assembles them inside
+        its ``serving.decode`` span and a speculation round just before
+        it, where each always did, so the span that ``decode_step_ms``
+        reads keeps measuring what it measured."""
+        from ..jit.bucketing import bucket_for
+
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            lanes = sorted(self._active.values(), key=lambda r: r.id)
+            lanes = self._ensure_pages(lanes, lookahead=lookahead)
+            if not lanes:
+                return None
+            self._step_lanes = list(lanes)  # the fault wall's blast radius
+            b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
+            t_rung = bucket_for(max(len(r.pages) for r in lanes),
+                                self.programs.table_rungs)
+            tokens = np.zeros(b_rung, np.int32)
+            tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
+            positions = np.zeros(b_rung, np.int32)
+            for i, r in enumerate(lanes):
+                tokens[i] = r.generated[-1]
+                tables[i, :len(r.pages)] = r.pages
+                positions[i] = r.position
+            if sp.id is not None:
+                sp.args.update(lanes=len(lanes), rung=(b_rung, t_rung))
+        return lanes, (b_rung, t_rung), tokens, tables, positions
 
     def _decode_step(self) -> None:
-        from ..jit.bucketing import bucket_for
-        from ..observability.tracing import tracer
-
         if (self.speculate_k > 0 and self.spec_enabled
                 and any(r.spec_live for r in self._active.values())):
             self._spec_round()
             return
-        lanes = sorted(self._active.values(), key=lambda r: r.id)
-        lanes = self._ensure_pages(lanes)
-        if not lanes:
+        built = self._step_inputs()
+        if built is None:
             return
-        self._step_lanes = list(lanes)  # the fault wall's blast radius
-        b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
-        t_rung = bucket_for(max(len(r.pages) for r in lanes),
-                            self.programs.table_rungs)
-        tokens = np.zeros(b_rung, np.int32)
-        tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
-        positions = np.zeros(b_rung, np.int32)
-        for i, r in enumerate(lanes):
-            tokens[i] = r.generated[-1]
-            tables[i, :len(r.pages)] = r.pages
-            positions[i] = r.position
+        lanes, rung, tokens, tables, positions = built
         t0 = time.perf_counter()
-        with tracer.span("serving.decode", track="serving.scheduler",
-                         kind="decode", rung=(b_rung, t_rung),
-                         lanes=len(lanes)):
-            ck, cv, toks = self._program_call(lambda: self.programs.decode(
+        with self._step_span("decode", rung, lanes):
+            toks = self._call_and_read("decode", lambda: self.programs.decode(
                 self.pool.k, self.pool.v, tokens, tables, positions,
-                *self._sample_args(lanes, b_rung)))
-            self.pool.commit(ck, cv)
-            toks = np.asarray(toks)
-        self._absorb(lanes, toks, kind="decode",
-                     seconds=time.perf_counter() - t0, rung=(b_rung, t_rung))
+                *self._sample_args(lanes, rung[0])))
+        self._absorb_traced(lanes, self._absorb, toks, kind="decode",
+                            seconds=time.perf_counter() - t0, rung=rung)
 
     def _spec_round(self) -> None:
         """One self-speculation round (ISSUE 20): ONE draft dispatch
@@ -758,47 +880,29 @@ class PagedDecodeScheduler(DecodeScheduler):
         up to k+1, always bitwise the tokens the plain decode loop
         would have produced. Pages grown for the speculative suffix
         roll back through the pool free-list in ``_absorb_spec``."""
-        from ..jit.bucketing import bucket_for
-        from ..observability.tracing import tracer
-
         k = self.speculate_k
-        lanes = sorted(self._active.values(), key=lambda r: r.id)
-        lanes = self._ensure_pages(lanes, lookahead=k)
-        if not lanes:
+        built = self._step_inputs(lookahead=k)
+        if built is None:
             return
-        self._step_lanes = list(lanes)  # the fault wall's blast radius
-        b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
-        t_rung = bucket_for(max(len(r.pages) for r in lanes),
-                            self.programs.table_rungs)
-        tokens = np.zeros(b_rung, np.int32)
-        tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
-        positions = np.zeros(b_rung, np.int32)
-        for i, r in enumerate(lanes):
-            tokens[i] = r.generated[-1]
-            tables[i, :len(r.pages)] = r.pages
-            positions[i] = r.position
-        sample = self._sample_args(lanes, b_rung)
-        with tracer.span("serving.decode", track="serving.scheduler",
-                         kind="speculate", rung=(b_rung, t_rung),
-                         lanes=len(lanes), k=k):
+        lanes, rung, tokens, tables, positions = built
+        sample = self._sample_args(lanes, rung[0])
+        with self._step_span("speculate", rung, lanes, k=k):
             t0 = time.perf_counter()
-            ck, cv, drafts = self._program_call(lambda: self.programs.draft(
+            # [b_rung, k] proposals
+            drafts = self._call_and_read("draft", lambda: self.programs.draft(
                 self.pool.k, self.pool.v, tokens, tables, positions,
                 *sample))
-            self.pool.commit(ck, cv)
-            drafts = np.asarray(drafts)       # [b_rung, k] proposals
             t_draft = time.perf_counter() - t0
-            vin = np.zeros((b_rung, k + 1), np.int32)
+            vin = np.zeros((len(tokens), k + 1), np.int32)
             vin[:, 0] = tokens                # last committed token at p
             vin[:, 1:] = drafts               # proposals at p+1..p+k
             t1 = time.perf_counter()
-            ck, cv, vtoks = self._program_call(lambda: self.programs.verify(
+            # [b_rung, k+1] true tokens
+            vtoks = self._call_and_read("verify", lambda: self.programs.verify(
                 self.pool.k, self.pool.v, vin, tables, positions, *sample))
-            self.pool.commit(ck, cv)
-            vtoks = np.asarray(vtoks)         # [b_rung, k+1] true tokens
             t_verify = time.perf_counter() - t1
-        self._absorb_spec(lanes, drafts, vtoks, t_draft=t_draft,
-                          t_verify=t_verify, rung=(b_rung, t_rung))
+        self._absorb_traced(lanes, self._absorb_spec, drafts, vtoks,
+                            t_draft=t_draft, t_verify=t_verify, rung=rung)
 
     def _absorb_spec(self, lanes, drafts, vtoks, *, t_draft: float,
                      t_verify: float, rung) -> None:
@@ -861,35 +965,21 @@ class PagedDecodeScheduler(DecodeScheduler):
             self.stats.record_spec_round(proposed, accepted, committed)
             self.stats.record_slot_occupancy(self.active_count(),
                                              self.max_lanes)
-        try:
-            from ..observability.metrics import registry
-
-            registry.counter(
-                "serving.spec_rounds",
-                "self-speculation rounds (one draft + one verify "
-                "dispatch each) run by the decode scheduler").inc()
-            registry.counter(
-                "serving.spec_tokens_proposed",
-                "draft tokens proposed by self-speculation "
-                "rounds").inc(proposed)
-            registry.counter(
-                "serving.spec_tokens_accepted",
-                "draft tokens the full-model verify pass accepted "
-                "(the rest rolled back)").inc(accepted)
-        except Exception:
-            pass
         if self.on_step is not None:
             self.on_step("speculate", len(lanes), rung, committed)
 
     def _absorb(self, lanes, toks, *, kind: str, seconds: float,
                 rung) -> None:
         self._step_lanes = []  # the call succeeded: nothing to fail
+        now = time.perf_counter()  # the first-token stamp of new lanes
         if self.breakers is not None:
             for tenant in {r.tenant for r in lanes}:
                 self.breakers.record_success(tenant)
         for i, r in enumerate(lanes):
             tok = int(toks[i])
             r.generated.append(tok)
+            if r.t_first_token is None:
+                self._first_token(r, now)
             done = (len(r.generated) >= r.max_new_tokens
                     or (self.eos_id is not None and tok == self.eos_id)
                     or r.position >= self.max_seq)

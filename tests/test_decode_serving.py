@@ -580,3 +580,247 @@ class TestDecodeAudit:
         finally:
             engine.kv_pool.release(slot)
         assert not any(f.code == "JX333" for f in audit_serving(engine))
+
+
+# ------------------------------------------------- beats, phases and stamps
+_SCHEDULER_SPANS = ("serving.admit", "serving.build", "serving.decode",
+                    "serving.absorb")
+
+
+def _traced_run(model, n=7, **engine_kw):
+    """A tiny paged engine serving `n` requests with the tracer on from
+    before its first beat. Returns (requests, complete events by name)."""
+    from paddle_tpu.observability import tracer
+
+    tracer.reset()
+    was = tracer.enabled
+    tracer.enable()
+    try:
+        eng = _engine(model, page_size=8, **engine_kw).warmup()
+        try:
+            rs = np.random.RandomState(3)
+            reqs = [eng.submit("t", p, max_new_tokens=int(m))
+                    for p, m in zip(_prompts(n, seed=5), rs.randint(2, 9, size=n))]
+            for r in reqs:
+                r.result(60)
+        finally:
+            eng.shutdown(drain=True)
+        events = [e for e in tracer.to_chrome_trace()["traceEvents"]
+                  if e["ph"] == "X"]
+    finally:
+        tracer.enabled = was
+        tracer.reset()
+    # the tracer is the process's: another engine of this module, idling on
+    # its own thread, records its beats too. This engine's are those of its
+    # own short count; everything else is kept by descent from them.
+    own = {e["id"] for e in events if e["name"] == "serving.beat"
+           and e["args"]["beat"] <= eng._scheduler._beat}
+    assert len(own) == eng._scheduler._beat
+    for e in sorted(events, key=lambda e: e["id"]):  # a parent's id is lower
+        if e["parent"] in own:
+            own.add(e["id"])
+    return reqs, [e for e in events if e["id"] in own]
+
+
+@pytest.fixture(scope="module", params=[0, 2], ids=["plain", "speculate"])
+def traced(request):
+    model = _tiny_model(num_hidden_layers=2)
+    return _traced_run(model, speculate_k=request.param, spec_draft_layers=1)
+
+
+class TestSchedulerTracing:
+    def test_every_scheduler_span_has_an_id_and_a_parent_field(self, traced):
+        _, events = traced
+        ids = [e["id"] for e in events]
+        assert len(set(ids)) == len(ids)
+        assert all("parent" in e for e in events)
+        by_id = {e["id"]: e for e in events}
+        for e in events:
+            if e["name"] in _SCHEDULER_SPANS:
+                assert by_id[e["parent"]]["name"] == "serving.beat"
+            elif e["name"] in ("serving.dispatch", "serving.read"):
+                assert by_id[e["parent"]]["name"] == "serving.decode"
+            elif e["name"] == "serving.beat":
+                assert e["parent"] is None
+
+    def test_children_tile_each_beat_without_overlap(self, traced):
+        _, events = traced
+        beats = [e for e in events if e["name"] == "serving.beat"]
+        assert [b["args"]["beat"] for b in beats] == sorted(
+            b["args"]["beat"] for b in beats)
+        kinds = {b["args"]["kind"] for b in beats}
+        assert {"prefill", "idle"} <= kinds
+        assert kinds - {"idle", "prefill"} <= {"decode", "speculate"}
+        for b in beats:
+            kids = sorted((e for e in events if e["parent"] == b["id"]
+                           and e["name"] in _SCHEDULER_SPANS),
+                          key=lambda e: e["ts"])
+            names = [k["name"] for k in kids]
+            if b["args"]["kind"] == "idle":
+                assert names == ["serving.admit"]
+                continue
+            assert names == list(_SCHEDULER_SPANS)
+            assert kids[0]["ts"] >= b["ts"]
+            assert kids[-1]["ts"] + kids[-1]["dur"] <= b["ts"] + b["dur"] + 1e-3
+            for a, c in zip(kids, kids[1:]):
+                assert a["ts"] + a["dur"] <= c["ts"] + 1e-3    # microseconds
+            step = kids[2]
+            assert step["args"]["kind"] == b["args"]["kind"]
+            inner = sorted((e for e in events if e["parent"] == step["id"]),
+                           key=lambda e: e["ts"])
+            want = (["serving.dispatch", "serving.read"]
+                    * (2 if step["args"]["kind"] == "speculate" else 1))
+            assert [e["name"] for e in inner] == want
+            assert sum(e["dur"] for e in inner) <= step["dur"]
+
+    def test_decode_span_names_the_lanes_it_carried(self, traced):
+        reqs, events = traced
+        known = {r.id for r in reqs}
+        steps = [e for e in events if e["name"] == "serving.decode"]
+        assert steps
+        for e in steps:
+            a = e["args"]
+            assert len(a["requests"]) == a["lanes"] and set(a["requests"]) <= known
+            assert a["kind"] in ("prefill", "decode", "speculate") and "rung" in a
+        # every token but a speculation round's extras is one ride
+        rides = {r.id: sum(r.id in e["args"]["requests"] for e in steps)
+                 for r in reqs}
+        for r in reqs:
+            assert 1 <= rides[r.id] <= len(r.generated)
+
+    def test_request_phases_share_the_id_and_meet_end_to_end(self, traced):
+        reqs, events = traced
+        phases = {}
+        for e in events:
+            if e["name"].startswith("serving.request."):
+                phases.setdefault(e["args"]["request"], {})[e["name"][16:]] = e
+        assert set(phases) == {r.id for r in reqs}
+        for r in reqs:
+            p = phases[r.id]
+            assert set(p) == {"queue", "prefill"}
+            assert p["queue"]["ts"] == pytest.approx(r.t_enqueue * 1e6)
+            assert p["queue"]["ts"] + p["queue"]["dur"] == pytest.approx(
+                p["prefill"]["ts"]) == pytest.approx(r.t_dispatch * 1e6)
+            assert p["prefill"]["ts"] + p["prefill"]["dur"] == pytest.approx(
+                r.t_first_token * 1e6)
+            assert p["prefill"]["args"]["prompt"] == r.prompt.size
+
+    def test_first_token_stamp_holds_its_invariants(self, traced):
+        reqs, events = traced
+        # the stamp is the end of the prefill step the request rode
+        ends = {e["args"]["request"]: e["ts"] + e["dur"] for e in events
+                if e["name"] == "serving.request.prefill"}
+        steps = [e for e in events if e["name"] == "serving.decode"
+                 and e["args"]["kind"] == "prefill"]
+        for r in reqs:
+            assert len(r.generated) == len(r.result(0))
+            assert (r.t_enqueue <= r.t_dispatch <= r.t_first_token
+                    <= r.t_complete)
+            rode = [e for e in steps if r.id in e["args"]["requests"]]
+            assert len(rode) == 1
+            assert rode[0]["ts"] + rode[0]["dur"] <= ends[r.id] + 1e-3
+
+    def test_stamps_are_taken_with_the_tracer_off(self, engine):
+        from paddle_tpu.observability import tracer
+
+        assert not tracer.enabled
+        r = engine.submit("t0", _prompts(1, seed=31)[0], max_new_tokens=5)
+        r.result(30)
+        assert len(r.generated) == 5
+        assert r.t_dispatch <= r.t_first_token <= r.t_complete
+        # (a beat that was open when an earlier test switched the tracer off
+        # may still close into the ring; nothing of this request does)
+        assert not any((e.get("args") or {}).get("request") == r.id
+                       for e in tracer.tail_chrome_events(64))
+
+
+def test_sampling_arguments_are_built_inside_the_decode_span(monkeypatch):
+    """What `serving.decode` covers is part of `decode_step_ms`' yardstick:
+    a plain step has always assembled its sampling arguments inside the
+    span (in the program call, so inside `serving.dispatch`)."""
+    from paddle_tpu.observability import tracer
+    from paddle_tpu.serving.scheduler import PagedDecodeScheduler
+
+    plain = PagedDecodeScheduler._sample_args
+
+    def marked(self, lanes, b_rung):
+        with tracer.span("test.sample_args"):
+            return plain(self, lanes, b_rung)
+
+    monkeypatch.setattr(PagedDecodeScheduler, "_sample_args", marked)
+    _, events = _traced_run(_tiny_model(num_hidden_layers=2), n=3)
+    by_id = {e["id"]: e for e in events}
+    marks = [e for e in events if e["name"] == "test.sample_args"]
+    kinds = set()
+    for m in marks:
+        call = by_id[m["parent"]]
+        step = by_id[call["parent"]]
+        assert (call["name"], step["name"]) == ("serving.dispatch",
+                                                "serving.decode")
+        kinds.add(step["args"]["kind"])
+    assert kinds == {"prefill", "decode"}
+
+
+def test_failed_request_closes_with_its_reason(model):
+    from paddle_tpu.observability import tracer
+
+    tracer.reset()
+    was = tracer.enabled
+    tracer.enable()
+    eng = _engine(model, max_slots=4).warmup()
+    try:
+        def boom(*a, **k):
+            raise RuntimeError("seeded prefill crash")
+
+        eng.programs.prefill = boom
+        doomed = eng.submit("t0", _prompts(1, seed=21)[0], max_new_tokens=4)
+        with pytest.raises(RuntimeError):
+            doomed.result(30)
+        events = {e["name"]: e for e in tracer.to_chrome_trace()["traceEvents"]
+                  if e["ph"] == "X" and (e.get("args") or {}).get("request") == doomed.id}
+    finally:
+        tracer.enabled = was
+        tracer.reset()
+        eng.shutdown(drain=False)
+    assert set(events) == {"serving.request.queue", "serving.request.failed"}
+    failed = events["serving.request.failed"]
+    assert failed["args"]["reason"] == "RuntimeError"
+    assert failed["ts"] == pytest.approx(doomed.t_dispatch * 1e6)
+    assert doomed.t_first_token is None
+
+
+# ------------------------------------------------------- program regions
+SERVING_REGIONS = ("embed", "attn/qkv", "attn/kv_write", "attn/kv_gather",
+                   "attn/core", "attn/out", "mlp", "lm_head", "sample")
+
+
+def test_serving_vocabulary_is_the_module_s():
+    from paddle_tpu.base import regions
+
+    assert regions.SERVING == SERVING_REGIONS
+
+
+@pytest.fixture(scope="module")
+def spec_programs():
+    model = _tiny_model(num_hidden_layers=2)
+    eng = _engine(model, page_size=8, speculate_k=2, spec_draft_layers=1)
+    return eng.programs
+
+
+@pytest.mark.parametrize("key", [("decode", 4, 2), ("prefill", 2, 8),
+                                 ("draft", 4, 2), ("verify", 4, 2)],
+                         ids=lambda k: k[0])
+def test_lowered_serving_program_names_every_region(spec_programs, key):
+    """The scope vocabulary is a contract (PERF.md): each program body under
+    its own root, every region inside it in some operation's name."""
+    import re
+
+    P = spec_programs
+    text = P._jitted(key).lower(P._call_params(key), P.pool.k, P.pool.v,
+                                *P._zero_args(key)).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    root = key[0]
+    for region in SERVING_REGIONS:
+        if region == "attn/kv_gather" and root == "prefill":
+            continue                      # a prompt attends to its own rows
+        assert any(f"/{root}/{region}/" in f"/{n}" for n in names), (root, region)

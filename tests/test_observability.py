@@ -172,6 +172,89 @@ class TestSpanTracer:
         assert t.open_spans() == []
         assert len(t) == 1
 
+    def test_ids_are_unique_and_parents_follow_the_thread(self):
+        """Every event has an id of its own; a span's parent is the
+        innermost span open ON ITS THREAD when it started, whatever other
+        threads had open at that moment."""
+        import threading
+
+        t = self._tracer(max_events=4096)
+        inner_started, outer_may_close = threading.Event(), threading.Event()
+
+        def worker(k):
+            with t.span(f"outer{k}", track="w"):
+                with t.span(f"mid{k}", track="w"):
+                    with t.span(f"leaf{k}", track="w"):
+                        inner_started.set()
+                        outer_may_close.wait(5)
+                    t.instant(f"tick{k}", track="w")
+
+        threads = [threading.Thread(target=worker, args=(k,)) for k in range(4)]
+        with t.span("main", track="m"):
+            for th in threads:
+                th.start()
+            inner_started.wait(5)
+            with t.span("main.child", track="m"):
+                pass
+            outer_may_close.set()
+            for th in threads:
+                th.join()
+        events = [e for e in t.to_chrome_trace()["traceEvents"]
+                  if e["ph"] != "M"]
+        by_name = {e["name"]: e for e in events}
+        assert len({e["id"] for e in events}) == len(events) == 4 * 4 + 2
+        assert by_name["main"]["parent"] is None
+        assert by_name["main.child"]["parent"] == by_name["main"]["id"]
+        for k in range(4):
+            assert by_name[f"outer{k}"]["parent"] is None  # not main's child
+            assert by_name[f"mid{k}"]["parent"] == by_name[f"outer{k}"]["id"]
+            assert by_name[f"leaf{k}"]["parent"] == by_name[f"mid{k}"]["id"]
+            assert by_name[f"tick{k}"]["parent"] == by_name[f"mid{k}"]["id"]
+        assert t.open_spans() == []
+        with t.span("after") as after:
+            assert after.parent is None      # every stack unwound
+
+    def test_emit_takes_the_parent_it_is_given_and_infers_none(self):
+        t = self._tracer()
+        with t.span("beat", track="s") as beat:
+            given = t.emit("phase", 1.0, 0.5, track="r", parent=beat.id, request=7)
+            orphan = t.emit("late", 2.0, 0.1, track="r")
+        by_id = {e["id"]: e for e in t.tail_chrome_events(10)}
+        assert by_id[given]["parent"] == beat.id
+        assert by_id[given]["args"] == {"request": 7}   # identity stays out of args
+        assert by_id[orphan]["parent"] is None
+        assert given != orphan != beat.id
+        assert self._tracer(enabled=False).emit("off", 0.0, 1.0) is None
+
+    def test_a_span_ended_out_of_order_leaves_the_stack_sound(self):
+        t = self._tracer()
+        a = t.span("a")
+        b = t.span("b")
+        a.end()                      # the outer one first
+        with t.span("c") as c:
+            assert c.parent == b.id  # b is still open, a is gone
+        b.end()
+        with t.span("d") as d:
+            assert d.parent is None
+
+    def test_ring_bound_is_read_once_per_enable(self, monkeypatch):
+        """_cap() used to call get_flag on every append."""
+        from paddle_tpu.base import flags
+        from paddle_tpu.observability.tracing import SpanTracer
+
+        reads = []
+        real = flags.get_flag
+        monkeypatch.setattr(flags, "get_flag",
+                            lambda name: (reads.append(name), real(name))[1])
+        t = SpanTracer(enabled=False)
+        t.enable()
+        for i in range(50):
+            t.instant(f"e{i}")
+        assert reads.count("telemetry_trace_max_events") == 1
+        t.disable().enable()
+        t.instant("again")
+        assert reads.count("telemetry_trace_max_events") == 2
+
     def test_set_flags_toggles_the_global_tracer(self, fresh_tracer):
         """paddle.set_flags({'telemetry_trace': ...}) must actually flip
         recording at runtime (the flag is mirrored into the hot-path
@@ -445,3 +528,112 @@ def test_lint_timings_rehomed_into_registry():
     _, _, timings = run_analyzers(("telemetry",))
     g = registry.gauge("lint.family_seconds")
     assert g.value(family="telemetry") == timings["telemetry"]
+
+
+# -------------------------------------------------------- program regions
+TRAIN_REGIONS = ("embed", "ln", "attn/qkv", "attn/core", "attn/out", "mlp",
+                 "lm_head", "loss")
+
+
+def _op_names(lowered):
+    import re
+
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+class TestProgramRegions:
+    """The training scope vocabulary (PERF.md lists it as a contract): every
+    region names some forward AND some backward operation of the lowered
+    step — the tape re-enters the forward op's scope around its pullback —
+    and the optimizer's update has its own."""
+
+    @pytest.fixture(scope="class")
+    def step_names(self):
+        from paddle_tpu.jit.api import TrainStep
+        from paddle_tpu.models import GPTForCausalLM, GPTPretrainingCriterion
+        from paddle_tpu.models.gpt import gpt_tiny
+
+        paddle.seed(0)
+        model = GPTForCausalLM(gpt_tiny())
+        criterion = GPTPretrainingCriterion(model.config)
+        optimizer = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                           parameters=model.parameters())
+        step = TrainStep(model=model, optimizer=optimizer,
+                         loss_fn=lambda ids: criterion(model(ids), ids))
+        ids = paddle.Tensor(
+            np.random.RandomState(0).randint(0, 512, (2, 16)).astype(np.int64),
+            stop_gradient=True)
+        for _ in range(2):              # discovery, then the one program
+            step(ids)
+        assert step.fallback_reason is None
+        compiled = step._compiled
+        entry = compiled.last_entry
+        if entry.get("guarded"):
+            entry = entry["entries"][entry["last"]]
+        args, kwargs = compiled._last_call
+        return _op_names(entry["jitted"].lower(
+            [c._value for c in entry["cells"]], args, kwargs))
+
+    @pytest.mark.parametrize("region", TRAIN_REGIONS)
+    def test_region_names_forward_and_backward_operations(self, step_names,
+                                                          region):
+        inside = [n for n in step_names
+                  if f"/{region}/" in f"/{n}" or f"({region})" in n]
+        assert any("transpose(" not in n for n in inside), region
+        assert any("transpose(jvp(" in n for n in inside), region
+
+    def test_optimizer_region_and_nearly_nothing_unscoped(self, step_names):
+        ops = [n for n in step_names if n.startswith("jit(pure)/")]
+        assert any(n.startswith("jit(pure)/optimizer/") for n in ops)
+        bare = [n for n in ops if n.count("/") == 1 or
+                n.startswith(("jit(pure)/transpose(jvp())", "jit(pure)/jvp()"))]
+        # the LR cell's read and the tied embedding's second gradient
+        assert len(bare) <= 2, bare
+
+    def test_flash_path_names_layout_core_and_the_three_kernels(self):
+        """The Pallas path (interpret mode here; compiled for the chip in
+        tests/test_chip_compile.py) names its transposes and its kernels."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+        def loss(q, k, v):
+            return fa.flash_attention_value(q, k, v, causal=True, scale=0.125,
+                                            interpret=True).sum()
+
+        names = _op_names(jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, q, q))
+        for want in ("jvp(attn/layout)", "transpose(jvp(attn/layout))",
+                     "jvp(attn/core)", "transpose(jvp(attn/core))"):
+            assert any(want in n for n in names), want
+        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            assert f"{kernel}/pallas_call" in names, kernel
+
+    def test_the_vocabulary_lives_in_one_module(self):
+        """`base/regions.py` is what the program and the benchmark's readers
+        both import; the names themselves are pinned here."""
+        from paddle_tpu.base import regions
+
+        assert set(regions.TRAINING) == set(TRAIN_REGIONS) | {
+            "attn/layout", "optimizer"}
+        assert regions.ROOTS == ("prefill", "decode", "draft", "verify")
+        assert regions.KERNELS == ("flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv")
+        with regions.region(regions.MLP):
+            from paddle_tpu.core.autograd import _active_scope
+
+            assert _active_scope() == "mlp"
+
+    def test_grad_node_records_the_scope_it_was_made_in(self):
+        import jax
+
+        x = paddle.Tensor(np.ones((2, 2), np.float32), stop_gradient=False)
+        with jax.named_scope("outer"), jax.named_scope("attn/qkv"):
+            y = paddle.multiply(x, x)
+        z = paddle.multiply(y, y)
+        assert y._grad_node.scope == "outer/attn/qkv"
+        assert z._grad_node.scope is None
+        paddle.sum(z).backward()        # re-entering a scope changes no value
+        np.testing.assert_allclose(x.grad.numpy(), 4.0 * np.ones((2, 2)))

@@ -792,6 +792,10 @@ class TestDeviceTraceFusion:
              "ts": 5020.0, "dur": 4.0},
             {"ph": "X", "name": "py_frame", "pid": 1, "tid": 11,
              "ts": 5000.0, "dur": 30.0},
+            # capture_device's annotation: on the capture's clock it sits
+            # 7 us after the earliest event
+            {"ph": "X", "name": "paddle_tpu.sync", "pid": 1, "tid": 11,
+             "ts": 5007.0, "dur": 1.0},
         ]
 
     def test_synthetic_ingest_clock_aligned_under_device_tracks(
@@ -800,7 +804,8 @@ class TestDeviceTraceFusion:
         with fresh_tracer.span("host.work", track="host"):
             pass
         _write_fake_xla_trace(str(tmp_path), self._fake_events())
-        n = fresh_tracer.ingest_device_trace_dir(str(tmp_path), 1000.0)
+        # the host read perf_counter = 1007 us inside the annotation
+        n = fresh_tracer.ingest_device_trace_dir(str(tmp_path), 1007.0)
         assert n == 2                                # python lane dropped
         assert fresh_tracer.device_event_count() == 2
         trace = fresh_tracer.to_chrome_trace()
@@ -810,11 +815,49 @@ class TestDeviceTraceFusion:
         assert "device.TPU:0 XLA Ops" in tracks      # ONE fused export
         dev = [e for e in trace["traceEvents"]
                if e.get("cat", "").startswith("device.")]
-        # earliest device event pinned to the capture-boundary stamp
+        # aligned on the annotation, not on the earliest event: the event
+        # 7 us before the annotation lands 7 us before the host's stamp
         assert min(e["ts"] for e in dev) == 1000.0
         assert {e["name"] for e in dev} == {"fusion.1", "copy.2"}
         gap = [e for e in dev if e["name"] == "copy.2"][0]
         assert gap["ts"] == 1020.0                   # relative offsets kept
+        assert all("id" not in e for e in dev)       # no host identity
+
+    def test_sync_annotation_later_in_capture_moves_every_event(
+            self, tmp_path, fresh_tracer):
+        """The profiler takes a while to start; the annotation is emitted
+        after it has. Pinning the earliest event to a stamp taken before
+        start_trace (the old alignment) put device lanes early by that
+        long; the annotation's own timestamp does not."""
+        events = self._fake_events()
+        events[-1]["ts"] = 5300.0                    # 300 us after the first
+        _write_fake_xla_trace(str(tmp_path), events)
+        fresh_tracer.ingest_device_trace_dir(str(tmp_path), 2000.0)
+        dev = {e["name"]: e["ts"]
+               for e in fresh_tracer.to_chrome_trace()["traceEvents"]
+               if e.get("cat", "").startswith("device.")}
+        assert dev == {"fusion.1": 1700.0, "copy.2": 1720.0}
+
+    def test_capture_without_the_annotation_is_not_ingested(
+            self, tmp_path, fresh_tracer):
+        events = [e for e in self._fake_events()
+                  if e["name"] != "paddle_tpu.sync"]
+        _write_fake_xla_trace(str(tmp_path), events)
+        import logging
+
+        from paddle_tpu.base.log import get_logger
+
+        said, handler = [], logging.Handler()
+        handler.emit = lambda record: said.append(record.getMessage())
+        get_logger().addHandler(handler)
+        try:
+            assert fresh_tracer.ingest_device_trace_dir(str(tmp_path), 0.0) == 0
+        finally:
+            get_logger().removeHandler(handler)
+        assert fresh_tracer.device_event_count() == 0
+        # not silently: the operator is told what was dropped, and why
+        assert any("no 'paddle_tpu.sync' annotation: 3 event(s)" in m
+                   for m in said), said
 
     def test_argsless_metadata_event_does_not_abort_ingest(
             self, tmp_path, fresh_tracer):
@@ -832,7 +875,7 @@ class TestDeviceTraceFusion:
         _write_fake_xla_trace(str(tmp_path), self._fake_events())
         n = fresh_tracer.ingest_device_trace_dir(str(tmp_path), 0.0,
                                                  include_python=True)
-        assert n == 3
+        assert n == 3                                # less the annotation
 
     def test_device_events_excluded_from_host_tail(self, tmp_path,
                                                    fresh_tracer):
@@ -851,6 +894,8 @@ class TestDeviceTraceFusion:
                    "args": {"name": "dev"}}]
         events += [{"ph": "X", "name": f"op.{i}", "pid": 1, "tid": 10,
                     "ts": 100.0 + i, "dur": 1.0} for i in range(6)]
+        events.append({"ph": "X", "name": "paddle_tpu.sync", "pid": 1,
+                       "tid": 10, "ts": 99.0, "dur": 1.0})
         _write_fake_xla_trace(str(tmp_path), events)
         prev = paddle.get_flags(["telemetry_device_trace_max_events"])
         paddle.set_flags({"telemetry_device_trace_max_events": 4})
