@@ -109,6 +109,40 @@ def _mlp(x, blk, eps):
                                approximate=True) @ blk["fc2_w"] + blk["fc2_b"]
 
 
+def _attend_merged(q, keys, vals, positions, heads, scale):
+    """Attention of ``S`` query positions a lane over a gathered paged
+    view whose minor dimension is heads and head_dim MERGED, read
+    without splitting it: ``q`` is ``[B, S, heads*dim]``, ``keys`` /
+    ``vals`` ``[B, cols, heads*dim]`` (``kv_cache.gather_pages``),
+    ``positions`` ``[B, S]``; returns ``[B, S, heads*dim]``. Query
+    ``s`` of lane ``b`` sees columns ``<= positions[b, s]``.
+
+    ``q`` is spread block-diagonally — ``qbd[b, s, h, g*dim + d]`` is
+    ``q[b, s, h*dim + d]`` where ``g == h`` and 0 elsewhere — so one
+    contraction over the whole merged dimension gives head ``h`` its
+    own logits (the other heads' columns add exact zeros), and after
+    the softmax head ``h`` keeps its own ``dim`` columns of ``probs @
+    vals``. The same products, mask, float32 softmax and dtypes as the
+    split ``"bhd,bthd->bht"`` form, at ``heads`` times its FLOPs on a
+    step bound by bytes — and no array of the cache's size is ever
+    reshaped to a ``(heads, dim)`` tail, which a TPU would pad to its
+    tile and relay out (see :class:`~.kv_cache.KVPagePool`)."""
+    import jax
+    import jax.numpy as jnp
+
+    HD = q.shape[-1]
+    own = (jnp.arange(HD) // (HD // heads))[None, :] == jnp.arange(heads)[:, None]
+    qbd = jnp.where(own, q[:, :, None, :], 0)
+    logits = jnp.einsum("bshk,btk->bhst", qbd, keys) * scale
+    col = jnp.arange(keys.shape[1])
+    mask = col[None, None, None, :] <= positions[:, None, :, None]
+    logits = jnp.where(mask, logits, -1e30)
+    probs = jax.nn.softmax(logits.astype(jnp.float32),
+                           axis=-1).astype(q.dtype)
+    full = jnp.einsum("bhst,btk->bshk", probs, vals)
+    return jnp.where(own, full, 0).sum(axis=2)
+
+
 class DecodePrograms:
     """The decode tier's compiled program set over one GPT's weights.
 
@@ -437,6 +471,14 @@ class PagedDecodePrograms(DecodePrograms):
       table is DATA: one compiled program serves any page map, so page
       churn (alloc on growth, reclaim on retire, reuse by the next
       request) costs zero retraces.
+    - the pool is ``[layers, pages+1, page_size, heads*head_dim]``: the
+      minor dimension merged, so (page_size, heads*head_dim) is whole
+      TPU tiles where (heads, head_dim) = (12, 64) was padded 2.67x.
+      The programs take and return it as it lies (no whole-pool
+      relayout on entry and exit) and ``decode``/``draft``/``verify``
+      never split the merged dimension on anything a page's size or
+      larger: fresh k/v rows are merged before the write, and the
+      gathered view is contracted whole by :func:`_attend_merged`.
     - decode rungs key on (batch rung × table rung): ``("decode", b,
       t)`` where ``t`` walks :func:`~..jit.bucketing.table_ladder` —
       a short context pays a short gather, a 4k one a long gather, and
@@ -581,11 +623,14 @@ class PagedDecodePrograms(DecodePrograms):
             next_tok = self._choose_tokens(head, temps, top_ks, top_ps, rkeys)
             # pad the prompt rows up to whole pages; the surplus rows route
             # through table entries past the lane's real pages (pad page 0)
-            S = krows.shape[2]
+            L, B, S = krows.shape[:3]
             want = tables.shape[1] * self.pool.page_size
-            if want > S:
-                padw = ((0, 0), (0, 0), (0, want - S), (0, 0), (0, 0))
-                with region(regions.ATTN_KV_WRITE):
+            with region(regions.ATTN_KV_WRITE):
+                # the pool's rows carry heads merged into the minor dim
+                krows = krows.reshape(L, B, S, self._hidden)
+                vrows = vrows.reshape(L, B, S, self._hidden)
+                if want > S:
+                    padw = ((0, 0), (0, 0), (0, want - S), (0, 0))
                     krows = jnp.pad(krows, padw)
                     vrows = jnp.pad(vrows, padw)
             ck = kvc.write_prompt_pages(ck, tables, krows)
@@ -608,7 +653,6 @@ class PagedDecodePrograms(DecodePrograms):
         proposals are garbage, but its verify tokens past the boundary
         are never committed — the scheduler retires it at ``max_seq``.
         """
-        import jax
         import jax.numpy as jnp
 
         B, T = tables.shape
@@ -621,10 +665,6 @@ class PagedDecodePrograms(DecodePrograms):
                                                  self._max_pos - 1)])
             else:
                 x = params["wte"][tokens] + params["wpe"][positions]
-        # the traced table maps token position -> page: column j of the
-        # gathered view IS position j, so the slot program's mask and
-        # softmax carry over unchanged (bit-exact greedy contract)
-        col = jnp.arange(T * ps)
         with region(regions.ATTN_KV_WRITE):
             page_idx = (positions // ps).astype(jnp.int32)
             if bounded:
@@ -639,19 +679,20 @@ class PagedDecodePrograms(DecodePrograms):
                 h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
                 qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
                     B, self._heads, 3, self._head_dim)
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                # one token's q, k, v, heads merged: [B, heads*dim]
+                q, k, v = (qkv[:, :, i].reshape(B, self._hidden)
+                           for i in range(3))
             ck = kvc.append_token_paged(ck, li, pages, offsets, k)
             cv = kvc.append_token_paged(cv, li, pages, offsets, v)
-            keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h, d]
+            # the traced table maps token position -> page: column j of the
+            # gathered view IS position j, so the slot program's mask and
+            # softmax carry over unchanged (bit-exact greedy contract)
+            keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h*d]
             vals = kvc.gather_pages(cv, li, tables)
             with region(regions.ATTN_CORE):
-                logits = jnp.einsum("bhd,bthd->bht", q, keys) * self._scale
-                mask = col[None, None, :] <= positions[:, None, None]
-                logits = jnp.where(mask, logits, -1e30)
-                probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                       axis=-1).astype(x.dtype)
-                att = jnp.einsum("bht,bthd->bhd", probs, vals).reshape(
-                    B, self._hidden)
+                att = _attend_merged(q[:, None], keys, vals,
+                                     positions[:, None], self._heads,
+                                     self._scale)[:, 0]
             with region(regions.ATTN_OUT):
                 x = x + att @ blk["out_w"] + blk["out_b"]
             x = _mlp(x, blk, eps)
@@ -713,7 +754,6 @@ class PagedDecodePrograms(DecodePrograms):
         key — the j-th verify token is bitwise the token the plain
         decode program would emit after committing tokens 0..j-1, which
         is the whole bit-exactness contract."""
-        import jax
         import jax.numpy as jnp
 
         self.traces += 1
@@ -726,31 +766,26 @@ class PagedDecodePrograms(DecodePrograms):
             with region(regions.EMBED):
                 x = (params["wte"][tokens]
                      + params["wpe"][jnp.minimum(pos, self._max_pos - 1)])
-            col = jnp.arange(T * ps)
             with region(regions.ATTN_KV_WRITE):
                 page_idx = jnp.minimum((pos // ps).astype(jnp.int32), T - 1)
                 pages = jnp.take_along_axis(tables, page_idx, axis=1)
                 pages = jnp.where(pos < self.max_seq, pages, 0)  # pad-page spill
                 offsets = (pos % ps).astype(jnp.int32)
-            # [B, heads, K1 queries, T*ps cols]: query j sees cols <= p+j
-            mask = col[None, None, None, :] <= pos[:, None, :, None]
             for li, blk in enumerate(params["blocks"]):
                 with region(regions.ATTN_QKV):
                     h = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
                     qkv = (h @ blk["qkv_w"] + blk["qkv_b"]).reshape(
                         B, K1, self._heads, 3, self._head_dim)
-                    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+                    q, k, v = (qkv[..., i, :].reshape(B, K1, self._hidden)
+                               for i in range(3))
                 ck = kvc.append_token_paged(ck, li, pages, offsets, k)
                 cv = kvc.append_token_paged(cv, li, pages, offsets, v)
-                keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h, d]
+                keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h*d]
                 vals = kvc.gather_pages(cv, li, tables)
                 with region(regions.ATTN_CORE):
-                    logits = jnp.einsum("bshd,bthd->bhst", q, keys) * self._scale
-                    logits = jnp.where(mask, logits, -1e30)
-                    probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                           axis=-1).astype(x.dtype)
-                    att = jnp.einsum("bhst,bthd->bshd", probs, vals).reshape(
-                        B, K1, self._hidden)
+                    # query j sees cols <= p+j
+                    att = _attend_merged(q, keys, vals, pos, self._heads,
+                                         self._scale)
                 with region(regions.ATTN_OUT):
                     x = x + att @ blk["out_w"] + blk["out_b"]
                 x = _mlp(x, blk, eps)

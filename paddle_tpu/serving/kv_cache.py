@@ -21,6 +21,13 @@ live sequence's state.
 Host-side bookkeeping (free list, per-slot lengths, occupancy gauge)
 stays in :class:`KVSlotPool`; the pure functions below run inside the
 jitted programs and carry no python state.
+
+:class:`KVPagePool` is the same discipline over fixed-size pages, shaped
+``[layers, num_pages+1, page_size, heads*head_dim]``: its minor dimension
+is heads and head_dim merged, which a TPU tiles without padding (the
+class docstring has the two costs that removed). The slot pool keeps the
+split ``(heads, head_dim)`` tail: it is the greedy oracle the paged
+programs are tested against, on another layout and another contraction.
 """
 from __future__ import annotations
 
@@ -77,22 +84,23 @@ def append_token(cache, layer, slot_ids, positions, rows):
 # ------------------------------------------------- paged functional updates
 def write_prompt_pages(cache, tables, rows):
     """Batched paged prefill write: ``rows`` is ``[layers, B, T*ps,
-    heads, dim]`` (prompt K/V padded up to whole pages), ``tables`` is
-    the traced ``[B, T]`` int32 block table — one scatter over the page
-    axis covering every layer. Table entries past a lane's real pages
-    are 0 (the pad page), so garbage rows land in the trash page and a
-    padded program call never touches live state."""
-    L, B, _, H, D = rows.shape
+    heads*dim]`` (prompt K/V padded up to whole pages, heads merged into
+    the pool's minor dimension), ``tables`` is the traced ``[B, T]``
+    int32 block table — one scatter over the page axis covering every
+    layer. Table entries past a lane's real pages are 0 (the pad page),
+    so garbage rows land in the trash page and a padded program call
+    never touches live state."""
+    L, B, _, HD = rows.shape
     T = tables.shape[1]
     ps = cache.shape[2]
     with region(regions.ATTN_KV_WRITE):
-        paged = rows.astype(cache.dtype).reshape(L, B, T, ps, H, D)
+        paged = rows.astype(cache.dtype).reshape(L, B, T, ps, HD)
         return cache.at[:, tables].set(paged)
 
 
 def append_token_paged(cache, layer, pages, offsets, rows):
     """One decode step's paged write for one layer: ``rows`` is ``[B,
-    heads, dim]`` landing at ``(layer, pages[b], offsets[b])`` where
+    heads*dim]`` landing at ``(layer, pages[b], offsets[b])`` where
     ``pages[b] = table[b, pos // page_size]`` and ``offsets[b] = pos %
     page_size`` — both traced. Pad lanes carry page 0."""
     with region(regions.ATTN_KV_WRITE):
@@ -101,14 +109,20 @@ def append_token_paged(cache, layer, pages, offsets, rows):
 
 def gather_pages(cache, layer, tables):
     """Materialize a batch's contiguous K (or V) view from the page
-    array: ``cache[layer][tables]`` gathers ``[B, T, ps, heads, dim]``
-    along the page axis and reshapes to ``[B, T*ps, heads, dim]`` — the
-    traced-block-table read the decode attention indexes through. One
-    compiled program serves ANY page map because the table is data."""
+    array: ``cache[layer, tables]`` gathers ``[B, T, ps, heads*dim]``
+    along the page axis (ONE gather from the whole pool — slicing the
+    layer out first is a 100 MB copy a layer on a TPU) and reshapes to
+    ``[B, T*ps, heads*dim]`` — the traced-block-table read the decode
+    attention indexes through. One compiled program serves ANY page map
+    because the table is data.
+    The view keeps the pool's merged minor dimension: the reader
+    contracts against it whole (``decode._attend_merged``) — splitting
+    it back into (heads, dim) here would have the TPU compiler pad and
+    relay out the copy, the cost the merged pool exists to remove."""
     B, T = tables.shape
-    ps, H, D = cache.shape[2], cache.shape[3], cache.shape[4]
+    ps, HD = cache.shape[2], cache.shape[3]
     with region(regions.ATTN_KV_GATHER):
-        return cache[layer][tables].reshape(B, T * ps, H, D)
+        return cache[layer, tables].reshape(B, T * ps, HD)
 
 
 # --------------------------------------------------------------- the pool
@@ -233,7 +247,20 @@ class KVSlotPool:
 # ---------------------------------------------------------- the page pool
 class KVPagePool:
     """Free-list *page* allocator over one device-resident K/V buffer
-    pair shaped ``[layers, num_pages+1, page_size, heads, head_dim]``.
+    pair shaped ``[layers, num_pages+1, page_size, heads*head_dim]``.
+
+    The minor dimension is heads and head_dim MERGED. A TPU holds an
+    array in tiles of its two minor dimensions (16 sublanes x 128 lanes
+    for bf16); a ``(heads, head_dim)`` tail such as (12, 64) fills
+    neither, so the compiled decode step (a) copied both pool arrays
+    whole into the padded form on entry and back on exit, every step,
+    and (b) gathered each layer's pages into a view 2.67x the bytes of
+    its data. ``(page_size, heads*head_dim)`` — (256, 768) for
+    gpt2-small — is whole tiles: the runtime's layout and the program's
+    are the same array, nothing is copied and nothing padded. Same
+    bytes, same page ids; only the rows' shape differs, and the decode
+    programs contract against the merged dimension without splitting
+    it (``decode._attend_merged``).
 
     The vLLM discipline applied to the slot pool above: instead of one
     full ``max_seq`` row per sequence, a request holds only the fixed-
@@ -268,7 +295,7 @@ class KVPagePool:
         self.head_dim = int(head_dim)
         # +1: page 0 is the pad page — never allocated, absorbs garbage
         shape = (self.num_layers, self.num_pages + 1, self.page_size,
-                 self.num_heads, self.head_dim)
+                 self.num_heads * self.head_dim)
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
         # low page ids hand out first: pop() from the tail

@@ -106,7 +106,28 @@ class TestKVPagePool:
 
         pool = self._pool()
         with pytest.raises(ValueError, match="footprint"):
-            pool.commit(jnp.zeros((1, 3, 8, 1, 4)), pool.v)
+            pool.commit(jnp.zeros((1, 3, 8, 4)), pool.v)
+        # the same bytes with the minor dimension split back into
+        # (heads, head_dim) is another footprint too: the layout is pinned
+        with pytest.raises(ValueError, match="footprint"):
+            pool.commit(pool.k.reshape(1, 7, 8, 1, 4), pool.v)
+        pool.commit(pool.k + 0, pool.v + 0)  # same footprint: fine
+
+    @pytest.mark.parametrize("layers,pages,ps,heads,dim",
+                             [(1, 6, 8, 1, 4), (2, 5, 16, 2, 4),
+                              (3, 4, 32, 12, 64)])
+    def test_device_bytes_and_shape(self, layers, pages, ps, heads, dim):
+        """The merged layout moves no byte: K and V are ``[layers,
+        pages+1, page_size, heads*head_dim]``, the footprint what the
+        split ``(heads, head_dim)`` tail held, and heads / head_dim
+        stay attributes of the pool."""
+        pool = KVPagePool(layers, pages, ps, heads, dim, dtype="bfloat16")
+        assert pool.k.shape == pool.v.shape == (layers, pages + 1, ps,
+                                                heads * dim)
+        assert (pool.num_heads, pool.head_dim) == (heads, dim)
+        assert pool.device_bytes() == 2 * 2 * layers * (pages + 1) * ps * heads * dim
+        pool.mark_warm()
+        assert pool.bytes_at_warmup == pool.device_bytes()
 
     def test_equal_bytes_vs_slot_pool(self):
         """The bench's sizing identity: a page pool with
@@ -126,6 +147,143 @@ class TestKVPagePool:
         assert rep["samples"] == 2
         assert rep["mean"] == pytest.approx(0.625)
         assert rep["min"] == pytest.approx(0.25)
+
+
+# ------------------------------------------------- merged-heads layout
+class TestMergedHeadsLayout:
+    """ISSUE 28: the pool's minor dimension is heads*head_dim, and the
+    decode programs read it without splitting it."""
+
+    @pytest.mark.parametrize("queries", [1, 3])
+    @pytest.mark.parametrize("width", [1, 4])
+    @pytest.mark.parametrize("heads,dim", [(2, 4), (12, 64)])
+    def test_merged_read_matches_split_heads(self, heads, dim, width,
+                                             queries):
+        """Write through ``append_token_paged``, read through
+        ``gather_pages`` + ``_attend_merged``; the oracle is the plain
+        split-heads einsum over the same bytes viewed ``[..., heads,
+        dim]``. Lanes: position 0, the last row of the first page, the
+        first row of the next (or the row before, at width 1), the
+        table's last position, and a pad lane whose table is all page 0
+        (its rows are the trash page's; it must still read itself)."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.serving import kv_cache as kvc
+        from paddle_tpu.serving.decode import _attend_merged
+
+        ps, B, HD = 8, 5, heads * dim
+        cols = width * ps
+        rs = np.random.RandomState(heads * 100 + width * 10 + queries)
+        pool = rs.randn(1, B * width + 1, ps, HD).astype(np.float32)
+        tables = 1 + np.arange(B * width, dtype=np.int32).reshape(B, width)
+        tables[-1] = 0                                   # the pad lane
+        last = cols - queries
+        first = np.array([0, ps - 1, min(ps, last), last, 0], np.int32)
+        first = np.minimum(first, last)
+        pos = first[:, None] + np.arange(queries, dtype=np.int32)[None, :]
+        q, k, v = (rs.randn(B, queries, HD).astype(np.float32)
+                   for _ in range(3))
+        pages = np.take_along_axis(tables, pos // ps, axis=1)
+        scale = 1.0 / np.sqrt(dim)
+
+        ck = kvc.append_token_paged(jnp.asarray(pool), 0, pages, pos % ps, k)
+        cv = kvc.append_token_paged(jnp.asarray(pool[:, ::-1]), 0, pages,
+                                    pos % ps, v)
+        keys = kvc.gather_pages(ck, 0, tables)
+        vals = kvc.gather_pages(cv, 0, tables)
+        assert keys.shape == vals.shape == (B, cols, HD)
+        got = _attend_merged(jnp.asarray(q), keys, vals, jnp.asarray(pos),
+                             heads, scale)
+        assert got.shape == (B, queries, HD)
+
+        keys4 = np.asarray(keys).reshape(B, cols, heads, dim)
+        vals4 = np.asarray(vals).reshape(B, cols, heads, dim)
+        # the token just written is what each query's own column holds
+        for b in range(B - 1):
+            np.testing.assert_array_equal(
+                keys4[b, pos[b]].reshape(queries, HD), k[b])
+        logits = jnp.einsum("bshd,bthd->bhst",
+                            q.reshape(B, queries, heads, dim), keys4,
+                            precision="highest") * scale
+        mask = np.arange(cols)[None, None, None, :] <= pos[:, None, :, None]
+        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+        want = jnp.einsum("bhst,bthd->bshd", probs, vals4,
+                          precision="highest").reshape(B, queries, HD)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5)
+
+    @pytest.fixture(scope="class")
+    def programs(self):
+        model = _tiny_model(num_hidden_layers=2, num_attention_heads=2,
+                            max_position_embeddings=64)
+        eng = _paged(model, max_seq=64, seq_buckets=[32, 64], page_size=16,
+                     speculate_k=2, spec_draft_layers=1)
+        yield eng.programs
+        eng.shutdown(drain=False)
+
+    @pytest.mark.parametrize("kind", ["decode", "draft", "verify"])
+    def test_no_program_splits_a_page_or_more(self, programs, kind):
+        """The half of the layout a reshape would silently undo: walk
+        the program's jaxpr and find no intermediate with a ``(heads,
+        head_dim)`` tail as large as one page (only one token's fresh
+        q, k, v may carry it), and the pool going in and out rank 4."""
+        import jax
+
+        from paddle_tpu.analysis.drift_check import _walk
+
+        P = programs
+        heads, dim, ps = P.pool.num_heads, P.pool.head_dim, P.pool.page_size
+        key = (kind, 4, 2)
+        assert key in P.rungs
+        fn = {"decode": P._decode_fn, "draft": P._draft_fn,
+              "verify": P._verify_fn}[kind]
+        closed = jax.make_jaxpr(fn)(P._call_params(key), P.pool.k, P.pool.v,
+                                    *P._zero_args(key))
+        n_params = len(jax.tree_util.tree_leaves(P._call_params(key)))
+        pool_in = closed.in_avals[n_params:n_params + 2]
+        pool_out = closed.out_avals[:2]
+        want = (P.pool.num_layers, P.pool.num_pages + 1, ps, heads * dim)
+        assert [a.shape for a in pool_in + pool_out] == [want] * 4
+        seen_small = 0
+        for eqn in _walk(closed.jaxpr):
+            for var in eqn.outvars:
+                shape = tuple(getattr(var.aval, "shape", ()))
+                if shape[-2:] != (heads, dim):
+                    continue
+                seen_small += 1
+                assert int(np.prod(shape)) < ps * heads * dim, (
+                    kind, eqn.primitive.name, shape)
+        assert seen_small  # the fresh token's q/k/v: the walk sees tails
+
+    def test_multi_head_streams_equal_slot_oracle_and_speculation(self):
+        """The contracts the one-head fixtures cannot see through the
+        merged read: with two heads, greedy paged tokens equal the slot
+        oracle's (split layout, split einsum), and the speculative
+        stream equals the plain one token for token."""
+        model = _tiny_model(num_hidden_layers=2, num_attention_heads=2,
+                            max_position_embeddings=64)
+        common = dict(max_slots=2, max_seq=64, seq_buckets=[32, 64],
+                      prefill_max_batch=2, stats=ServingStats())
+        prompts = _prompts([5, 16, 31, 40], seed=11)
+        streams = {}
+        for name, kw in [("slots", dict(kv_mode="slots")),
+                         ("paged", dict(kv_mode="paged", page_size=16)),
+                         ("spec", dict(kv_mode="paged", page_size=16,
+                                       speculate_k=2, spec_draft_layers=1,
+                                       spec_min_accept=0.0))]:
+            eng = serving.DecodeEngine(model, **dict(common, **kw)).warmup()
+            try:
+                futs = [eng.submit("t", p, max_new_tokens=10)
+                        for p in prompts]
+                streams[name] = [np.asarray(f.result(60)) for f in futs]
+                assert eng.serving_report()["compiles_after_warmup"] == 0
+            finally:
+                eng.shutdown(drain=True)
+        for want, paged, spec in zip(*(streams[n] for n in
+                                       ("slots", "paged", "spec"))):
+            assert np.array_equal(paged, want)
+            assert np.array_equal(spec, want)
 
 
 # ------------------------------------------------- mixed-context matrix
