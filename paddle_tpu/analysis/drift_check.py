@@ -392,6 +392,46 @@ def _decode_fingerprints(programs, rung_grids) -> None:
     rung_grids["decode/paged"] = sorted(grid)
 
 
+def _retention_fingerprints(programs, rung_grids) -> None:
+    """The state-lane residency's representatives: one prefill chunk and
+    one decode rung of a 1-layer tiny Brumby over a StateLanePool (the jnp
+    decode path: the Pallas kernel is a TPU's), retraced abstractly."""
+    import jax
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from ..models.brumby import BrumbyForCausalLM, brumby_tiny
+    from ..serving.decode import RetentionPrograms
+    from ..serving.kv_cache import StateLanePool
+
+    paddle.seed(0)
+    model = BrumbyForCausalLM(brumby_tiny(
+        num_hidden_layers=1, hidden_size=32, intermediate_size=48,
+        num_attention_heads=2, num_key_value_heads=1, vocab_size=64,
+        max_position_embeddings=32))
+    model.eval()
+    pool = StateLanePool(num_layers=1, max_slots=2, num_kv_heads=1,
+                         head_dim=16, max_seq=32)
+    progs = RetentionPrograms(model, pool, seq_ladder=[8],
+                              prefill_batch_rungs=[1], decode_rungs=[2])
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+
+    donation = tuple(f"arg{i}" for i in progs._donate)
+    fns = {"decode": progs._decode_fn, "prefill": progs._prefill_fn}
+    grid = []
+    for key in progs.rungs:
+        closed = jax.make_jaxpr(fns[key[0]])(
+            jax.tree_util.tree_map(sds, progs.params), sds(pool.state),
+            *(sds(a) for a in progs._zero_args(key)))
+        rung = ":".join(str(p) for p in key)
+        grid.append(rung)
+        programs[f"decode/state:{rung}"] = fingerprint_jaxpr(
+            closed, donation=donation)
+    rung_grids["decode/state"] = sorted(grid)
+
+
 def _qpsum_fingerprint(programs) -> None:
     """The quantized-allreduce oracle over an awkward (non-multiple)
     shape — the exact wire math, block size pinned so the trace is
@@ -457,6 +497,7 @@ def record_drift_programs(refresh: bool = False) -> dict:
         _train_fingerprints(env, programs, skipped)
         _serving_fingerprints(programs, rung_grids)
         _decode_fingerprints(programs, rung_grids)
+        _retention_fingerprints(programs, rung_grids)
         _qpsum_fingerprint(programs)
         _reshard_fingerprints(programs, skipped)
     live = {"programs": programs, "rung_grids": rung_grids,

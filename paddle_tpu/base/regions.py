@@ -14,8 +14,10 @@ kernels' wrappers, ``optimizer.step``) uses ``TRAINING``; backward
 operations keep their forward region (``core/autograd.py`` re-enters it
 around each pullback). A serving program (``serving/decode.py``,
 ``serving/kv_cache.py``) runs under one of ``ROOTS`` and uses ``SERVING``
-inside it. ``KERNELS`` are the ``pl.pallas_call(name=...)`` of
-``ops/pallas/flash_attention.py``.
+inside it; the programs of a model whose layers hold a recurrent state
+(``models/brumby.py``) use ``RETENTION`` there. ``KERNELS`` are the
+``pl.pallas_call(name=...)`` of ``ops/pallas/flash_attention.py`` and
+``ops/pallas/retention.py``.
 """
 from __future__ import annotations
 
@@ -29,6 +31,11 @@ ATTN_CORE = "attn/core"            # the attention itself (the kernel calls)
 ATTN_OUT = "attn/out"
 ATTN_KV_WRITE = "attn/kv_write"    # every write into the KV pool
 ATTN_KV_GATHER = "attn/kv_gather"  # a lane's pages gathered into one view
+RETN_GATE = "retn/gate"          # the gate's projection, log-sigmoid, cumulative sum
+RETN_CHUNK = "retn/chunk"        # prefill: phi, the masked quadratic part, the carry in and out
+RETN_STATE = "retn/state"        # decode: phi, the update of the state, the read for y
+NORM = "norm"                    # RMSNorm (the retention model's; GPT's LayerNorm is `ln`)
+ROPE = "rope"
 MLP = "mlp"
 LM_HEAD = "lm_head"
 LOSS = "loss"
@@ -43,10 +50,13 @@ VERIFY = "verify"
 FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
+RETN_STEP = "retn_step"          # ops/pallas/retention.py: the decode step's state kernel
 
 TRAINING = (EMBED, LN, ATTN_QKV, ATTN_LAYOUT, ATTN_CORE, ATTN_OUT, MLP,
             LM_HEAD, LOSS, OPTIMIZER)
 SERVING = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KV_GATHER, ATTN_CORE,
            ATTN_OUT, MLP, LM_HEAD, SAMPLE)
+RETENTION = (EMBED, NORM, ATTN_QKV, ROPE, RETN_GATE, RETN_CHUNK, RETN_STATE,
+             ATTN_OUT, MLP, LM_HEAD, SAMPLE)
 ROOTS = (PREFILL, DECODE, DRAFT, VERIFY)
-KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, RETN_STEP)

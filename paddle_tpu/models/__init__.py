@@ -16,6 +16,13 @@ from .bert import (  # noqa: F401
     bert_tiny,
     ernie_base,
 )
+from .brumby import (  # noqa: F401
+    BrumbyConfig,
+    BrumbyForCausalLM,
+    BrumbyLayers,
+    BrumbyModel,
+    brumby_tiny,
+)
 from .gpt import (  # noqa: F401
     GPTConfig,
     GPTModel,

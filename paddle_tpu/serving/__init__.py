@@ -30,6 +30,13 @@ Serving phase 2 (ISSUE 13) adds TRUE continuous batching for GPT decode:
   prefill-or-decode program call per step; requests join freed slots
   mid-flight and leave the step they finish — no batch re-assembly.
 
+A model whose layers keep a recurrent state and no keys and values
+(``models.BrumbyForCausalLM``) is served by the same engine over the third
+residency: :class:`StateLanePool` (one float32 state array, a lane a
+request) and :class:`RetentionPrograms` (a chunked prefill that carries
+the state, a decode step that updates it in place); the residency follows
+from the model.
+
 Latency accounting (enqueue→admit→dispatch→complete, queue depth,
 p50/p99, requests/sec at FLAGS_serving_slo_ms, the prefill-vs-decode
 step split and decode tokens/sec) flows through
@@ -44,9 +51,9 @@ step split and decode tokens/sec) flows through
     req.result()
     engine.shutdown(drain=True)
 """
-from .decode import DecodeEngine, DecodePrograms
+from .decode import DecodeEngine, DecodePrograms, RetentionPrograms
 from .engine import EngineBase, ServingEngine
-from .kv_cache import KVSlotPool
+from .kv_cache import KVSlotPool, StateLanePool
 from .request_queue import (AdmissionController, AdmissionError,
                             DecodeRequest, RejectedError, Request,
                             RequestQueue)
@@ -56,6 +63,7 @@ from .scheduler import (DecodeScheduler, Scheduler, scatter_outputs,
 __all__ = [
     "AdmissionController", "AdmissionError", "DecodeEngine",
     "DecodePrograms", "DecodeRequest", "DecodeScheduler", "EngineBase",
-    "KVSlotPool", "RejectedError", "Request", "RequestQueue", "Scheduler",
-    "ServingEngine", "scatter_outputs", "stack_requests",
+    "KVSlotPool", "RejectedError", "Request", "RequestQueue",
+    "RetentionPrograms", "Scheduler", "ServingEngine", "StateLanePool",
+    "scatter_outputs", "stack_requests",
 ]

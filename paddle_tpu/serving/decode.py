@@ -45,11 +45,12 @@ from ..observability.locks import named_lock
 from ..profiler.pipeline import serving_stats
 from . import kv_cache as kvc
 from .engine import EngineBase
-from .kv_cache import KVPagePool, KVSlotPool
+from .kv_cache import KVPagePool, KVSlotPool, StateLanePool
 from .request_queue import DecodeRequest
 from .scheduler import DecodeScheduler, PagedDecodeScheduler
 
-__all__ = ["DecodeEngine", "DecodePrograms", "PagedDecodePrograms"]
+__all__ = ["DecodeEngine", "DecodePrograms", "PagedDecodePrograms",
+           "RetentionPrograms"]
 
 
 def _extract_gpt(model):
@@ -170,19 +171,12 @@ class DecodePrograms:
                  decode_rungs: Sequence[int]):
         import jax
 
-        params, cfg = _extract_gpt(model)
+        params, cfg = self._extract(model)
         self.params = jax.device_put(params)
         self.pool = pool
         self.seq_ladder = sorted(int(s) for s in seq_ladder)
         self.prefill_batch_rungs = sorted(int(b) for b in prefill_batch_rungs)
         self.decode_rungs = sorted(int(b) for b in decode_rungs)
-        self._heads = cfg.num_attention_heads
-        self._head_dim = cfg.head_dim
-        self._hidden = cfg.hidden_size
-        self._max_pos = int(cfg.max_position_embeddings)
-        self._eps = float(cfg.layer_norm_epsilon)
-        self._tied = bool(cfg.tie_word_embeddings)
-        self._scale = 1.0 / math.sqrt(cfg.head_dim)
         self.traces = 0
         self.warmed: List[tuple] = []
         self.restored: List[tuple] = []
@@ -194,7 +188,29 @@ class DecodePrograms:
         # updates the KV cache in place. CPU ignores donation — skip the
         # warning noise there; the footprint proof holds either way
         # (commit() pins shape/dtype, device_bytes stays constant).
-        self._donate = (1, 2) if backend != "cpu" else ()
+        self._donate = (tuple(range(1, 1 + len(pool.arrays())))
+                        if backend != "cpu" else ())
+        self._bind_config(cfg, pool)
+        self._jit_prefill = jax.jit(self._prefill_fn,
+                                    donate_argnums=self._donate)
+        self._jit_decode = jax.jit(self._decode_fn,
+                                   donate_argnums=self._donate)
+
+    #: programs that prefill a prompt in pieces set this; the scheduler
+    #: then keeps a cursor per pending request (one chunk a beat)
+    chunked = False
+    _extract = staticmethod(_extract_gpt)
+
+    def _bind_config(self, cfg, pool) -> None:
+        """The model's constants the traced bodies bake in, and the
+        structural key of the compile cache."""
+        self._heads = cfg.num_attention_heads
+        self._head_dim = cfg.head_dim
+        self._hidden = cfg.hidden_size
+        self._max_pos = int(cfg.max_position_embeddings)
+        self._eps = float(cfg.layer_norm_epsilon)
+        self._tied = bool(cfg.tie_word_embeddings)
+        self._scale = 1.0 / math.sqrt(cfg.head_dim)
         # executables are parameter-VALUE independent (params are runtime
         # args), so the cache key needs only the structural identity —
         # which includes every compile-time CONSTANT baked into the traced
@@ -206,10 +222,6 @@ class DecodePrograms:
             int(cfg.max_position_embeddings), self._tied, self._eps,
             tuple(int(d) for d in pool.k.shape), str(pool.k.dtype),
             tuple(self._donate))
-        self._jit_prefill = jax.jit(self._prefill_fn,
-                                    donate_argnums=self._donate)
-        self._jit_decode = jax.jit(self._decode_fn,
-                                   donate_argnums=self._donate)
 
     # ------------------------------------------------------------ programs
     def _logits_head(self, params, x):
@@ -375,7 +387,7 @@ class DecodePrograms:
                 self.restored.append(key)
                 return
             lowered = self._jitted(key).lower(
-                self._call_params(key), self.pool.k, self.pool.v,
+                self._call_params(key), *self.pool.arrays(),
                 *args)  # traces += 1
             compiled = lowered.compile()
             cc.store_executable(
@@ -386,9 +398,9 @@ class DecodePrograms:
         # in-memory warm: one traced call against the pad slot (harmless
         # writes land in the trash slot); outputs are committed so a
         # donation backend keeps the pool buffers alive
-        k, v, _ = self._jitted(key)(self._call_params(key), self.pool.k,
-                                    self.pool.v, *args)
-        self.pool.commit(k, v)
+        *arrays, _ = self._jitted(key)(self._call_params(key),
+                                       *self.pool.arrays(), *args)
+        self.pool.commit(*arrays)
 
     def _call_params(self, key) -> dict:
         """The parameter pytree rung ``key`` runs against. The base
@@ -420,7 +432,7 @@ class DecodePrograms:
         Returns the number of parameter leaves swapped."""
         import jax
 
-        new_params, _cfg = _extract_gpt(model)
+        new_params, _cfg = self._extract(model)
         old_leaves, old_def = jax.tree_util.tree_flatten(self.params)
         new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
         if old_def != new_def:
@@ -889,6 +901,262 @@ class PagedDecodePrograms(DecodePrograms):
         return self._jit_verify(self.params, ck, cv, *args)
 
 
+def _extract_brumby(model):
+    """A ``models.brumby.BrumbyForCausalLM``'s parameters as a plain
+    pytree, zero-copy: the model already holds its layers stacked on a
+    leading axis, which is what the programs scan over."""
+    cfg = model.config
+    stack = model.brumby.layers
+    return {
+        "embed": model.brumby.embed_tokens._value,
+        "norm": model.brumby.norm._value,
+        "head": model.lm_head._value,
+        "layers": {name: p._value for name, p in stack._parameters.items()},
+    }, cfg
+
+
+def _rms(x, w, eps):
+    """RMSNorm, the mean of squares in float32, the result in ``x``'s dtype."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, positions, theta):
+    """Rotate-halves RoPE on all of the head's dimensions: ``x`` ``[N,
+    heads, d]`` float32 at ``positions`` ``[N]``."""
+    import jax.numpy as jnp
+
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
+
+
+class RetentionPrograms(DecodePrograms):
+    """The decode program set over a :class:`~.kv_cache.StateLanePool`,
+    for a model whose layers are power-retention layers
+    (``models/brumby.py``): no keys and values, a state of constant size
+    a lane. The warm-up, compile-cache, donation and hot-swap machinery
+    is the slot family's; the programs are not:
+
+    - ``("prefill", 1, c)``: ONE CHUNK of one lane's prompt, ``c`` from
+      the seq ladder (whole chunks run the top rung, a ragged last chunk
+      the smallest rung that holds it). It takes the lane's state in and
+      gives it back: inside the chunk the masked quadratic form, across
+      chunks the state (``retention_chunk``). A prompt of 8k is four
+      calls of one program; ``fresh`` tells the first that the lane's
+      old state is to be ignored. Every call returns the token after its
+      last valid position; the scheduler keeps the last chunk's.
+    - ``("decode", b)``: one token a lane. The lanes' states are updated
+      in place under donation and read once: on a TPU by the kernel of
+      ``ops/pallas/retention.py``, which finds each lane's blocks through
+      the prefetched slot ids, elsewhere by a gather, the jnp step and a
+      scatter.
+
+    The layers run under ONE ``lax.scan`` over the stacked weights, the
+    pool riding the carry, so one layer is compiled once. Greedy only,
+    as the slot family."""
+
+    chunked = True
+    _extract = staticmethod(_extract_brumby)
+
+    def _bind_config(self, cfg, pool) -> None:
+        self._heads = int(cfg.num_attention_heads)
+        self._kv_heads = int(cfg.num_key_value_heads)
+        self._head_dim = int(cfg.head_dim)
+        self._hidden = int(cfg.hidden_size)
+        self._max_pos = int(cfg.max_position_embeddings)
+        self._eps = float(cfg.rms_norm_eps)
+        self._theta = float(cfg.rope_theta)
+        self._model_key = (
+            "brumby", int(cfg.vocab_size), self._hidden,
+            int(cfg.intermediate_size), int(cfg.num_hidden_layers),
+            self._heads, self._kv_heads, self._head_dim, self._eps,
+            self._theta, str(self.params["embed"].dtype),
+            tuple(int(d) for d in pool.state.shape), tuple(self._donate),
+            self._kernel())
+
+    @staticmethod
+    def _kernel() -> bool:
+        """Whether the decode step's state update is the Pallas kernel."""
+        from ..ops import pallas
+
+        return bool(pallas.enabled())
+
+    # ------------------------------------------------------------ the block
+    def _project(self, w, x, positions):
+        """``x`` ``[N, hidden]`` at ``positions`` ``[N]`` -> q ``[N, Hq,
+        d]`` and k ``[N, Hkv, d]`` in float32 (normed, rotated), v in the
+        activations' dtype, ``log g`` ``[N, Hkv]`` in float32."""
+        import jax
+        import jax.numpy as jnp
+
+        N, d = x.shape[0], self._head_dim
+        with region(regions.NORM):
+            a = _rms(x, w["input_norm"], self._eps)
+        with region(regions.ATTN_QKV):
+            qkv = a @ w["qkv_proj"]
+            nq, nk = self._heads * d, self._kv_heads * d
+            q = qkv[:, :nq].reshape(N, self._heads, d)
+            k = qkv[:, nq:nq + nk].reshape(N, self._kv_heads, d)
+            v = qkv[:, nq + nk:].reshape(N, self._kv_heads, d)
+        with region(regions.RETN_GATE):
+            log_g = jax.nn.log_sigmoid(
+                jnp.dot(a, w["g_proj"], preferred_element_type=jnp.float32)
+                + w["g_bias"])
+        with region(regions.NORM):
+            q = _rms(q.astype(jnp.float32), w["q_norm"], self._eps)
+            k = _rms(k.astype(jnp.float32), w["k_norm"], self._eps)
+        with region(regions.ROPE):
+            q = _rope(q, positions, self._theta)
+            k = _rope(k, positions, self._theta)
+        return q, k, v, log_g
+
+    def _finish(self, w, x, y):
+        """The layer's second half: the output projection of the
+        retention's ``y`` ``[N, Hq, d]``, then RMSNorm and SwiGLU."""
+        import jax
+        import jax.numpy as jnp
+
+        with region(regions.ATTN_OUT):
+            x = x + y.reshape(x.shape[0], -1).astype(x.dtype) @ w["o_proj"]
+        with region(regions.NORM):
+            b = _rms(x, w["post_norm"], self._eps)
+        with region(regions.MLP):
+            gate, up = jnp.split(b @ w["gate_up_proj"], 2, axis=-1)
+            return x + (jax.nn.silu(gate) * up) @ w["down_proj"]
+
+    def _logits_head(self, params, x):
+        with region(regions.LM_HEAD):
+            return _rms(x, params["norm"], self._eps) @ params["head"]
+
+    # ------------------------------------------------------------ programs
+    def _prefill_fn(self, params, state, tokens, lengths, slot_ids, starts,
+                    fresh):
+        import jax.numpy as jnp
+        from jax import lax
+
+        self.traces += 1
+        with region(regions.PREFILL):
+            C = tokens.shape[1]
+            n, slot, start = lengths[0], slot_ids[0], starts[0]
+            with region(regions.EMBED):
+                x = params["embed"][tokens[0]]
+            positions = start + jnp.arange(C, dtype=jnp.int32)
+            valid = jnp.arange(C) < n
+
+            def layer(carry, w):
+                x, state, li = carry
+                q, k, v, log_g = self._project(w, x, positions)
+                y, state = self._state_chunk(state, li, slot, fresh[0],
+                                             q, k, v, log_g, valid)
+                return (self._finish(w, x, y), state, li + 1), None
+
+            (x, state, _), _ = lax.scan(
+                layer, (x, state, jnp.zeros((), jnp.int32)), params["layers"])
+            # the token after the chunk's LAST VALID position
+            with region(regions.LM_HEAD):
+                x_last = lax.dynamic_slice_in_dim(x, n - 1, 1, axis=0)
+            head = self._logits_head(params, x_last)
+            with region(regions.SAMPLE):
+                return state, jnp.argmax(head, axis=-1).astype(jnp.int32)
+
+    def _state_chunk(self, state, li, slot, fresh, q, k, v, log_g, valid):
+        """One prefill chunk of one layer against the pool: the lane's
+        state taken out (ignored where the lane is ``fresh``), carried
+        through the chunk, put back; ``y`` ``[C, Hq, d]`` read."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        from ..nn.functional.power_retention import retention_chunk
+
+        with region(regions.RETN_CHUNK):
+            zero = jnp.zeros((), jnp.int32)
+            at = (li, slot, zero, zero, zero)
+            held = lax.dynamic_slice(state, at, (1, 1) + state.shape[2:])[0, 0]
+            keep = jnp.where(fresh != 0, 0.0, 1.0).astype(state.dtype)
+        y, held = retention_chunk(q, k, v, log_g, held * keep, valid)
+        with region(regions.RETN_CHUNK):
+            return y, lax.dynamic_update_slice(state, held[None, None], at)
+
+    def _state_step(self, state, li, slot_ids, q, k, v, log_g):
+        """One decode step of one layer against the pool: the touched
+        lanes' states updated in place, ``y`` ``[B, Hq, d]`` read."""
+        from ..nn.functional import power_retention as pr
+
+        if self._kernel():
+            from ..ops.pallas import retention as kernel
+
+            with region(regions.RETN_STATE):
+                state, total = kernel.retention_step(
+                    state, li, slot_ids, *pr.step_operands(q, k, v, log_g))
+                return pr.finish_step(total), state
+        with region(regions.RETN_STATE):
+            held = state[li, slot_ids]
+        y, held = pr.retention_step(q, k, v, log_g, held)
+        with region(regions.RETN_STATE):
+            return y, state.at[li, slot_ids].set(held)
+
+    def _decode_fn(self, params, state, tokens, slot_ids, positions):
+        import jax.numpy as jnp
+        from jax import lax
+
+        self.traces += 1
+        with region(regions.DECODE):
+            with region(regions.EMBED):
+                x = params["embed"][tokens]
+
+            def layer(carry, w):
+                x, state, li = carry
+                q, k, v, log_g = self._project(w, x, positions)
+                y, state = self._state_step(state, li, slot_ids, q, k, v, log_g)
+                return (self._finish(w, x, y), state, li + 1), None
+
+            (x, state, _), _ = lax.scan(
+                layer, (x, state, jnp.zeros((), jnp.int32)), params["layers"])
+            head = self._logits_head(params, x)
+            with region(regions.SAMPLE):
+                return state, jnp.argmax(head, axis=-1).astype(jnp.int32)
+
+    # -------------------------------------------------------------- rungs
+    @property
+    def rungs(self) -> List[tuple]:
+        return ([("decode", b) for b in self.decode_rungs]
+                + [("prefill", 1, c) for c in self.seq_ladder])
+
+    def _zero_args(self, key):
+        pad = self.pool.pad_slot
+        if key[0] == "decode":
+            return super()._zero_args(key)
+        c = key[2]
+        return (np.zeros((1, c), np.int32), np.ones(1, np.int32),
+                np.full(1, pad, np.int32), np.zeros(1, np.int32),
+                np.ones(1, np.int32))
+
+    # -------------------------------------------------------------- calls
+    def prefill(self, state, tokens, lengths, slot_ids, starts, fresh):
+        key = ("prefill", 1, int(tokens.shape[1]))
+        args = (tokens, lengths, slot_ids, starts, fresh)
+        ex = self._aot.get(key)
+        if ex is not None:
+            return ex(self.params, state, *args)
+        return self._jit_prefill(self.params, state, *args)
+
+    def decode(self, state, tokens, slot_ids, positions):
+        key = ("decode", int(tokens.shape[0]))
+        ex = self._aot.get(key)
+        if ex is not None:
+            return ex(self.params, state, tokens, slot_ids, positions)
+        return self._jit_decode(self.params, state, tokens, slot_ids,
+                                positions)
+
+
 class DecodeEngine(EngineBase):
     """GPT decode serving with true continuous batching.
 
@@ -946,6 +1214,11 @@ class DecodeEngine(EngineBase):
             raise ValueError(f"kv_mode must be 'paged' or 'slots', "
                              f"got {kv_mode!r}")
         cfg = model.config
+        # the residency follows from the model: one whose layers keep a
+        # recurrent state (``serving_residency = "state"``) has no keys
+        # and values to page or slot, whatever ``kv_mode`` says
+        if getattr(model, "serving_residency", "kv") == "state":
+            kv_mode = "state"
         max_slots = int(get_flag("serving_max_slots")
                         if max_slots is None else max_slots)
         flag_seq = int(get_flag("serving_max_seq"))
@@ -958,6 +1231,8 @@ class DecodeEngine(EngineBase):
         prefill_max = int(get_flag("serving_prefill_max_batch")
                           if prefill_max_batch is None else prefill_max_batch)
         prefill_max = min(prefill_max, max_slots)
+        if seq_buckets is None and kv_mode == "state":
+            seq_buckets = [min(2048, max_seq)]  # the prefill chunk
         if seq_buckets is None:
             seq_min = min(int(get_flag("serving_seq_bucket_min")), max_seq)
             # clamp the top rung: the power-of-two ladder rounds UP past a
@@ -971,11 +1246,12 @@ class DecodeEngine(EngineBase):
         spec_k = int(get_flag("serving_spec_k")
                      if speculate_k is None else speculate_k)
         spec_k = max(spec_k, 0)
-        if kv_mode == "slots" and spec_k > 0:
+        if kv_mode != "paged" and spec_k > 0:
             raise ValueError(
                 "self-speculative decoding rides the paged block tables; "
-                "the slots-mode engine is the greedy oracle — use "
-                "kv_mode='paged' for speculate_k > 0")
+                "the slots-mode engine is the greedy oracle and a state "
+                "lane has no rollback — use kv_mode='paged' (a model with "
+                "keys and values) for speculate_k > 0")
         self.kv_mode = kv_mode
         self.max_slots = max_slots  # max concurrent lanes in either mode
         self.eos_id = eos_id
@@ -984,7 +1260,19 @@ class DecodeEngine(EngineBase):
         from ..reliability.policy import RetryPolicy
 
         retry = RetryPolicy("serving.decode_step")
-        if kv_mode == "slots":
+        if kv_mode == "state":
+            self.kv_pool = StateLanePool(
+                cfg.num_hidden_layers, max_slots, cfg.num_key_value_heads,
+                cfg.head_dim, max_seq)
+            self.programs = RetentionPrograms(
+                model, self.kv_pool, seq_ladder=seq_buckets,
+                prefill_batch_rungs=[1],
+                decode_rungs=powers_of_two_buckets(1, max_slots))
+            self._scheduler = DecodeScheduler(
+                self.queue, self.programs, self.kv_pool,
+                prefill_max_batch=1, eos_id=eos_id, stats=stats,
+                retry=retry, breakers=self.breakers)
+        elif kv_mode == "slots":
             self.kv_pool = KVSlotPool(
                 cfg.num_hidden_layers, max_slots, max_seq,
                 cfg.num_attention_heads, cfg.head_dim, dtype=kv_dtype)
@@ -1057,9 +1345,10 @@ class DecodeEngine(EngineBase):
         built with ``speculate_k > 0``). Speculation never changes the
         token stream — committed tokens always come from the full-model
         verify pass — only how many commit per full-model call."""
-        if self.kv_mode == "slots" and temperature > 0:
+        if self.kv_mode != "paged" and temperature > 0:
             raise ValueError("sampled decoding needs kv_mode='paged'; "
-                             "the slot-pool engine is the greedy oracle")
+                             "the slot-pool engine is the greedy oracle "
+                             "and the state-lane programs are greedy")
         if speculate and not self.speculate_k:
             raise ValueError(
                 "speculate=True needs an engine built with speculate_k > 0 "
@@ -1071,7 +1360,8 @@ class DecodeEngine(EngineBase):
         req = DecodeRequest(tenant, prompt, max_new_tokens,
                             temperature=temperature, top_k=top_k,
                             top_p=top_p, seed=seed, speculate=spec)
-        top = self.programs.seq_ladder[-1]
+        top = (self.kv_pool.max_seq - 1 if self.programs.chunked
+               else self.programs.seq_ladder[-1])
         if req.prompt.size > top:
             raise ValueError(
                 f"prompt of {req.prompt.size} tokens exceeds the largest "
@@ -1189,7 +1479,7 @@ class DecodeEngine(EngineBase):
                 kv_pages_in_use=self.kv_pool.in_use(),
             )
         else:
-            health.update(kv_mode="slots",
+            health.update(kv_mode=self.kv_mode,
                           kv_slots_in_use=self.kv_pool.in_use())
         return health
 

@@ -39,7 +39,7 @@ from ..base import regions
 from ..base.regions import region
 from ..observability.locks import named_lock
 
-__all__ = ["KVSlotPool", "KVPagePool", "write_prompt",
+__all__ = ["LanePool", "KVSlotPool", "KVPagePool", "StateLanePool", "write_prompt",
            "write_prompt_batch", "append_token", "write_prompt_pages",
            "append_token_paged", "gather_pages"]
 
@@ -126,38 +126,24 @@ def gather_pages(cache, layer, tables):
 
 
 # --------------------------------------------------------------- the pool
-class KVSlotPool:
-    """Free-list slot allocator over one device-resident K/V buffer pair.
-
+class LanePool:
+    """The host side every lane-per-request pool shares: a free list of
+    ``max_slots`` lanes plus the *pad lane* (the last one, never
+    allocated), per-lane lengths, and the frozen footprint baseline.
     ``alloc()``/``release()`` run on the scheduler thread (a lock keeps
-    them safe for engine shutdown paths); the arrays themselves are
-    replaced wholesale by :meth:`commit` after each program call — the
-    functional update idiom, with donation making it in-place on
-    accelerators. :meth:`device_bytes` must never change after
-    :meth:`mark_warm` (the JX332 audit and the bench's
-    ``kv_pool_bytes_constant`` proof)."""
+    them safe for engine shutdown paths). A subclass owns the device
+    arrays: :meth:`arrays`, :meth:`commit`, :meth:`device_bytes` and the
+    occupancy gauge."""
 
-    def __init__(self, num_layers: int, max_slots: int, max_seq: int,
-                 num_heads: int, head_dim: int, dtype="float32"):
-        import jax.numpy as jnp
-
+    def __init__(self, max_slots: int, max_seq: int):
         if max_slots < 1:
-            raise ValueError("KVSlotPool needs at least one slot")
-        self.num_layers = int(num_layers)
+            raise ValueError(f"{type(self).__name__} needs at least one slot")
         self.max_slots = int(max_slots)
         self.max_seq = int(max_seq)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
-        # +1: the pad slot — garbage writes from bucket-padding lanes
-        shape = (self.num_layers, self.max_slots + 1, self.max_seq,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
         self.lengths = np.zeros(self.max_slots, np.int32)  # host-side
         self._free: List[int] = list(range(self.max_slots - 1, -1, -1))
         self._lock = named_lock("serving.kv_pool")
         self.bytes_at_warmup: Optional[int] = None
-        self._gauge_occupancy()
 
     # ------------------------------------------------------------ slots
     @property
@@ -199,6 +185,46 @@ class KVSlotPool:
         with self._lock:
             return len(self._free)
 
+    def mark_warm(self) -> None:
+        """Freeze the footprint baseline (end of engine warmup): any
+        later :meth:`device_bytes` drift is a JX332 error."""
+        self.bytes_at_warmup = self.device_bytes()
+
+    def arrays(self) -> tuple:
+        """The device arrays a program call takes and gives back."""
+        raise NotImplementedError
+
+    def device_bytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.arrays())
+
+
+class KVSlotPool(LanePool):
+    """Free-list slot allocator over one device-resident K/V buffer pair.
+
+    The arrays themselves are replaced wholesale by :meth:`commit` after
+    each program call — the functional update idiom, with donation making
+    it in-place on accelerators. :meth:`device_bytes` must never change
+    after :meth:`mark_warm` (the JX332 audit and the bench's
+    ``kv_pool_bytes_constant`` proof)."""
+
+    def __init__(self, num_layers: int, max_slots: int, max_seq: int,
+                 num_heads: int, head_dim: int, dtype="float32"):
+        import jax.numpy as jnp
+
+        super().__init__(max_slots, max_seq)
+        self.num_layers = int(num_layers)
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        # +1: the pad slot — garbage writes from bucket-padding lanes
+        shape = (self.num_layers, self.max_slots + 1, self.max_seq,
+                 self.num_heads, self.head_dim)
+        self.k = jnp.zeros(shape, dtype)
+        self.v = jnp.zeros(shape, dtype)
+        self._gauge_occupancy()
+
+    def arrays(self) -> tuple:
+        return self.k, self.v
+
     # ------------------------------------------------------------ buffers
     def commit(self, new_k, new_v) -> None:
         """Swap in the post-step buffers (the jitted program's functional
@@ -225,14 +251,6 @@ class KVSlotPool:
 
         numerics.watch("serving.kv_commit", new_k)
 
-    def device_bytes(self) -> int:
-        return int(self.k.nbytes) + int(self.v.nbytes)
-
-    def mark_warm(self) -> None:
-        """Freeze the footprint baseline (end of engine warmup): any
-        later :meth:`device_bytes` drift is a JX332 error."""
-        self.bytes_at_warmup = self.device_bytes()
-
     # ------------------------------------------------------ observability
     def _gauge_occupancy(self) -> None:
         from ..observability.metrics import registry
@@ -241,6 +259,70 @@ class KVSlotPool:
             "serving.kv_slots_in_use",
             "KV cache slots currently allocated to live decode sequences "
             "(capacity = FLAGS_serving_max_slots)").set(
+                self.max_slots - len(self._free))
+
+
+# --------------------------------------------------------- the state pool
+class StateLanePool(LanePool):
+    """The third residency: a *recurrent state* a lane, for a model whose
+    layers remember the sequence in a state of constant size (power
+    retention, ``nn/functional/power_retention.py``) and keep no keys or
+    values. ONE device array ``[layers, max_slots + 1, kv_heads, head_dim
+    + 8, D]`` in float32, allocated once, the pad lane last: a lane's
+    state is as many bytes at token 100 as at token 30,000, so the pool
+    sets no limit on a sequence's length (``max_seq`` is the model's
+    position limit, kept for the scheduler's retirement test). Row
+    ``head_dim`` of a head's state is the normaliser ``z``, so the update
+    and the read are one pass over one array; ``D`` is the minor
+    dimension, whole lanes of 128 at the published head size.
+
+    A lane that JOINS is not cleared here: the first prefill chunk of a
+    request ignores what the lane held (``fresh``), which is the zeroing.
+    Programs take and return the array under donation; the decode step's
+    kernel updates the touched lanes in place and reads each once."""
+
+    def __init__(self, num_layers: int, max_slots: int, num_kv_heads: int,
+                 head_dim: int, max_seq: int):
+        import jax.numpy as jnp
+
+        from ..nn.functional.power_retention import phi_dim, state_rows
+
+        super().__init__(max_slots, max_seq)
+        self.num_layers = int(num_layers)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.state = jnp.zeros(
+            (self.num_layers, self.max_slots + 1, self.num_kv_heads,
+             state_rows(self.head_dim), phi_dim(self.head_dim)), jnp.float32)
+        self._gauge_occupancy()
+        from ..observability.metrics import registry
+
+        registry.gauge(
+            "serving.state_pool_bytes",
+            "device bytes of the recurrent-state lane pool (allocated once)"
+        ).set(self.device_bytes())
+
+    def arrays(self) -> tuple:
+        return (self.state,)
+
+    def commit(self, new_state) -> None:
+        """Swap in the post-step array; shape and dtype are pinned, and an
+        injected ``kv.commit`` fault rejects the swap before it."""
+        from ..reliability.faults import fault_point
+
+        fault_point("kv.commit")
+        if new_state.shape != self.state.shape or new_state.dtype != self.state.dtype:
+            raise ValueError(
+                f"state commit changed the pool footprint: {self.state.shape}/"
+                f"{self.state.dtype} -> {new_state.shape}/{new_state.dtype}")
+        self.state = new_state
+
+    def _gauge_occupancy(self) -> None:
+        from ..observability.metrics import registry
+
+        registry.gauge(
+            "serving.state_lanes_in_use",
+            "recurrent-state lanes currently held by live sequences").set(
                 self.max_slots - len(self._free))
 
 
@@ -355,6 +437,10 @@ class KVPagePool:
             return len(self._free)
 
     # ------------------------------------------------------------ buffers
+    def arrays(self) -> tuple:
+        """The device arrays a program call takes and gives back."""
+        return self.k, self.v
+
     def commit(self, new_k, new_v) -> None:
         """Swap in the post-step buffers — same contract as
         :meth:`KVSlotPool.commit`: footprint pinned, ``kv.commit``

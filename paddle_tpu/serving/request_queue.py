@@ -128,7 +128,7 @@ class DecodeRequest(Request):
     denominated in slots for the decode tier."""
 
     __slots__ = ("prompt", "max_new_tokens", "generated", "slot", "seq_rung",
-                 "pages", "temperature", "top_k", "top_p", "seed",
+                 "cursor", "pages", "temperature", "top_k", "top_p", "seed",
                  "speculate", "spec_live", "spec_proposed", "spec_accepted",
                  "t_first_token")
 
@@ -149,6 +149,7 @@ class DecodeRequest(Request):
         self.t_first_token: Optional[float] = None
         self.slot = None          # KV slot, assigned at admission-to-slot
         self.seq_rung = None      # prefill seq-ladder rung (scheduler set)
+        self.cursor = 0           # prompt tokens already prefilled (chunked programs)
         self.pages: List[int] = []  # block table (paged pools only)
         # sampling knobs ride the programs as traced DATA (never a
         # retrace); temperature 0 = greedy, the bit-exact audit mode

@@ -270,6 +270,7 @@ class DecodeScheduler:
         self._active: Dict[int, object] = {}    # slot -> DecodeRequest
         self._pending: List[object] = []        # slot held, prefill due
         self._step_lanes: List[object] = []     # lanes riding the current call
+        self._owe_decode = False  # a prompt's chunk just ran: the lanes decode next
         self.shed_count = 0
         self._beat = 0            # running index of scheduler beats
         self._beat_kind = "idle"  # what this beat ran (set by the step)
@@ -371,10 +372,14 @@ class DecodeScheduler:
         return len(taken)
 
     def _step(self, monitor) -> bool:
-        if self._pending:
+        # prefill first, except right after a CHUNK of a prompt (programs
+        # that prefill in pieces): then the decoding lanes get their beat,
+        # so a prompt of many chunks never starves them for its length
+        if self._pending and not (self._owe_decode and self._active):
             self._guarded(self._prefill_step, monitor)
             return True
         if self._active:
+            self._owe_decode = False
             self._guarded(self._decode_step, monitor)
             return True
         return False
@@ -404,8 +409,8 @@ class DecodeScheduler:
         device may still be running), ``serving.read`` is the wait for
         the tokens."""
         with self._span("serving.dispatch", program=program):
-            ck, cv, toks = self._program_call(call)
-        self.pool.commit(ck, cv)
+            *arrays, toks = self._program_call(call)
+        self.pool.commit(*arrays)
         with self._span("serving.read", program=program):
             return np.asarray(toks)
 
@@ -419,7 +424,9 @@ class DecodeScheduler:
     def _seq_rung(self, r) -> int:
         from ..jit.bucketing import bucket_for
 
-        return bucket_for(int(r.prompt.size), self.programs.seq_ladder)
+        ladder = self.programs.seq_ladder
+        # a prompt past the top rung is one that chunked programs cut
+        return bucket_for(min(int(r.prompt.size), ladder[-1]), ladder)
 
     def _guarded(self, step, monitor) -> None:
         """Batch-scoped fault wall: a crashed program call fails exactly
@@ -514,6 +521,8 @@ class DecodeScheduler:
     def _prefill_step(self) -> None:
         from ..jit.bucketing import bucket_for
 
+        if getattr(self.programs, "chunked", False):
+            return self._prefill_chunk_step()
         with self._span("serving.build", lanes=0, rung=None) as sp:
             rung = self._pending[0].seq_rung  # oldest request anchors the rung
             group = [r for r in self._pending
@@ -536,10 +545,62 @@ class DecodeScheduler:
         t0 = time.perf_counter()
         with self._step_span("prefill", (b_rung, rung), group):
             toks = self._call_and_read("prefill", lambda: self.programs.prefill(
-                self.pool.k, self.pool.v, tokens, lengths, slots))
+                *self.pool.arrays(), tokens, lengths, slots))
         self._absorb_traced(group, self._absorb, toks, kind="prefill",
                             seconds=time.perf_counter() - t0,
                             rung=(b_rung, rung))
+
+    def _prefill_chunk_step(self) -> None:
+        """One chunk of the OLDEST pending request's prompt, for programs
+        that prefill in pieces (``programs.chunked``: a recurrent state
+        carries what came before). The request keeps a cursor; a whole
+        chunk takes the ladder's top rung, the ragged last one the
+        smallest rung that holds it; the first chunk tells the program
+        that the lane is fresh (its old state is ignored: joining a lane
+        is zeroing it); the last one yields the first token. Between two
+        chunks the decoding lanes get their beat (:meth:`_step`)."""
+        from ..jit.bucketing import bucket_for
+        from ..observability.metrics import registry
+
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            r = self._pending.pop(0)  # back at the head if chunks remain
+            ladder = self.programs.seq_ladder
+            size, top = int(r.prompt.size), ladder[-1]
+            left = size - r.cursor
+            rung = top if left >= top else bucket_for(left, ladder)
+            n = min(left, rung)
+            last = r.cursor + n >= size
+            self._step_lanes = [r]  # the fault wall's blast radius
+            tokens = np.zeros((1, rung), np.int32)
+            tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
+            args = (tokens, np.asarray([n], np.int32),
+                    np.asarray([r.slot], np.int32),
+                    np.asarray([r.cursor], np.int32),
+                    np.asarray([r.cursor == 0], np.int32))
+            if sp.id is not None:
+                sp.args.update(lanes=1, rung=(1, rung))
+        t0 = time.perf_counter()
+        with self._step_span("prefill", (1, rung), [r], chunk=r.cursor // top,
+                             chunks=-(-size // top), tokens=n):
+            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
+                *self.pool.arrays(), *args))
+        r.cursor += n
+        self._owe_decode = True
+        registry.counter(
+            "serving.prefill_chunks",
+            "prefill chunks run by the decode scheduler (a prompt longer "
+            "than the chunk rung takes several beats)").inc()
+        if last:
+            self._absorb_traced([r], self._absorb, toks, kind="prefill",
+                                seconds=time.perf_counter() - t0,
+                                rung=(1, rung))
+        else:
+            self._step_lanes = []  # the call succeeded: nothing to fail
+            self._pending.insert(0, r)
+            with self._span("serving.absorb", retired=0):
+                if self.stats is not None:
+                    self.stats.record_decode_step(
+                        "prefill", time.perf_counter() - t0, 1, 0)
 
     def _decode_step(self) -> None:
         from ..jit.bucketing import bucket_for
@@ -561,7 +622,7 @@ class DecodeScheduler:
         t0 = time.perf_counter()
         with self._step_span("decode", b_rung, lanes):
             toks = self._call_and_read("decode", lambda: self.programs.decode(
-                self.pool.k, self.pool.v, tokens, slots, positions))
+                *self.pool.arrays(), tokens, slots, positions))
         self._absorb_traced(lanes, self._absorb, toks, kind="decode",
                             seconds=time.perf_counter() - t0, rung=b_rung)
 

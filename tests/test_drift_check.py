@@ -50,7 +50,7 @@ def _errors(findings):
 def test_committed_lockfile_shape_and_coverage():
     """The acceptance floor: version pinned, >= 10 programs, all three
     TrainStep tiers, >= 2 serving rungs, >= 2 paged-decode rungs, the
-    qpsum oracle and a reshard route — the full performance story."""
+    state residency's prefill chunk and decode rung, the qpsum oracle and a reshard route — the full performance story."""
     lock = _lock()
     assert lock["version"] == 1
     progs = lock["programs"]
@@ -59,6 +59,8 @@ def test_committed_lockfile_shape_and_coverage():
         assert f"train_step/{tier}" in progs
     assert len([n for n in progs if n.startswith("serving/batch:")]) >= 2
     assert len([n for n in progs if n.startswith("decode/paged:")]) >= 2
+    assert {n for n in progs if n.startswith("decode/state:")} == {
+        "decode/state:decode:2", "decode/state:prefill:1:8"}
     assert "collective/qpsum" in progs
     assert "reshard/s_to_s" in progs
     # every fingerprint carries the full canonical schema
@@ -69,7 +71,8 @@ def test_committed_lockfile_shape_and_coverage():
                                    "comm_bytes", "peak_bytes",
                                    "guard_preds"}, name
     # the rung grids cover the serving + decode groups
-    assert set(lock["rung_grids"]) == {"serving/batch", "decode/paged"}
+    assert set(lock["rung_grids"]) == {"serving/batch", "decode/paged",
+                                       "decode/state"}
 
 
 def test_lock_digest_matches_committed_bytes():
