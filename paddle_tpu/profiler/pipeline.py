@@ -148,6 +148,8 @@ class ServingStats:
                 "draft_ms": [], "verify_ms": [],     # bounded rings
                 "spec_rounds": 0, "spec_proposed": 0,
                 "spec_accepted": 0, "spec_committed": 0,
+                # program calls that sorted the vocabulary (a lane sampled)
+                "sample_sort_steps": 0,
                 "tokens": 0, "t_first": None, "t_last": None,
                 "occ_sum": 0, "occ_samples": 0, "occ_peak": 0,
                 "slots": 0,
@@ -231,6 +233,15 @@ class ServingStats:
             if cell["t_first"] is None:
                 cell["t_first"] = now - seconds
             cell["t_last"] = now
+
+    def record_sample_sort(self, calls: int):
+        """``calls`` program calls of one step carried a lane with
+        ``temperature > 0``, so each sorted the vocabulary in every lane
+        (``PagedDecodePrograms._choose_tokens``). One minus
+        ``sample_sort_steps`` over the sum of the four ``*_steps`` is
+        the share of calls that chose by argmax alone."""
+        with self._lock:
+            self._decode["sample_sort_steps"] += int(calls)
 
     def record_spec_round(self, proposed: int, accepted: int,
                           committed: int):
@@ -347,6 +358,7 @@ class ServingStats:
         out = {
             "prefill_steps": cell["prefill_steps"],
             "decode_steps": cell["decode_steps"],
+            "sample_sort_steps": cell["sample_sort_steps"],
             "prefill_p50_ms": pct(prefill, 0.50),
             "prefill_p99_ms": pct(prefill, 0.99),
             "decode_p50_ms": pct(decode, 0.50),

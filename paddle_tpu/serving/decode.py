@@ -497,8 +497,11 @@ class PagedDecodePrograms(DecodePrograms):
       both replay warm.
     - sampling rides as traced per-lane arguments (temperature / top-k
       / top-p / raw uint32 PRNG key pair): sampling is data too, never
-      a retrace. ``temp == 0`` lanes take the argmax branch bit-exactly
-      — the greedy audit mode the slot oracle is compared against.
+      a retrace. ``temp == 0`` lanes take the argmax bit-exactly — the
+      greedy audit mode the slot oracle is compared against — and a
+      call in which no lane samples skips the vocabulary sort whole
+      (one ``lax.cond`` in :meth:`_choose_tokens`); a call with one
+      sampling lane sorts for every lane.
 
     With ``speculate_k > 0`` two more program families join the same
     (batch rung × table rung) grid — self-speculative decoding over the
@@ -599,6 +602,13 @@ class PagedDecodePrograms(DecodePrograms):
         with ``jax.random.categorical`` from the lane's own raw uint32
         key pair — the key is ``[request_seed, token_index]`` on the
         host, so a request's stream never depends on batch composition.
+
+        The sampled path sits under one ``lax.cond`` on ``any(temps >
+        0)``: a call with no sampling lane runs the argmax and nothing
+        else (no sort, softmax, cumulative sum or draw); a call with one
+        runs the sort for EVERY lane and keeps the argmax wherever
+        ``temp == 0``. The predicate is data, so both kinds of call
+        replay the same compiled program.
         """
         import jax
         import jax.numpy as jnp
@@ -622,8 +632,11 @@ class PagedDecodePrograms(DecodePrograms):
                 filtered = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
                 return jax.random.categorical(key, filtered).astype(jnp.int32)
 
-            sampled = jax.vmap(lane)(head, temps, top_ks, top_ps, rkeys)
-            return jnp.where(temps > 0, sampled, greedy)
+            def sample():
+                sampled = jax.vmap(lane)(head, temps, top_ks, top_ps, rkeys)
+                return jnp.where(temps > 0, sampled, greedy)
+
+            return jax.lax.cond(jnp.any(temps > 0), sample, lambda: greedy)
 
     # ----------------------------------------------------------- programs
     def _prefill_fn(self, params, ck, cv, tokens, lengths, tables,

@@ -395,7 +395,7 @@ class DecodeScheduler:
         """The ``serving.decode`` span of one step. Its name and its
         ``kind``/``rung``/``lanes`` arguments are what the benchmark's
         readers filter on; ``requests`` (the lane request ids) is built
-        only when recording."""
+        only when recording. The paged scheduler adds ``sampling``."""
         self._beat_kind = kind
         if self._trace is None:
             return _NULL_SPAN
@@ -841,7 +841,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         key is ``[request_seed, generated_token_index]`` — a pure
         function of the request, never of batch composition, so sampled
         streams are deterministic per seed under any join/leave order.
-        Pad lanes carry temperature 0 (the cheap greedy branch)."""
+        Pad lanes carry temperature 0: a call whose every lane does runs
+        no vocabulary sort, and a call with one sampling lane runs it
+        for every lane, pads included (``_choose_tokens``)."""
         temps = np.zeros(b_rung, np.float32)
         top_ks = np.zeros(b_rung, np.int32)
         top_ps = np.ones(b_rung, np.float32)
@@ -853,6 +855,17 @@ class PagedDecodeScheduler(DecodeScheduler):
             rkeys[i] = (np.uint32(r.seed & 0xFFFFFFFF),
                         np.uint32(len(r.generated)))
         return temps, top_ks, top_ps, rkeys
+
+    def _step_span(self, kind: str, rung, lanes, **args):
+        """The base class's span plus ``sampling``, the lanes of the
+        step with ``temperature > 0``. If there is one, each program
+        call of the step (a speculation round makes two) sorts the
+        vocabulary in every lane, and the stats count those calls."""
+        sampling = sum(1 for r in lanes if r.temperature > 0)
+        if sampling and self.stats is not None:
+            self.stats.record_sample_sort(2 if kind == "speculate" else 1)
+        return super()._step_span(kind, rung, lanes, sampling=sampling,
+                                  **args)
 
     def _prefill_step(self) -> None:
         from ..jit.bucketing import bucket_for

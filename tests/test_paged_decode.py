@@ -51,6 +51,18 @@ def engine(model):
 
 
 @pytest.fixture(scope="module")
+def programs():
+    """A speculating engine's four program families, not warmed: for
+    the tests that walk a traced program."""
+    model = _tiny_model(num_hidden_layers=2, num_attention_heads=2,
+                        max_position_embeddings=64)
+    eng = _paged(model, max_seq=64, seq_buckets=[32, 64], page_size=16,
+                 speculate_k=2, spec_draft_layers=1)
+    yield eng.programs
+    eng.shutdown(drain=False)
+
+
+@pytest.fixture(scope="module")
 def oracle(model):
     """The PR 13 slot-pool engine: greedy decode ground truth."""
     eng = serving.DecodeEngine(
@@ -212,15 +224,6 @@ class TestMergedHeadsLayout:
                           precision="highest").reshape(B, queries, HD)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-5)
-
-    @pytest.fixture(scope="class")
-    def programs(self):
-        model = _tiny_model(num_hidden_layers=2, num_attention_heads=2,
-                            max_position_embeddings=64)
-        eng = _paged(model, max_seq=64, seq_buckets=[32, 64], page_size=16,
-                     speculate_k=2, spec_draft_layers=1)
-        yield eng.programs
-        eng.shutdown(drain=False)
 
     @pytest.mark.parametrize("kind", ["decode", "draft", "verify"])
     def test_no_program_splits_a_page_or_more(self, programs, kind):
@@ -386,6 +389,211 @@ class TestSampledDecoding:
         with pytest.raises(ValueError, match="greedy oracle"):
             oracle.submit("s", self.PROMPT, max_new_tokens=4,
                           temperature=0.9)
+
+
+# ------------------------------------- the sort runs only when a lane samples
+def _parent_choose_tokens(head, temps, top_ks, top_ps, rkeys):
+    """``PagedDecodePrograms._choose_tokens`` as it stood before the
+    ``lax.cond`` (PR 30's body, without its region): every lane sorted,
+    ``temp == 0`` lanes overwritten by the argmax at the end."""
+    import jax
+    import jax.numpy as jnp
+
+    greedy = jnp.argmax(head, axis=-1).astype(jnp.int32)
+    V = head.shape[-1]
+
+    def lane(lg, temp, tk, tp, key):
+        lg = lg.astype(jnp.float32)
+        scaled = lg / jnp.where(temp > 0, temp, 1.0)
+        srt = jnp.sort(scaled)[::-1]  # descending
+        rank = jnp.arange(V)
+        k_eff = jnp.clip(jnp.where(tk > 0, tk, V), 1, V)
+        probs = jax.nn.softmax(srt)
+        p_eff = jnp.where((tp > 0.0) & (tp < 1.0), tp, 1.0)
+        keep = (rank < k_eff) & (jnp.cumsum(probs) - probs < p_eff)
+        cutoff = jnp.min(jnp.where(keep, srt, jnp.inf))
+        filtered = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+        return jax.random.categorical(key, filtered).astype(jnp.int32)
+
+    sampled = jax.vmap(lane)(head, temps, top_ks, top_ps, rkeys)
+    return jnp.where(temps > 0, sampled, greedy)
+
+
+def _steps(stats) -> int:
+    """Program calls the stats counted, of every kind."""
+    cell = stats.summary()["decode"]
+    return sum(cell.get(f"{kind}_steps", 0)
+               for kind in ("prefill", "decode", "draft", "verify"))
+
+
+class TestSortOnlyWhenSampling:
+    PROMPTS = _prompts([40, 50, 63, 100], seed=41)
+
+    # one token choice a program, or one a draft step / verify position
+    @pytest.mark.parametrize("kind,key,choices",
+                             [("prefill", ("prefill", 2, 32), 1),
+                              ("decode", ("decode", 4, 2), 1),
+                              ("draft", ("draft", 4, 2), 2),
+                              ("verify", ("verify", 4, 2), 3)])
+    def test_sort_sits_inside_a_cond_branch(self, programs, kind, key,
+                                            choices):
+        """Walk the traced program: outside a ``cond`` it holds no
+        ``sort`` (nor the cumulative sum or the draw's bits), the
+        ``sample`` region holds one ``cond`` a token choice whose
+        predicate is computed in the program, the argmax stays outside
+        it, and the sort is in one branch of each."""
+        import jax
+
+        from paddle_tpu.analysis.drift_check import _sub_jaxprs, _walk
+
+        P = programs
+        assert key in P.rungs
+        fn = {"prefill": P._prefill_fn, "decode": P._decode_fn,
+              "draft": P._draft_fn, "verify": P._verify_fn}[kind]
+        closed = jax.make_jaxpr(fn)(P._call_params(key), P.pool.k, P.pool.v,
+                                    *P._zero_args(key))
+
+        def outside_conds(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                if eqn.primitive.name != "cond":
+                    for sub in _sub_jaxprs(eqn):
+                        yield from outside_conds(sub)
+
+        top = list(outside_conds(closed.jaxpr))
+        names = [e.primitive.name for e in top]
+        for banned in ("sort", "cumsum", "random_bits", "threefry2x32"):
+            assert banned not in names, (kind, banned)
+        conds = [e for e in top if e.primitive.name == "cond"]
+        assert len(conds) == choices
+        assert names.count("argmax") == choices
+        for e in conds + [e for e in top if e.primitive.name == "argmax"]:
+            assert str(e.source_info.name_stack).endswith("sample")
+        for e in conds:
+            sorts = [sum(x.primitive.name == "sort" for x in _walk(b.jaxpr))
+                     for b in e.params["branches"]]
+            assert sorted(sorts) == [0, 1]
+            # the predicate is the program's own, not a host constant
+            assert not hasattr(e.invars[0], "val")  # a Literal has one
+
+    def test_greedy_batch_equals_slot_oracle_and_sorts_nothing(self, engine,
+                                                               oracle):
+        before = engine.stats.summary()["decode"]["sample_sort_steps"]
+        paged = [engine.submit("g", p, max_new_tokens=8)
+                 for p in self.PROMPTS]
+        slot = [oracle.submit("g", p, max_new_tokens=8)
+                for p in self.PROMPTS]
+        for pr, sr in zip(paged, slot):
+            assert np.array_equal(pr.result(60), sr.result(60))
+        after = engine.stats.summary()["decode"]["sample_sort_steps"]
+        assert after == before
+
+    def test_mixed_batch_keeps_every_lanes_stream(self, engine):
+        """One sampling lane among three greedy ones: the batch sorts,
+        the sampled lane draws what it draws alone, and the greedy
+        lanes keep the tokens of an all-greedy batch."""
+        sampled_kw = dict(max_new_tokens=10, temperature=1.5, seed=7)
+        solo = engine.submit("s", self.PROMPTS[0], **sampled_kw).result(60)
+        greedy = [engine.submit("g", p, max_new_tokens=10)
+                  for p in self.PROMPTS[1:]]
+        greedy = [r.result(60) for r in greedy]
+        before = engine.stats.summary()["decode"]["sample_sort_steps"]
+        mixed = [engine.submit("s", self.PROMPTS[0], **sampled_kw)]
+        mixed += [engine.submit("g", p, max_new_tokens=10)
+                  for p in self.PROMPTS[1:]]
+        mixed = [r.result(60) for r in mixed]
+        assert np.array_equal(mixed[0], solo)
+        for got, want in zip(mixed[1:], greedy):
+            assert np.array_equal(got, want)
+        after = engine.stats.summary()["decode"]["sample_sort_steps"]
+        assert after - before >= 10  # each call the sampled lane rode
+
+    @pytest.mark.parametrize("temps,top_ks,top_ps", [
+        ([0.0, 0.0, 0.0, 0.0], [0, 0, 0, 0], [1.0, 1.0, 1.0, 1.0]),
+        ([1.5, 0.0, 0.0, 0.0], [0, 0, 0, 0], [1.0, 1.0, 1.0, 1.0]),
+        ([0.9, 0.9, 0.9, 0.9], [16, 16, 16, 16], [0.9, 0.9, 0.9, 0.9]),
+        ([0.7, 0.0, 1.3, 2.0], [0, 5, 1, 40], [0.5, 0.9, 1.0, 0.95]),
+    ], ids=["all-greedy", "one-sampling", "topk-topp", "mixed"])
+    def test_streams_are_the_parents_per_seed(self, engine, temps, top_ks,
+                                              top_ps):
+        """On the same logits and keys, eager and jitted, the tokens
+        are those of the parent's body for every lane and seed."""
+        import jax
+
+        rs = np.random.RandomState(43)
+        head = (3.0 * rs.randn(4, 128)).astype(np.float32)
+        args = (np.asarray(temps, np.float32), np.asarray(top_ks, np.int32),
+                np.asarray(top_ps, np.float32))
+        choose = engine.programs._choose_tokens
+        for seed in (3, 7, 2**31 + 5):
+            for index in range(4):
+                rkeys = np.tile(np.asarray([seed, index], np.uint32), (4, 1))
+                want = np.asarray(_parent_choose_tokens(head, *args, rkeys))
+                assert np.array_equal(choose(head, *args, rkeys), want)
+                assert np.array_equal(jax.jit(choose)(head, *args, rkeys),
+                                      want)
+                greedy = np.asarray(temps) == 0
+                assert np.array_equal(want[greedy],
+                                      head.argmax(-1)[greedy])
+
+    @pytest.mark.parametrize("speculate_k", [0, 2], ids=["plain", "speculate"])
+    def test_span_carries_sampling_and_stats_count_sort_calls(
+            self, speculate_k):
+        """``serving.decode`` carries ``sampling``; the stats count no
+        call of a greedy run and every call of a sampled request's."""
+        from paddle_tpu.observability import tracer
+
+        model = _tiny_model(num_hidden_layers=2, max_position_embeddings=64)
+        stats = ServingStats()
+        tracer.reset()
+        was = tracer.enabled
+        tracer.enable()
+        try:
+            eng = _paged(model, max_seq=64, seq_buckets=[32, 64],
+                         page_size=16, speculate_k=speculate_k,
+                         spec_draft_layers=1, spec_min_accept=0.0,
+                         stats=stats).warmup()
+            try:
+                greedy = [eng.submit("g", p, max_new_tokens=6)
+                          for p in _prompts([20, 30, 40], seed=45)]
+                for r in greedy:
+                    r.result(60)
+                assert _steps(stats) > 0
+                assert stats.summary()["decode"]["sample_sort_steps"] == 0
+                n_greedy = _steps(stats)
+                one = eng.submit("s", _prompts([25], seed=46)[0],
+                                 max_new_tokens=6, temperature=1.5, seed=7)
+                one.result(60)
+                assert (stats.summary()["decode"]["sample_sort_steps"]
+                        == _steps(stats) - n_greedy > 0)
+            finally:
+                eng.shutdown(drain=True)
+            steps = [e for e in tracer.to_chrome_trace()["traceEvents"]
+                     if e["ph"] == "X" and e["name"] == "serving.decode"]
+        finally:
+            tracer.enabled = was
+            tracer.reset()
+        # the tracer is the process's: keep this engine's steps
+        mine = {r.id for r in greedy} | {one.id}
+        steps = [e for e in steps if set(e["args"]["requests"]) <= mine]
+        by_sampling = {0: 0, 1: 0}
+        for e in steps:
+            want = int(one.id in e["args"]["requests"])
+            assert e["args"]["sampling"] == want
+            by_sampling[want] += 1
+        assert by_sampling[0] > 0 and by_sampling[1] > 0
+
+    def test_alternating_greedy_and_sampled_batches_never_retrace(self,
+                                                                  engine):
+        warmed = engine.programs.traces
+        for i in range(3):
+            kw = dict(temperature=0.8, top_k=8, seed=i) if i % 2 else {}
+            reqs = [engine.submit("alt", p, max_new_tokens=4, **kw)
+                    for p in self.PROMPTS]
+            for r in reqs:
+                r.result(60)
+        assert engine.programs.traces == warmed
+        assert engine.serving_report()["compiles_after_warmup"] == 0
 
 
 # ------------------------------------------------------- pool pressure
