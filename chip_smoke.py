@@ -191,7 +191,7 @@ def train(cfg, batch, seq, steps, attention_op, facts, sharding=None):
     model = GPTForCausalLM(cfg)
     criterion = GPTPretrainingCriterion(cfg)
     paddle.amp.decorate(model, level="O2", dtype="bfloat16")
-    # 1e-4 as in bench.py: with no warm-up, 3e-4 overshot on the fourth step
+    # 1e-4 as in the train cell: with no warm-up, 3e-4 overshot on the 4th step
     opt = paddle.optimizer.AdamW(learning_rate=1e-4,
                                  parameters=model.parameters())
 

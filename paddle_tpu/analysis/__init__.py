@@ -29,8 +29,8 @@ analyzers that run at commit time:
   one-hop cross-file mesh-declaration resolution.
 - :mod:`cost_model` — static FLOPs/bytes/collective-volume/peak-residency
   walker over the same retraced ClosedJaxprs (CM5xx), feeding
-  ``CompiledFunction.cost()``, the planner's jaxpr-backed HBM estimates
-  and bench's ``extras.cost_model``.
+  ``CompiledFunction.cost()`` and the planner's jaxpr-backed HBM
+  estimates.
 - :mod:`telemetry_check` — the observability layer's own contract
   (OB6xx): no unclosed span at trace export, no duplicate metric
   registration, no blocking device sync inside a memory sampler.

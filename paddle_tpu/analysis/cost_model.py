@@ -42,7 +42,7 @@ aggregate:
 Everything lands in one :class:`CostReport`, exposed as
 ``CompiledFunction/BucketedFunction/TrainStep.cost()`` (per-entry
 breakdown under ``.per_entry``) and per cached executable via
-``core.kernel_cache.cost_stats()``. Three consumers:
+``core.kernel_cache.cost_stats()``. Two consumers:
 
 1. the ``cost`` family of ``python -m tools.lint`` (:func:`check_cost`):
 
@@ -69,9 +69,7 @@ breakdown under ``.per_entry``) and per cached executable via
    jaxpr-backed ``estimate_per_device_bytes``/``estimate_step_cost``
    that prefer measured-from-jaxpr numbers over the closed-form
    transformer accounting, and ``compare_with_measured`` reporting all
-   three (closed-form / cost-model / XLA memory_analysis);
-3. ``bench.py`` ``extras.cost_model`` (analysis wall-time, estimated vs
-   measured peak, step FLOPs for gpt_tiny).
+   three (closed-form / cost-model / XLA memory_analysis).
 
 The per-layer formulas ``hapi/dynamic_flops.py`` applies through its
 forward-hook API live here too (:func:`linear_flops` et al., MAC
@@ -143,7 +141,7 @@ _REDUCTIONS = {
 # shared layer-level formulas (hapi/dynamic_flops.py delegates here).
 # MAC convention (1 multiply-accumulate = 1 FLOP) for parity with the
 # reference's paddle.flops; the jaxpr walker below uses the standard
-# 2·MAC convention, matching bench.py's analytic step-FLOPs formulas.
+# 2·MAC convention, matching ``benchmark/flops.py``'s step-FLOPs formulas.
 # ---------------------------------------------------------------------------
 
 def linear_flops(out_numel: int, in_features: int, has_bias: bool) -> int:

@@ -101,8 +101,7 @@ def default_lock_path() -> str:
 
 def lock_digest(path: Optional[str] = None) -> Optional[str]:
     """sha256 of the lockfile bytes (None when absent) — the digest
-    ``tools.cache verify`` prints so a cache row and the program set it
-    was built under can be correlated from one log line."""
+    ``tools.lint --update-lock`` prints."""
     path = path or default_lock_path()
     try:
         with open(path, "rb") as fh:

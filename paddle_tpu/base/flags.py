@@ -266,23 +266,6 @@ define_flag("anomaly_reject_burst", 16,
             "anomaly flight recorder: admission rejections within one "
             "second that count as a rejection burst; <=0 disables the "
             "burst watcher")
-define_flag("compile_cache", False,
-            "persistent compile cache (paddle_tpu.compile_cache): serialize "
-            "AOT-compiled XLA executables to disk, keyed by the kernel-cache "
-            "signature scheme + an environment fingerprint, so restarted "
-            "trainers and serving replicas warm-start from deserialization "
-            "instead of retrace+recompile; off = zero disk IO, every "
-            "compile site behaves exactly as before")
-define_flag("compile_cache_dir", "",
-            "persistent compile cache: the on-disk store directory; empty "
-            "resolves to ~/.cache/paddle_tpu/compile_cache. One directory "
-            "holds one environment fingerprint's entries (CC702 audits "
-            "mixed-fingerprint dirs)")
-define_flag("compile_cache_max_bytes", 1 << 30,
-            "persistent compile cache: LRU byte budget of the store — after "
-            "a store pushes the directory past this, least-recently-USED "
-            "entries (load refreshes mtime) are pruned; <=0 disables "
-            "pruning (CC701 flags a store over budget)")
 define_flag("comm_quantize_dp_grads", False,
             "comm-efficient collectives (distributed/collective_opt): "
             "sync dp gradients through the blockwise-int8 quantized "

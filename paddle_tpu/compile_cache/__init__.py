@@ -1,261 +1,5 @@
-"""Persistent compile cache: disk-backed AOT executables (ISSUE 9).
+"""The one compile cache is JAX's persistent compilation cache.
 
-Every process used to pay the full retrace+compile bill from scratch —
-a serving replica re-warmed its whole bucket ladder, a restarted trainer
-(the ``distributed/fleet`` elastic path restarts by design) recompiled
-every TrainStep before the first useful step. This package is the
-content-addressed on-disk store that lets the three compile sites
-warm-start from deserialization instead:
-
-- ``core/kernel_cache.py`` — the eager dispatch fast path persists its
-  no-VJP jitted executables (the pullback ``Partial`` treedef closes
-  over a jax-internal local function and cannot serialize; VJP entries
-  stay in-memory, counted ``vjp_skip``);
-- ``jit/functionalize.py`` — ``CompiledFunction``/``TrainStep`` entries
-  AOT-lower on first run and key on the lowered StableHLO (portable
-  across processes where the python-side cache key is not), skipping the
-  XLA compile on a warm start;
-- ``inference._BatchProgram`` — serving replicas restore the WHOLE
-  bucket ladder from static keys (exported-module content hash + rung
-  shapes), paying zero traces and zero compiles on a warm start.
-
-The mechanics ride jax's AOT tier (``Lowered``/``Compiled`` +
-``jax.experimental.serialize_executable`` — the same machinery
-``jit/serialization.py`` uses for symbolic-batch export): ``serialize``
-yields (executable bytes, in-treedef, out-treedef); the pickled triple
-is the store payload. Keys extend the kernel-cache signature scheme
-with an environment fingerprint (jax/jaxlib version, backend+platform,
-device kind/count, relevant FLAGS — ``keys.py``); publishing is atomic
-write-then-rename with sha256 integrity checks, and ANY failure —
-corrupt entry, version mismatch, unpicklable key, read-only dir —
-degrades to a normal compile: a bad cache entry must never take down a
-trainer or a replica (``store.py``).
-
-Operational surface: ``python -m tools.cache`` (ls/verify/prune/stats),
-``FLAGS_compile_cache{,_dir,_max_bytes}``, counters
-``compile_cache.{hit,miss,store,corrupt,...}`` re-homed into
-``observability.snapshot()``, load/store spans on the trace timeline,
-and the ``cache`` lint family (CC70x, ``analysis/cache_check.py``).
+``jax_cache.enable_jax_cache`` decides its directory for every entry
+point: ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
 """
-from __future__ import annotations
-
-import pickle
-import time
-from typing import Any, Optional
-
-from ..base.flags import get_flag
-from ..observability.tracing import tracer as _tracer
-from . import store as _store
-from .keys import derive_digest, fingerprint, fingerprint_digest
-
-__all__ = ["enabled", "cache_dir", "fingerprint", "fingerprint_digest",
-           "derive_digest", "load_executable", "store_executable",
-           "record", "stats", "reset_stats"]
-
-# process-local counters, re-homed into observability.snapshot() under
-# "compile_cache" by a pull-time collector (observability/adapters.py)
-_counters = {"hit": 0, "miss": 0, "store": 0, "corrupt": 0,
-             "store_error": 0, "vjp_skip": 0, "key_skip": 0,
-             "fingerprint_mismatch": 0,
-             "load_seconds": 0.0, "store_seconds": 0.0}
-
-
-def enabled() -> bool:
-    """One flag read: is the persistent tier on? Every compile site gates
-    its disk path on this — off means byte-identical legacy behavior."""
-    try:
-        return bool(get_flag("compile_cache"))
-    except Exception:
-        return False
-
-
-def cache_dir() -> str:
-    """The resolved store directory (flag, or the per-user default)."""
-    import os
-
-    d = str(get_flag("compile_cache_dir") or "")
-    if not d:
-        d = os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                         "compile_cache")
-    return d
-
-
-def record(event: str, n: float = 1) -> None:
-    """Tick one counter (unknown names create themselves: the CC audit
-    and snapshot just project the dict)."""
-    _counters[event] = _counters.get(event, 0) + n
-
-
-# running store-size estimate per directory, maintained at store/prune
-# time so neither the publish path nor the telemetry scrape path has to
-# re-walk the directory per event ({"dir": ..., "bytes": ...})
-_disk_state = {"dir": None, "bytes": 0}
-
-# bounded-retry store I/O (ISSUE 14): a transient read/write fault (NFS
-# hiccup, injected chaos) costs a backoff instead of a cold compile or a
-# lost publish; a give-up STILL degrades to the legacy behavior (miss /
-# in-memory only) — the cache must never take down its caller
-_retry_policies: dict = {}
-
-
-def _io_retry(site: str):
-    policy = _retry_policies.get(site)
-    if policy is None:
-        from ..reliability.policy import RetryPolicy
-
-        policy = _retry_policies[site] = RetryPolicy(
-            site, max_delay_s=0.25, deadline_s=10.0)
-    return policy
-
-
-def stats(disk: bool = True) -> dict:
-    """Counter snapshot + store size (when the tier is on). ``disk=True``
-    walks the directory for the exact byte total; the pull-time
-    observability collector passes False and reports the running
-    estimate instead — a Prometheus scrape must not stat every entry."""
-    out = dict(_counters)
-    out["enabled"] = enabled()
-    if enabled():
-        d = cache_dir()
-        out["dir"] = d
-        if disk:
-            out["disk_bytes"] = _store.total_bytes(d)
-        elif _disk_state["dir"] == d:
-            out["disk_bytes_estimate"] = _disk_state["bytes"]
-    return out
-
-
-def reset_stats() -> None:
-    for k in list(_counters):
-        _counters[k] = 0.0 if k.endswith("_seconds") else 0
-
-
-def load_executable(digest: Optional[str], site: str = "") -> Optional[Any]:
-    """Deserialize-and-load the compiled executable for ``digest``.
-
-    None on miss/corruption/mismatch (counted; corrupt entries are
-    discarded by the store) — the caller compiles normally. A successful
-    load emits a ``compile_cache.load`` span so the timeline shows
-    load-vs-compile wall time side by side.
-    """
-    if digest is None or not enabled():
-        return None
-    t0 = time.perf_counter()
-    try:
-        payload, why = _io_retry("compile_cache.load").run(
-            _store.read_entry, cache_dir(), digest,
-            expected_fp_digest=fingerprint_digest())
-    except Exception as e:
-        # retries exhausted: a broken store is a miss, never a crash
-        record("load_error")
-        record("miss")
-        from ..base.log import get_logger
-
-        get_logger().warning(
-            "compile_cache: load of %s failed after retries (%s) — "
-            "compiling normally", digest[:12], e)
-        return None
-    if payload is None:
-        if why in ("corrupt", "fingerprint_mismatch"):
-            record(why)
-        record("miss")  # a bad entry is also a miss: the site compiles
-        if _tracer.enabled:
-            _tracer.instant("compile_cache." + (why or "miss"),
-                            track="dispatch", site=site)
-        return None
-    try:
-        from jax.experimental import serialize_executable as _se
-
-        compiled = _se.deserialize_and_load(*pickle.loads(payload))
-    except Exception as e:
-        # undeserializable despite a valid checksum (e.g. an executable
-        # from a subtly different toolchain): drop it and compile
-        _store._discard(_store.entry_path(cache_dir(), digest))
-        record("corrupt")
-        record("miss")
-        from ..base.log import get_logger
-
-        get_logger().warning(
-            "compile_cache: entry %s failed to deserialize (%s) — "
-            "discarded, compiling normally", digest[:12], e)
-        return None
-    dur = time.perf_counter() - t0
-    record("hit")
-    record("load_seconds", dur)
-    if _tracer.enabled:
-        _tracer.emit("compile_cache.load", t0, dur, track="dispatch",
-                     site=site, digest=digest[:12])
-    return compiled
-
-
-def store_executable(digest: Optional[str], compiled: Any,
-                     key_meta: Optional[dict] = None) -> bool:
-    """Serialize one AOT ``Compiled`` and publish it under ``digest``.
-
-    False (counted, warned once) on any failure — serialization trouble
-    (unpicklable out-tree), a read-only store, disk pressure. Success
-    prunes the store to its byte budget and emits a
-    ``compile_cache.store`` span.
-    """
-    if digest is None or not enabled():
-        return False
-    t0 = time.perf_counter()
-    try:
-        from jax.experimental import serialize_executable as _se
-
-        payload = pickle.dumps(_se.serialize(compiled), protocol=4)
-    except Exception as e:
-        record("store_error")
-        from ..base.log import get_logger
-
-        get_logger().warning(
-            "compile_cache: executable for %s is not serializable (%s) — "
-            "entry stays in-memory only",
-            (key_meta or {}).get("site", digest[:12]), e)
-        return False
-    d = cache_dir()
-    try:
-        written = _io_retry("compile_cache.store").run(
-            _store.write_entry, d, digest, payload, key_meta=key_meta)
-    except Exception:
-        # retries exhausted: the executable stays in-memory only — same
-        # degradation contract as a read-only store
-        written = False
-    if not written:
-        record("store_error")
-        return False
-    dur = time.perf_counter() - t0
-    record("store")
-    record("store_seconds", dur)
-    if _tracer.enabled:
-        _tracer.emit("compile_cache.store", t0, dur, track="dispatch",
-                     site=(key_meta or {}).get("site", ""),
-                     digest=digest[:12], bytes=len(payload))
-    _maybe_prune(d, digest, len(payload))
-    return True
-
-
-def _maybe_prune(d: str, digest: str, payload_bytes: int) -> None:
-    """LRU-prune only when the running byte estimate crosses the budget:
-    a cold start publishing N entries must cost N stats, not the O(N²)
-    of re-walking the whole (possibly shared, possibly NFS) store after
-    every publish. The estimate seeds itself with one full walk per
-    directory and re-syncs from each prune's report."""
-    import os
-
-    if _disk_state["dir"] != d:
-        _disk_state["dir"] = d
-        _disk_state["bytes"] = _store.total_bytes(d)
-    else:
-        try:
-            _disk_state["bytes"] += os.stat(
-                _store.entry_path(d, digest)).st_size
-        except OSError:
-            _disk_state["bytes"] += payload_bytes
-    try:
-        max_bytes = int(get_flag("compile_cache_max_bytes"))
-    except Exception:
-        return
-    if max_bytes > 0 and _disk_state["bytes"] > max_bytes:
-        report = _store.prune(d, max_bytes=max_bytes)
-        _disk_state["bytes"] = report["kept_bytes"]

@@ -1,5 +1,5 @@
 """Where JAX's own persistent compilation cache lives, for every entry point
-(``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``,
+(``chip_smoke.py``, ``benchmark/run.py``, ``tests/conftest.py``,
 ``__graft_entry__.py``).
 
 The directory is part of what a later process must agree on to hit the

@@ -256,8 +256,8 @@ _bypass = record_bypass
 def stats() -> dict:
     """Cache statistics snapshot: per-op ``hits/misses/bypasses/evictions``
     (+ ``bypass_reasons``) under ``"ops"``, aggregate ``"totals"``, and the
-    current ``"size"``/``"capacity"``. Consumed by ``bench.py``
-    (``extras.dispatch``) and the JX32x kernel-cache audit."""
+    current ``"size"``/``"capacity"``. Consumed by the JX32x kernel-cache
+    audit."""
     ops = {op: {**s, "bypass_reasons": dict(s["bypass_reasons"])}
            for op, s in _stats.items()}
     totals = {k: sum(s[k] for s in _stats.values())
@@ -330,8 +330,7 @@ def poison(key, op: str) -> None:
 # ---------------------------------------------------------------------------
 
 class _Entry:
-    __slots__ = ("key", "op", "fwd", "bwd", "traced_idx", "has_vjp", "staged",
-                 "exec")
+    __slots__ = ("key", "op", "fwd", "bwd", "traced_idx", "has_vjp", "staged")
 
     def __init__(self, key, op, fwd, bwd, traced_idx, has_vjp):
         self.key = key
@@ -345,11 +344,6 @@ class _Entry:
         self.traced_idx = traced_idx
         self.has_vjp = has_vjp
         self.staged = False       # first call traces; later calls replay
-        # AOT Compiled from the persistent disk tier (compile_cache): set
-        # at staging when FLAGS_compile_cache restored or published this
-        # entry's executable; replaces fwd on the replay path (fwd stays —
-        # cost_stats retraces it on demand, a Compiled is not traceable)
-        self.exec = None
 
 
 def _build(key, op, fn, values, attrs, diff_idx, traced_idx) -> _Entry:
@@ -485,8 +479,7 @@ def execute(entry: _Entry, values: Sequence[Any]):
     if not entry.staged:
         return _staging_call(entry, arrs)
     if not entry.has_vjp:
-        fwd = entry.exec
-        return entry.fwd(*arrs) if fwd is None else fwd(*arrs)
+        return entry.fwd(*arrs)
     out, pullback = entry.fwd(*arrs)
     return out, CachedVJP(pullback, entry.bwd)
 
@@ -504,12 +497,10 @@ def _staging_call(entry: _Entry, arrs):
     cell = gen._cell
     before = None if cell is None else cell._value
     clean_before = before is None or not isinstance(before, jax.core.Tracer)
-    publish = None
     try:
         if not entry.has_vjp:
-            result, publish = _persistent_stage(entry, arrs)
+            result = entry.fwd(*arrs)
         else:
-            _note_vjp_skip()
             out, pullback = entry.fwd(*arrs)
             result = (out, CachedVJP(pullback, entry.bwd))
     except Exception:
@@ -521,54 +512,7 @@ def _staging_call(entry: _Entry, arrs):
             f"kernel for op '{entry.op}' drew from the global RNG under the "
             "staging trace — split the key outside the kernel body")
     entry.staged = True
-    if publish is not None:
-        # publish to the persistent tier only now, AFTER the RNG guard
-        # accepted the staging: a refused kernel must never reach disk (a
-        # warm restore replays the executable without tracing, so the
-        # guard could not re-detect the frozen-randomness defect there)
-        publish()
     return result
-
-
-def _persistent_stage(entry: _Entry, arrs):
-    """Stage one no-VJP entry, riding the persistent compile cache when
-    FLAGS_compile_cache is on: restore the AOT executable from disk (zero
-    trace, zero compile) or AOT-compile it. Returns ``(result,
-    publish)`` — ``publish`` (or None) is the deferred disk write the
-    caller runs only after the staging RNG guard accepts the kernel.
-    Disabled, or when the signature cannot be canonicalized, this is
-    exactly the legacy ``entry.fwd(*arrs)`` staging call. Trace/compile
-    failures propagate — the dispatcher poisons the key the same way it
-    always has."""
-    from .. import compile_cache as cc
-
-    if not cc.enabled():
-        return entry.fwd(*arrs), None
-    digest = cc.derive_digest("kernel", entry.key)
-    if digest is None:
-        cc.record("key_skip")
-        return entry.fwd(*arrs), None
-    compiled = cc.load_executable(digest, site="kernel:" + entry.op)
-    publish = None
-    if compiled is None:
-        compiled = entry.fwd.lower(*arrs).compile()
-
-        def publish(digest=digest, compiled=compiled):
-            cc.store_executable(digest, compiled,
-                                key_meta={"site": "kernel", "op": entry.op})
-
-    entry.exec = compiled
-    return compiled(*arrs), publish
-
-
-def _note_vjp_skip() -> None:
-    """Count a differentiable entry staying in-memory only: the pullback
-    ``Partial``'s treedef closes over a jax-internal local function and
-    cannot serialize (see compile_cache docs)."""
-    from .. import compile_cache as cc
-
-    if cc.enabled():
-        cc.record("vjp_skip")
 
 
 def _repair_rng(gen, cell_before, value_before) -> bool:

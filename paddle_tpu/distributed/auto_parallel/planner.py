@@ -162,8 +162,7 @@ def compare_with_measured(step, spec: ModelSpec, batch_size: int,
       (argument + temp), ``None`` when the step has not run compiled.
 
     Ratios are cost_model/xla and closed_form/xla (when xla is present) —
-    the calibration numbers the AutoTuner history and the bench's
-    ``extras.cost_model`` record."""
+    the calibration numbers the AutoTuner history records."""
     dp = degrees.get("dp_degree", 1)
     mp = degrees.get("mp_degree", 1)
     pp = degrees.get("pp_degree", 1)

@@ -208,10 +208,10 @@ def save_state_dict(state_dict: Dict, path: str, process_group=None, coordinator
         # at the previous complete checkpoint
         fault_point("ckpt.write")
         os.replace(shard_tmp, shard_final)
-        # fsync the DIRECTORY too (the compile_cache/store.py discipline
-        # completed): the rename itself must survive power loss, or a
-        # committed metadata.json can reference a shard the directory
-        # forgot (ISSUE 14 satellite)
+        # fsync the DIRECTORY too (write to a temporary name, fsync the
+        # file, rename, fsync the directory): the rename itself must
+        # survive power loss, or a committed metadata.json can reference
+        # a shard the directory forgot (ISSUE 14 satellite)
         fsync_dir(path)
         if chunked:
             # durable-shard ack for this save. No pre-write cleanup here:

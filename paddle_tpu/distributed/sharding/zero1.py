@@ -36,8 +36,8 @@ The quantized gather tier rides the comm engagement policy
 (``FLAGS_comm_quantize_dp_grads`` / ``amp.auto_cast(comm_dtype="int8")``).
 
 Pure accounting (:func:`plan_shards`, :func:`zero1_wire_report`,
-:func:`opt_state_report`) is shared by the planner's step-cost pricing,
-the QZ804/QZ805 lint gates and ``bench.py extras.zero1``.
+:func:`opt_state_report`) is shared by the planner's step-cost pricing
+and the QZ804/QZ805 lint gates.
 """
 from __future__ import annotations
 

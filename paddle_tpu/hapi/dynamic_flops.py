@@ -2,8 +2,8 @@
 python/paddle/hapi/dynamic_flops.py): forward-post hooks record each leaf
 layer's multiply-accumulate count from its real input/output shapes, summed
 over one dry forward. On TPU the number doubles as the MFU denominator —
-bench.py's analytic formulas are the model-specific fast path; this is the
-generic layer-walk.
+``benchmark/flops.py``'s analytic formulas are the model-specific fast
+path; this is the generic layer-walk.
 
 The per-op formulas themselves live in ``analysis/cost_model.py``
 (``linear_flops``/``conv_flops``/...): the static jaxpr walker and this

@@ -12,8 +12,8 @@ python floats, so the flushed values are **bit-identical** to what the
 per-step ``float(...)`` loop would have produced.
 
 Every materialization is timed and counted in
-``profiler.pipeline_stats`` (``host_sync_us`` / ``host_syncs_per_step``) —
-the bench's ``extras.pipeline`` proves the steady state issues zero.
+``profiler.pipeline_stats`` (``host_sync_us`` / ``host_syncs_per_step``):
+the steady state issues zero.
 """
 from __future__ import annotations
 

@@ -207,13 +207,6 @@ class _BatchProgram:
                 pass  # no metadata: outputs keep their pad (still correct rows)
         self.traces = 0          # += 1 per compiled specialization
         self.warmed: List[int] = []
-        # persistent compile cache (paddle_tpu.compile_cache): rungs served
-        # as AOT executables — restored from disk (zero traces) or compiled
-        # once and published. Keyed on the exported module's content hash,
-        # so the key is derivable WITHOUT tracing.
-        self._aot: Dict[int, object] = {}
-        self.restored: List[int] = []   # rungs restored from disk this process
-        self._content_hash = getattr(layer, "_content_hash", None)
         self._lock = named_lock("inference.batch_program")
 
         def _fwd(params, *args):
@@ -275,78 +268,17 @@ class _BatchProgram:
 
         return bucket_grid(self.ladder, self.seq_ladder)
 
-    @staticmethod
-    def _rung_key(bucket):
-        return tuple(int(b) for b in bucket) \
-            if isinstance(bucket, (tuple, list)) else int(bucket)
-
     def warmup(self, dtype_shapes: Sequence) -> None:
         """Compile every ladder rung once (zeros of the recorded specs) so
-        live traffic replays warm executables. Idempotent per rung. With
-        FLAGS_compile_cache on, each rung restores its AOT executable from
-        the persistent store instead — a fully warm-disk replica restores
-        the WHOLE ladder (the full two-axis grid for seq-dynamic exports)
-        with zero traces and zero compiles (``traces == 0`` and
-        ``restored == rungs`` after warmup)."""
+        live traffic replays warm executables. Idempotent per rung."""
         with self._lock:
             for bucket in self.rungs:
                 if bucket in self.warmed:
-                    continue
-                if self._warm_from_cache(bucket, dtype_shapes):
-                    self.warmed.append(bucket)
                     continue
                 zeros = [np.zeros(self._bucket_shape(i, s, bucket), np.dtype(d))
                          for i, (s, d) in enumerate(dtype_shapes)]
                 self(zeros, bucket)
                 self.warmed.append(bucket)
-
-    def _rung_digest(self, bucket, dtype_shapes: Sequence):
-        """Static key for one rung's executable: exported-module content
-        hash + padded input specs + donation spec (+ the environment
-        fingerprint inside derive_digest). None when the model carries no
-        content identity (params-only load) — that rung stays in-memory."""
-        from .. import compile_cache as cc
-
-        if self._content_hash is None or not dtype_shapes:
-            cc.record("key_skip")
-            return None
-        shapes = tuple(
-            (tuple(self._bucket_shape(i, s, bucket)), str(np.dtype(d)))
-            for i, (s, d) in enumerate(dtype_shapes))
-        return cc.derive_digest(
-            "serving", ("serving", self._content_hash,
-                        tuple(sorted(self.dynamic_axes.items())),
-                        tuple(sorted(self.dynamic_ranks.items())),
-                        tuple(self._donate), shapes))
-
-    def _warm_from_cache(self, bucket, dtype_shapes: Sequence) -> bool:
-        """Arm one rung through the persistent tier: disk restore (zero
-        traces) or AOT compile-and-publish (one trace — the same one the
-        legacy ``self(zeros, bucket)`` warmup pays). False defers to the
-        legacy path (tier off, or no derivable key)."""
-        from .. import compile_cache as cc
-
-        if not cc.enabled():
-            return False
-        digest = self._rung_digest(bucket, dtype_shapes)
-        if digest is None:
-            return False
-        compiled = cc.load_executable(
-            digest, site=f"serving:b{self._rung_key(bucket)}")
-        if compiled is not None:
-            self._aot[self._rung_key(bucket)] = compiled
-            self.restored.append(bucket)
-            return True
-        zeros = [np.zeros(self._bucket_shape(i, s, bucket), np.dtype(d))
-                 for i, (s, d) in enumerate(dtype_shapes)]
-        lowered = self._jitted.lower(self._params, *zeros)  # traces += 1
-        compiled = lowered.compile()
-        cc.store_executable(
-            digest, compiled,
-            key_meta={"site": "serving", "bucket": repr(self._rung_key(bucket)),
-                      "model": (self._content_hash or "")[:16]})
-        self._aot[self._rung_key(bucket)] = compiled
-        return True
 
     def _bucket_shape(self, idx, spec_shape, bucket):
         # dynamic axes were recorded as None in the spec; each one
@@ -365,11 +297,6 @@ class _BatchProgram:
     def __call__(self, arrays: Sequence, bucket):
         """Run one assembled batch already padded to ``bucket`` (an int on
         the one-axis ladder, a ``(batch, seq)`` pair on the grid)."""
-        ex = self._aot.get(self._rung_key(bucket))
-        if ex is not None:
-            # AOT-armed rung (persistent tier): a Compiled cannot retrace,
-            # so the compile-event bookkeeping below has nothing to see
-            return ex(self._params, *arrays)
         from ..observability.tracing import tracer
 
         if not tracer.enabled:
@@ -476,14 +403,6 @@ class Predictor:
         serving tier's recompile proof: warmup pays one per ladder rung,
         steady state must add ZERO."""
         return self._ensure_batch_program().traces
-
-    @property
-    def restored_rungs(self) -> List[int]:
-        """Ladder rungs restored from the persistent compile cache this
-        process (zero traces paid). A fully warm-disk start shows
-        ``restored_rungs == batch_ladder`` and ``compile_count == 0`` —
-        the ``traces_on_warm_start == 0`` proof."""
-        return list(self._ensure_batch_program().restored)
 
     def _ensure_batch_program(self) -> _BatchProgram:
         if self._batch_program is None:

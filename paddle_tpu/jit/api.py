@@ -6,7 +6,8 @@ the TPU-native replacement for SOT bytecode capture + PIR programs: jax
 tracing IS the program capture, XLA IS the executor (SURVEY.md §7).
 
 TrainStep is the blessed whole-step compile: forward + backward + optimizer
-in one donated XLA program. hapi.Model and bench.py train through it.
+in one donated XLA program. hapi.Model and the benchmark's train cell
+train through it.
 """
 from __future__ import annotations
 

@@ -388,61 +388,6 @@ class CompiledFunction:
         return outcomes
 
     # ------------------------------------------------------------------ run
-    def _call_entry(self, entry, cell_vals, args, kwargs):
-        """Dispatch one cache entry: the AOT executable once the
-        persistent compile cache armed it, else the jitted wrapper.
-        With FLAGS_compile_cache off this is exactly the legacy
-        ``entry["jitted"](...)`` call. Trace-time exceptions
-        (concretization, branch mismatch) propagate unchanged — the
-        callers' fallback handling is the same on both paths."""
-        ex = entry.get("exec")
-        if ex is not None:
-            return ex(cell_vals, args, kwargs)
-        if not entry.get("compiled_once"):
-            from .. import compile_cache as cc
-
-            if cc.enabled():
-                compiled = self._aot_entry(entry, cell_vals, args, kwargs)
-                entry["exec"] = compiled
-                return compiled(cell_vals, args, kwargs)
-        return entry["jitted"](cell_vals, args, kwargs)
-
-    def _aot_entry(self, entry, cell_vals, args, kwargs):
-        """AOT-lower one entry and restore its executable from the
-        persistent cache — or compile and publish it. The portable key is
-        the lowered StableHLO text (+ the environment fingerprint): the
-        in-process cache key is treedef/callsite identity, which no other
-        process shares, but what XLA is handed is content. The lowering
-        trace is paid either way (the jitted call would trace too); the
-        warm win is skipping the XLA compile."""
-        from .. import compile_cache as cc
-
-        lowered = entry["jitted"].lower(cell_vals, args, kwargs)
-        try:
-            digest = cc.derive_digest("jit", lowered.as_text().encode())
-        except Exception:
-            cc.record("key_skip")
-            digest = None
-        compiled = cc.load_executable(digest, site="jit:" + self.name)
-        if compiled is None:
-            import time
-
-            t0 = time.perf_counter()
-            compiled = lowered.compile()
-            from ..observability.tracing import tracer
-
-            if tracer.enabled:
-                tracer.emit("compile_cache.compile", t0,
-                            time.perf_counter() - t0, track="dispatch",
-                            site="jit:" + self.name)
-            cc.store_executable(digest, compiled,
-                                key_meta={"site": "jit",
-                                          "program": self.name,
-                                          "donated": bool(
-                                              self.donate_cells
-                                              and entry.get("guards") is None)})
-        return compiled
-
     def _run_guarded(self, key, family, args, kwargs):
         """Speculative execution against the last-seen branch signature:
         the compiled program returns its predicate values; a mismatch
@@ -482,8 +427,7 @@ class CompiledFunction:
         observed predicates match the speculated signature."""
         cells = entry["cells"]
         cell_vals = [c._value for c in cells]
-        out_vals, new_vals, preds = self._call_entry(entry, cell_vals,
-                                                     args, kwargs)
+        out_vals, new_vals, preds = entry["jitted"](cell_vals, args, kwargs)
         observed = tuple(bool(np.asarray(p)) for p in preds)
         if observed != entry["guards"]:
             return None, False
@@ -537,8 +481,7 @@ class CompiledFunction:
                 else:
                     seen.add(id(v))
         try:
-            out_vals, new_vals = self._call_entry(entry, cell_vals,
-                                                  args, kwargs)
+            out_vals, new_vals = entry["jitted"](cell_vals, args, kwargs)
         except (
             jax.errors.ConcretizationTypeError,
             jax.errors.TracerArrayConversionError,

@@ -142,9 +142,6 @@ class TranslatedLayer:
         self._exported = exported
         self._params = params
         self._meta = meta
-        # content identity of the serialized module (set by load): the
-        # persistent compile cache keys serving-ladder executables on it
-        self._content_hash = None
         self.training = False
 
     def __call__(self, *args):
@@ -166,15 +163,11 @@ def load(path, **configs):
     with open(path + ".pdmeta", "rb") as f:
         meta = pickle.load(f)
     if meta.get("has_program"):
-        import hashlib
-
         from jax import export as jax_export
 
         with open(path + ".pdmodel", "rb") as f:
             raw = f.read()
         exported = jax_export.deserialize(raw)
         params = {k: v._value for k, v in state.items()}
-        layer = TranslatedLayer(exported, params, meta)
-        layer._content_hash = hashlib.sha256(raw).hexdigest()
-        return layer
+        return TranslatedLayer(exported, params, meta)
     return state

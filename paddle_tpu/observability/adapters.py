@@ -19,9 +19,6 @@ serving                ``profiler.pipeline.serving_stats.summary()``
 jit.compile            process-wide program-build counters: whole-step
                        ``CompiledFunction`` builds (jit/functionalize) and
                        serving ``_BatchProgram`` trace count (inference)
-compile_cache          ``compile_cache.stats()`` (persistent AOT store:
-                       hit/miss/store/corrupt/vjp_skip/key_skip counters,
-                       load/store wall seconds, disk bytes when enabled)
 concurrency            ``observability.locks.witness_stats()`` (named-lock
                        registry size, witness acquires/contended/hold_ms,
                        order-graph edges, CX1004/CX1005 violation counts)
@@ -86,19 +83,10 @@ def _collect_numerics() -> dict:
     return witness_stats()
 
 
-def _collect_compile_cache() -> dict:
-    from ..compile_cache import stats
-
-    # disk=False: a telemetry scrape must not stat every store entry —
-    # the running byte estimate stands in for the exact directory walk
-    return stats(disk=False)
-
-
 def register_default_collectors(reg: MetricsRegistry = registry) -> None:
     reg.register_collector("dispatch.kernel_cache", _collect_kernel_cache)
     reg.register_collector("pipeline", _collect_pipeline)
     reg.register_collector("serving", _collect_serving)
     reg.register_collector("jit.compile", _collect_compile)
-    reg.register_collector("compile_cache", _collect_compile_cache)
     reg.register_collector("concurrency", _collect_concurrency)
     reg.register_collector("numerics", _collect_numerics)

@@ -5,9 +5,8 @@ dispatch behind device compute — but only when the loop around the compiled
 step actually lets it (no per-step ``.numpy()``, batches staged ahead of
 consumption). This module is the observability half of that contract: the
 ``DeviceLoader`` (io/device_prefetch.py), ``MetricBuffer``
-(hapi/metric_buffer.py) and the hapi/bench train loops report their waits
-into one process-global :class:`PipelineStats`, and ``bench.py`` publishes
-the summary under ``extras.pipeline``:
+(hapi/metric_buffer.py) and the hapi train loop report their waits into
+one process-global :class:`PipelineStats`, whose summary holds:
 
 - ``h2d_wait_us``   — time the consumer blocked waiting for the next
   device-resident batch (0 when prefetch keeps up: the H2D overlapped the
@@ -97,8 +96,8 @@ class ServingStats:
     """Request-phase accounting for the serving tier (paddle_tpu/serving):
     every completed request reports its enqueue→admit→dispatch→complete
     timestamps, every scheduler pass samples the queue depth, and every
-    dispatched batch reports its fill. The summary is the bench's
-    ``extras.serving`` payload: p50/p99 end-to-end latency, requests/sec,
+    dispatched batch reports its fill. The summary holds p50/p99
+    end-to-end latency, requests/sec,
     and requests/sec *within the SLO* (FLAGS_serving_slo_ms) — the
     EQuARX-style accounting discipline: a serving tier is measured in
     admitted work per second at a latency bound, not raw throughput.

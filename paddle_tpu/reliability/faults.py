@@ -67,12 +67,6 @@ SITES: Dict[str, str] = {
     "io.h2d": (
         "prefetch worker forwards the error through the bounded queue; "
         "the consumer (Model.fit) re-raises instead of deadlocking"),
-    "compile_cache.load": (
-        "load degrades to a miss — the site compiles normally; corrupt "
-        "entries are unlinked so they cannot poison later starts"),
-    "compile_cache.store": (
-        "store degrades to in-memory only (store_error counted); a "
-        "corrupted payload fails the sha256 check on the next load"),
     "ckpt.write": (
         "atomic tmp+replace commit: a crash leaves the previous "
         "checkpoint/snapshot intact and an ignorable tmp file"),

@@ -18,12 +18,12 @@ stream **bit-identically**:
 - the global RNG key (bit-exact — dropout streams continue, not
   restart).
 
-Commit protocol (the ``compile_cache/store.py`` discipline, applied to
-a directory): everything writes into ``.tmp_<step>_<nonce>/``, every
-file is fsynced, then ONE ``os.rename`` publishes ``snap_<step>/`` and
-the parent directory is fsynced — a crash (or an injected
-``ckpt.write`` fault) at any point leaves the previous snapshot intact
-plus an ignorable tmp dir, never a torn snapshot. ``latest()`` only
+Commit protocol (write to a temporary name, fsync file and directory,
+rename, applied to a directory): everything writes into
+``.tmp_<step>_<nonce>/``, every file is fsynced, then ONE ``os.rename``
+publishes ``snap_<step>/`` and the parent directory is fsynced — a crash
+(or an injected ``ckpt.write`` fault) at any point leaves the previous
+snapshot intact plus an ignorable tmp dir, never a torn snapshot. ``latest()`` only
 ever sees renamed (complete) snapshots. The directory is rolling:
 ``keep`` newest survive, older ones are pruned after each commit.
 """

@@ -40,8 +40,7 @@ from the model.
 Latency accounting (enqueue→admit→dispatch→complete, queue depth,
 p50/p99, requests/sec at FLAGS_serving_slo_ms, the prefill-vs-decode
 step split and decode tokens/sec) flows through
-``profiler.pipeline.serving_stats``; ``bench.py`` publishes it as
-``extras.serving``.
+``profiler.pipeline.serving_stats``.
 
     engine = serving.ServingEngine("ckpt/model", buckets=[1, 2, 4, 8])
     engine.warmup()

@@ -161,8 +161,7 @@ class DecodePrograms:
     Both take and return the pool buffers functionally; KV args are
     donated on accelerators so XLA aliases output onto input — zero
     per-step reallocation. ``traces`` ticks inside the traced bodies
-    (the zero-retrace proof); warmup arms every rung through the
-    persistent compile cache (``restored`` rungs paid zero traces).
+    (the zero-retrace proof).
     """
 
     def __init__(self, model, pool: KVSlotPool, *,
@@ -179,8 +178,6 @@ class DecodePrograms:
         self.decode_rungs = sorted(int(b) for b in decode_rungs)
         self.traces = 0
         self.warmed: List[tuple] = []
-        self.restored: List[tuple] = []
-        self._aot: Dict[tuple, object] = {}
         self._lock = named_lock("serving.decode.programs")
         backend = jax.devices()[0].platform
         # serving-step donation idiom: the pool buffers are dead after the
@@ -190,7 +187,7 @@ class DecodePrograms:
         # (commit() pins shape/dtype, device_bytes stays constant).
         self._donate = (tuple(range(1, 1 + len(pool.arrays())))
                         if backend != "cpu" else ())
-        self._bind_config(cfg, pool)
+        self._bind_config(cfg)
         self._jit_prefill = jax.jit(self._prefill_fn,
                                     donate_argnums=self._donate)
         self._jit_decode = jax.jit(self._decode_fn,
@@ -201,9 +198,8 @@ class DecodePrograms:
     chunked = False
     _extract = staticmethod(_extract_gpt)
 
-    def _bind_config(self, cfg, pool) -> None:
-        """The model's constants the traced bodies bake in, and the
-        structural key of the compile cache."""
+    def _bind_config(self, cfg) -> None:
+        """The model's constants the traced bodies bake in."""
         self._heads = cfg.num_attention_heads
         self._head_dim = cfg.head_dim
         self._hidden = cfg.hidden_size
@@ -211,17 +207,6 @@ class DecodePrograms:
         self._eps = float(cfg.layer_norm_epsilon)
         self._tied = bool(cfg.tie_word_embeddings)
         self._scale = 1.0 / math.sqrt(cfg.head_dim)
-        # executables are parameter-VALUE independent (params are runtime
-        # args), so the cache key needs only the structural identity —
-        # which includes every compile-time CONSTANT baked into the traced
-        # bodies (eps is one; miss it and two models differing only in
-        # layer_norm_epsilon would share executables)
-        self._model_key = (
-            int(cfg.vocab_size), int(cfg.hidden_size),
-            int(cfg.num_hidden_layers), int(cfg.num_attention_heads),
-            int(cfg.max_position_embeddings), self._tied, self._eps,
-            tuple(int(d) for d in pool.k.shape), str(pool.k.dtype),
-            tuple(self._donate))
 
     # ------------------------------------------------------------ programs
     def _logits_head(self, params, x):
@@ -357,9 +342,7 @@ class DecodePrograms:
         return self._jit_decode if key[0] == "decode" else self._jit_prefill
 
     def warmup(self) -> List[tuple]:
-        """Arm every rung: restored from the persistent compile cache
-        (zero traces) or AOT compile-and-publish (one trace — the same
-        one an in-memory warm call pays). Idempotent per rung."""
+        """Arm every rung with one traced call. Idempotent per rung."""
         with self._lock:
             for key in self.rungs:
                 if key in self.warmed:
@@ -368,36 +351,11 @@ class DecodePrograms:
                 self.warmed.append(key)
         return list(self.warmed)
 
-    def _digest(self, key):
-        from .. import compile_cache as cc
-
-        return cc.derive_digest(
-            "serving.decode", ("serving.decode", self._model_key, key))
-
     def _warm(self, key) -> None:
-        from .. import compile_cache as cc
-
         args = self._zero_args(key)
-        if cc.enabled():
-            digest = self._digest(key)
-            compiled = cc.load_executable(
-                digest, site=f"serving.decode:{key[0]}{key[1:]}")
-            if compiled is not None:
-                self._aot[key] = compiled
-                self.restored.append(key)
-                return
-            lowered = self._jitted(key).lower(
-                self._call_params(key), *self.pool.arrays(),
-                *args)  # traces += 1
-            compiled = lowered.compile()
-            cc.store_executable(
-                digest, compiled,
-                key_meta={"site": "serving.decode", "rung": repr(key)})
-            self._aot[key] = compiled
-            return
-        # in-memory warm: one traced call against the pad slot (harmless
-        # writes land in the trash slot); outputs are committed so a
-        # donation backend keeps the pool buffers alive
+        # one traced call against the pad slot (harmless writes land in
+        # the trash slot); outputs are committed so a donation backend
+        # keeps the pool buffers alive
         *arrays, _ = self._jitted(key)(self._call_params(key),
                                        *self.pool.arrays(), *args)
         self.pool.commit(*arrays)
@@ -456,18 +414,10 @@ class DecodePrograms:
 
     # -------------------------------------------------------------- calls
     def prefill(self, ck, cv, tokens, lengths, slot_ids):
-        key = ("prefill", int(tokens.shape[0]), int(tokens.shape[1]))
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, ck, cv, tokens, lengths, slot_ids)
         return self._jit_prefill(self.params, ck, cv, tokens, lengths,
                                  slot_ids)
 
     def decode(self, ck, cv, tokens, slot_ids, positions):
-        key = ("decode", int(tokens.shape[0]))
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, ck, cv, tokens, slot_ids, positions)
         return self._jit_decode(self.params, ck, cv, tokens, slot_ids,
                                 positions)
 
@@ -521,9 +471,9 @@ class PagedDecodePrograms(DecodePrograms):
       the non-speculative stream token for token; the draft only
       decides HOW MANY commit per round.
 
-    Both families bake ``k`` and ``draft_layers`` into ``_model_key``
-    (compile-time constants) and warm with everything else, so flipping
-    speculation on or off mid-flight never traces.
+    Both families bake ``k`` and ``draft_layers`` in (compile-time
+    constants) and warm with everything else, so flipping speculation on
+    or off mid-flight never traces.
     """
 
     def __init__(self, model, pool: KVPagePool, *,
@@ -546,20 +496,13 @@ class PagedDecodePrograms(DecodePrograms):
         # block — a degenerate full-depth draft that accepts 100% and
         # still wins on dispatch count (2 calls commit up to k+1 tokens)
         self.draft_layers = max(1, min(dl, n_layers))
-        # super() derives _model_key from pool.k.shape (already the page
-        # layout) and jits self._prefill_fn/_decode_fn — the overrides
-        # below, bound through normal method resolution
+        # super() jits self._prefill_fn/_decode_fn — the overrides below,
+        # bound through normal method resolution
         super().__init__(model, pool,
                          seq_ladder=seq_ladder,
                          prefill_batch_rungs=prefill_batch_rungs,
                          decode_rungs=decode_rungs)
         self.table_rungs = table_ladder(self.max_seq, pool.page_size)
-        # disambiguate from a slot pool that happens to share shapes,
-        # and cover the table ladder (it shapes the warmed rung set)
-        # plus the speculation constants unrolled into draft/verify
-        self._model_key = self._model_key + (
-            "paged", int(pool.page_size), tuple(self.table_rungs),
-            "spec", self.speculate_k, self.draft_layers)
         self.draft_params = (self._draft_view(self.params)
                              if self.speculate_k else None)
         if self.speculate_k:
@@ -877,41 +820,25 @@ class PagedDecodePrograms(DecodePrograms):
     # -------------------------------------------------------------- calls
     def prefill(self, ck, cv, tokens, lengths, tables,
                 temps, top_ks, top_ps, rkeys):
-        key = ("prefill", int(tokens.shape[0]), int(tokens.shape[1]))
-        args = (tokens, lengths, tables, temps, top_ks, top_ps, rkeys)
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, ck, cv, *args)
-        return self._jit_prefill(self.params, ck, cv, *args)
+        return self._jit_prefill(self.params, ck, cv, tokens, lengths, tables,
+                                 temps, top_ks, top_ps, rkeys)
 
     def decode(self, ck, cv, tokens, tables, positions,
                temps, top_ks, top_ps, rkeys):
-        key = ("decode", int(tokens.shape[0]), int(tables.shape[1]))
-        args = (tokens, tables, positions, temps, top_ks, top_ps, rkeys)
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, ck, cv, *args)
-        return self._jit_decode(self.params, ck, cv, *args)
+        return self._jit_decode(self.params, ck, cv, tokens, tables, positions,
+                                temps, top_ks, top_ps, rkeys)
 
     def draft(self, ck, cv, tokens, tables, positions,
               temps, top_ks, top_ps, rkeys):
         """One draft dispatch: k truncated-layer steps, proposals [B, k]."""
-        key = ("draft", int(tokens.shape[0]), int(tables.shape[1]))
-        args = (tokens, tables, positions, temps, top_ks, top_ps, rkeys)
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.draft_params, ck, cv, *args)
-        return self._jit_draft(self.draft_params, ck, cv, *args)
+        return self._jit_draft(self.draft_params, ck, cv, tokens, tables,
+                               positions, temps, top_ks, top_ps, rkeys)
 
     def verify(self, ck, cv, tokens, tables, positions,
                temps, top_ks, top_ps, rkeys):
         """One verify dispatch: full-model scores at all k+1 positions."""
-        key = ("verify", int(tokens.shape[0]), int(tables.shape[1]))
-        args = (tokens, tables, positions, temps, top_ks, top_ps, rkeys)
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, ck, cv, *args)
-        return self._jit_verify(self.params, ck, cv, *args)
+        return self._jit_verify(self.params, ck, cv, tokens, tables, positions,
+                                temps, top_ks, top_ps, rkeys)
 
 
 def _extract_brumby(model):
@@ -979,7 +906,7 @@ class RetentionPrograms(DecodePrograms):
     chunked = True
     _extract = staticmethod(_extract_brumby)
 
-    def _bind_config(self, cfg, pool) -> None:
+    def _bind_config(self, cfg) -> None:
         self._heads = int(cfg.num_attention_heads)
         self._kv_heads = int(cfg.num_key_value_heads)
         self._head_dim = int(cfg.head_dim)
@@ -987,13 +914,6 @@ class RetentionPrograms(DecodePrograms):
         self._max_pos = int(cfg.max_position_embeddings)
         self._eps = float(cfg.rms_norm_eps)
         self._theta = float(cfg.rope_theta)
-        self._model_key = (
-            "brumby", int(cfg.vocab_size), self._hidden,
-            int(cfg.intermediate_size), int(cfg.num_hidden_layers),
-            self._heads, self._kv_heads, self._head_dim, self._eps,
-            self._theta, str(self.params["embed"].dtype),
-            tuple(int(d) for d in pool.state.shape), tuple(self._donate),
-            self._kernel())
 
     @staticmethod
     def _kernel() -> bool:
@@ -1154,18 +1074,10 @@ class RetentionPrograms(DecodePrograms):
 
     # -------------------------------------------------------------- calls
     def prefill(self, state, tokens, lengths, slot_ids, starts, fresh):
-        key = ("prefill", 1, int(tokens.shape[1]))
-        args = (tokens, lengths, slot_ids, starts, fresh)
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, state, *args)
-        return self._jit_prefill(self.params, state, *args)
+        return self._jit_prefill(self.params, state, tokens, lengths,
+                                 slot_ids, starts, fresh)
 
     def decode(self, state, tokens, slot_ids, positions):
-        key = ("decode", int(tokens.shape[0]))
-        ex = self._aot.get(key)
-        if ex is not None:
-            return ex(self.params, state, tokens, slot_ids, positions)
         return self._jit_decode(self.params, state, tokens, slot_ids,
                                 positions)
 
@@ -1505,7 +1417,6 @@ class DecodeEngine(EngineBase):
             decode_rungs=list(self.programs.decode_rungs),
             prefill_batch_rungs=list(self.programs.prefill_batch_rungs),
             compiled_rungs=len(self.programs.warmed),
-            restored_rungs=len(self.programs.restored),
             compiles_after_warmup=self.compiles_after_warmup,
             kv_pool_bytes=self.kv_pool.device_bytes(),
             kv_pool_bytes_constant=(

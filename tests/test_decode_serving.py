@@ -253,16 +253,6 @@ def test_default_seq_ladder_clamps_to_non_power_of_two_max_seq(model):
     assert all(s <= 24 for s in eng.programs.seq_ladder)
 
 
-def test_model_cache_key_covers_layer_norm_eps():
-    """eps is baked into the traced programs as a compile-time constant:
-    two models differing only there must not share cache digests."""
-    a = _engine(_tiny_model())
-    b = _engine(_tiny_model(layer_norm_epsilon=1e-3))
-    assert a.programs._model_key != b.programs._model_key
-    key = a.programs.rungs[0]
-    assert a.programs._digest(key) != b.programs._digest(key)
-
-
 def test_static_output_axis_matching_seq_rung_survives(tmp_path):
     """Out-slicing is driven by the export's symbolic out_avals, not
     shape coincidence: an output whose STATIC axis equals the seq rung
@@ -294,30 +284,6 @@ def test_static_output_axis_matching_seq_rung_survives(tmp_path):
     x = np.random.RandomState(0).randint(0, 64, size=(1, 9)).astype(np.int64)
     out, = p.run_many([x])          # rung (1, 16): 16 == hidden size
     assert out.shape == (1, 16)     # all 16 real columns intact
-
-
-# ------------------------------------------------------ compile-cache warm
-@pytest.mark.slow
-def test_warm_disk_restores_all_rungs_with_zero_traces(model, tmp_path):
-    from paddle_tpu.base.flags import get_flags, set_flags
-
-    prev = get_flags(["compile_cache", "compile_cache_dir"])
-    set_flags({"compile_cache": True, "compile_cache_dir": str(tmp_path)})
-    try:
-        e1 = _engine(model).warmup()
-        prompt = _prompts(1, seed=4)[0]
-        r1 = e1.generate("a", prompt, max_new_tokens=4)
-        assert e1.programs.traces == len(e1.programs.rungs)
-        e1.shutdown()
-        e2 = _engine(model).warmup()
-        assert e2.programs.traces == 0
-        assert len(e2.programs.restored) == len(e2.programs.rungs)
-        r2 = e2.generate("a", prompt, max_new_tokens=4)
-        np.testing.assert_array_equal(r1, r2)
-        assert e2.compiles_after_warmup == 0
-        e2.shutdown()
-    finally:
-        set_flags(prev)
 
 
 # ---------------------------------------------------------------- TTL gate

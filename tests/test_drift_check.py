@@ -430,23 +430,3 @@ def test_cli_select_and_ignore_govern_the_exit_code(monkeypatch, capsys):
     out = json.loads(capsys.readouterr().out)
     assert rc == 0 and out["findings"] == []
 
-
-# ---------------------------------------------------------------------------
-# tools.cache verify prints the program-lock digest (ISSUE 19 satellite)
-# ---------------------------------------------------------------------------
-
-def test_cache_verify_reports_program_lock_digest(tmp_path, capsys):
-    import tools.cache as cache_cli
-
-    from paddle_tpu.analysis.drift_check import lock_digest
-
-    rc = cache_cli.main(["verify", "--dir", str(tmp_path), "--json"])
-    out = json.loads(capsys.readouterr().out)
-    assert rc == 0
-    assert out["program_lock_digest"] == lock_digest()
-    assert out["entries"] == [] and out["problems"] == []
-
-    rc = cache_cli.main(["verify", "--dir", str(tmp_path)])
-    text = capsys.readouterr().out
-    assert rc == 0
-    assert f"program-lock: {lock_digest()[:16]}" in text

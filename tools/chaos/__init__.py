@@ -19,9 +19,6 @@ decode_faults       injected ``serving.decode_step`` + ``kv.commit``
 prefetch_crash      injected ``io.h2d`` fault in the DeviceLoader
                     staging thread: the error propagates to ``fit``
                     promptly — never a deadlocked queue
-cache_corruption    injected ``compile_cache.store`` corruption: the
-                    next load detects the bad sha256, discards the
-                    entry, degrades to a normal compile, republishes
 ckpt_torn_write     injected ``ckpt.write`` crash between tmp-write and
                     rename: the previous snapshot stays the committed
                     one; the retry lands the new one
@@ -432,43 +429,6 @@ def scenario_prefetch_crash(seed: int) -> dict:
             "wall_s": round(wall, 3)}
 
 
-def scenario_cache_corruption(seed: int) -> dict:
-    """Corrupted store entries are detected, discarded, recompiled."""
-    import numpy as np
-
-    import paddle_tpu as paddle
-    from paddle_tpu import compile_cache, reliability as rel
-    from paddle_tpu.base.flags import set_flags
-    from paddle_tpu.jit.functionalize import functionalize
-
-    tmpdir = tempfile.mkdtemp(prefix="chaos_cache_")
-    set_flags({"compile_cache": True, "compile_cache_dir": tmpdir})
-    compile_cache.reset_stats()
-    try:
-        x = paddle.to_tensor(np.arange(8, dtype=np.float32))
-        rel.arm(rel.FaultInjector(seed=seed).plan(
-            "compile_cache.store", rate=1.0, kind="corrupt"))
-        try:
-            poisoned = functionalize(lambda t: t * 2.0 + 1.0)
-            first = np.asarray(poisoned(x)._value)
-        finally:
-            rel.disarm()
-        stored = compile_cache.stats()["store"]
-        # a fresh program instance re-derives the same digest, hits the
-        # corrupted entry, must detect + discard + compile normally
-        fresh = functionalize(lambda t: t * 2.0 + 1.0)
-        second = np.asarray(fresh(x)._value)
-        stats = compile_cache.stats()
-        ok = (stored > 0 and stats["corrupt"] > 0
-              and np.array_equal(first, second))
-        return {"ok": bool(ok), "stored_corrupted": stored,
-                "corrupt_detected": stats["corrupt"],
-                "bit_identical_output": bool(np.array_equal(first, second))}
-    finally:
-        set_flags({"compile_cache": False, "compile_cache_dir": ""})
-        shutil.rmtree(tmpdir, ignore_errors=True)
-
-
 def scenario_ckpt_torn_write(seed: int) -> dict:
     """A crash between tmp-write and rename never tears a snapshot."""
     import paddle_tpu  # noqa: F401 — flag registry
@@ -616,7 +576,6 @@ _SCENARIOS = (
     ("page_pressure", scenario_page_pressure),
     ("spec_rollback", scenario_spec_rollback),
     ("prefetch_crash", scenario_prefetch_crash),
-    ("cache_corruption", scenario_cache_corruption),
     ("ckpt_torn_write", scenario_ckpt_torn_write),
     ("watchdog_hang", scenario_watchdog_hang),
     ("nonfinite_grad", scenario_nonfinite_grad),
@@ -645,7 +604,6 @@ def run_schedule(seed: int = 0, only=None) -> dict:
     # that don't report per-site detail contribute their known site)
     known = {"train_resume": None, "serving_retry": "serving.execute",
              "prefetch_crash": "io.h2d",
-             "cache_corruption": "compile_cache.store",
              "ckpt_torn_write": "ckpt.write",
              "watchdog_hang": "comm.watchdog",
              "nonfinite_grad": "numerics.nonfinite_grad"}

@@ -8,9 +8,8 @@ Three subcommands over one checkpoint directory
 - **verify**:  integrity + completeness pass (manifest parse, per-piece
                byte count and sha256, bounds/overlap/coverage). Exits
                **non-zero on any corrupt, truncated or missing piece**
-               — the CI hook, mirroring ``tools.cache verify``: a
-               checkpoint that would refuse to load at restore/hot-swap
-               time fails loudly here instead;
+               — the CI hook: a checkpoint that would refuse to load at
+               restore/hot-swap time fails loudly here instead;
 - **convert**: rewrite a checkpoint under a new float dtype
                (``--dtype bfloat16``: fp32 training checkpoint → a
                half-size bf16 serving checkpoint), piece by piece at
@@ -86,7 +85,6 @@ def cmd_verify(ckpt_dir: str, as_json: bool, deep: bool = True) -> int:
     except (FileNotFoundError, ValueError):
         pass
     # orphans are hygiene, not restorability — they warn, never gate
-    # (mirroring the CC703-vs-verify split in tools.cache)
     gating = [p for p in problems if p["kind"] != "orphan"]
     if as_json:
         print(json.dumps({"dir": ckpt_dir, "tensors": n_entries,

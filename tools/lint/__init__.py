@@ -1,6 +1,6 @@
 """``python -m tools.lint`` — the repo's static-analysis driver.
 
-Runs the fifteen ``paddle_tpu.analysis`` analyzers and reports findings:
+Runs the fourteen ``paddle_tpu.analysis`` analyzers and reports findings:
 
 - **trace**:    the trace-safety AST linter over ``paddle_tpu/`` (or the
                 paths given on the command line),
@@ -31,11 +31,6 @@ Runs the fifteen ``paddle_tpu.analysis`` analyzers and reports findings:
                 dead-anomaly-monitor / unbounded-egress audits over a
                 demo telemetry session (with a fed demo monitor) AND the
                 live process tracer + registry + monitor + exporters,
-- **cache**:    the persistent compile cache's hermeticity contract
-                (CC7xx) over a freshly recorded demo store (publish two
-                AOT executables → audit: every entry fingerprinted,
-                store within its byte budget, one fingerprint per dir,
-                no corrupt/orphan files),
 - **comm**:     the comm-efficient collective tier's contract (QZ8xx)
                 over a fresh demo sync session: quantized-allreduce
                 accuracy vs the exact fp32 sum, bitwise determinism /
@@ -108,7 +103,7 @@ import sys
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _ANALYZERS = ("trace", "registry", "program", "jaxpr", "spmd", "cost",
-              "serving", "telemetry", "cache", "comm", "fault", "ckpt",
+              "serving", "telemetry", "comm", "fault", "ckpt",
               "concurrency", "numerics", "drift")
 
 
@@ -270,22 +265,6 @@ def _run_telemetry(_paths, include_tests=False):
     return findings
 
 
-def _run_cache(_paths, include_tests=False):
-    """Record the representative persistent-compile-cache store (two AOT
-    executables published through the public path into a temp dir) and
-    audit its hermeticity contract (CC70x, analysis/cache_check.py)."""
-    import shutil
-    import tempfile
-
-    from paddle_tpu.analysis.cache_check import audit_cache_dir, record_demo_cache
-
-    tmpdir = tempfile.mkdtemp(prefix="paddle_lint_cache_")
-    try:
-        return audit_cache_dir(record_demo_cache(tmpdir))
-    finally:
-        shutil.rmtree(tmpdir, ignore_errors=True)
-
-
 def _run_comm(_paths, include_tests=False):
     """Record the representative quantized-sync session (accuracy +
     determinism gates over the qpsum oracle and, multi-device, the
@@ -369,7 +348,7 @@ _RUNNERS = {"trace": _run_trace, "registry": _run_registry,
             "program": _run_program, "jaxpr": _run_jaxpr,
             "spmd": _run_spmd, "cost": _run_cost,
             "serving": _run_serving, "telemetry": _run_telemetry,
-            "cache": _run_cache, "comm": _run_comm, "fault": _run_fault,
+            "comm": _run_comm, "fault": _run_fault,
             "ckpt": _run_ckpt, "concurrency": _run_concurrency,
             "numerics": _run_numerics, "drift": _run_drift}
 
@@ -377,8 +356,8 @@ _RUNNERS = {"trace": _run_trace, "registry": _run_registry,
 # (<PREFIX>999) stays visible under --select filters for that family
 _FAMILY_PREFIX = {"trace": "TS", "registry": "RC", "program": "PV",
                   "jaxpr": "JX", "spmd": "SP", "cost": "CM",
-                  "serving": "JX", "telemetry": "OB", "cache": "CC",
-                  "comm": "QZ", "fault": "FT", "ckpt": "CK",
+                  "serving": "JX", "telemetry": "OB", "comm": "QZ",
+                  "fault": "FT", "ckpt": "CK",
                   "concurrency": "CX", "numerics": "NM", "drift": "PD"}
 
 
