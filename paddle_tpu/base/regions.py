@@ -16,8 +16,8 @@ around each pullback). A serving program (``serving/decode.py``,
 ``serving/kv_cache.py``) runs under one of ``ROOTS`` and uses ``SERVING``
 inside it; the programs of a model whose layers hold a recurrent state
 (``models/brumby.py``) use ``RETENTION`` there. ``KERNELS`` are the
-``pl.pallas_call(name=...)`` of ``ops/pallas/flash_attention.py`` and
-``ops/pallas/retention.py``.
+``pl.pallas_call(name=...)`` of ``ops/pallas/flash_attention.py``,
+``ops/pallas/retention.py`` and ``ops/pallas/paged_attention.py``.
 """
 from __future__ import annotations
 
@@ -51,6 +51,7 @@ FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 RETN_STEP = "retn_step"          # ops/pallas/retention.py: the decode step's state kernel
+PAGED_ATTN = "paged_attn"        # ops/pallas/paged_attention.py: decode attention over live pages
 
 TRAINING = (EMBED, LN, ATTN_QKV, ATTN_LAYOUT, ATTN_CORE, ATTN_OUT, MLP,
             LM_HEAD, LOSS, OPTIMIZER)
@@ -59,4 +60,4 @@ SERVING = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KV_GATHER, ATTN_CORE,
 RETENTION = (EMBED, NORM, ATTN_QKV, ROPE, RETN_GATE, RETN_CHUNK, RETN_STATE,
              ATTN_OUT, MLP, LM_HEAD, SAMPLE)
 ROOTS = (PREFILL, DECODE, DRAFT, VERIFY)
-KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, RETN_STEP)
+KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, RETN_STEP, PAGED_ATTN)

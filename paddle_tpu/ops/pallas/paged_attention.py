@@ -1,0 +1,155 @@
+"""Decode attention over the paged K/V pool, as one Pallas kernel that reads
+each lane's live pages where they lie.
+
+The pool is ``[layers, pages + 1, page, heads x head_dim]`` (``serving/
+kv_cache.py:KVPagePool``) and is passed whole. The layer index, the block
+tables ``[B, T]`` and the per-query positions ``[B, S]`` are *prefetched
+scalars*: the K and V block index maps name ``(layer, tables[b, t])``, so a
+page's DMA comes straight from the pool, and no dense ``[B, T x page, heads
+x head_dim]`` view of the cache is ever built. A grid step is one lane and
+up to ``PAGES`` consecutive table entries, each an operand of its own. An
+entry past the lane's last live page is *dead*: it names the page its
+operand held in the step before (the pipeline refetches nothing when a block
+index stands still) and its arithmetic is skipped, so the bytes a call moves
+are the live pages', once, and a table wider than a lane's pages costs at
+most a skipped grid step. A padded batch lane (position 0, table all 0)
+reads the pad page and its output is thrown away by the caller.
+
+The mathematics is ``serving/decode.py:_attend_merged``'s: K, V and q as
+stored, logits times ``scale``, column ``j`` visible to query ``s`` where
+``j <= positions[b, s]``, softmax in float32 (across pages its
+running-maximum form, accumulated in float32), probabilities cast to the
+activations' dtype before the product with V. ``q`` arrives spread
+block-diagonally over the merged minor dimension (row ``s x heads + h`` holds
+head ``h``'s ``head_dim`` columns of query ``s`` and zeros elsewhere), so both
+products contract the whole ``heads x head_dim`` on the matrix unit and the
+minor dimension is never split.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...base import regions
+from .flash_attention import _NN, _NT, NEG_INF, _dot
+
+ROWS = 16    # a bf16 tile's sublanes: the spread q's rows are padded to whole tiles
+PAGES = 4    # table entries a grid step: 8 double-buffered (256, 768) bf16 blocks are 6.3 MB of VMEM
+
+
+def _kernel(layer_ref, pages_ref, last_ref, pos_ref, q_ref, *refs, scale,
+            heads, queries, page, per_step):
+    del layer_ref, pages_ref   # read by the index maps
+    kv_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:2 * per_step], refs[2 * per_step:]
+    b, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    for j in range(per_step):
+        entry = t * per_step + j
+
+        @pl.when(entry <= last_ref[b])
+        def _(entry=entry, k_ref=kv_refs[j], v_ref=kv_refs[per_step + j]):
+            v = v_ref[...]
+            logits = _dot(q_ref[...], k_ref[...], _NT) * scale   # [rows, page] f32
+            row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+            col = entry * page + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            # row s x heads + h asks for query s; padding rows keep the last one's
+            at = jnp.full(logits.shape, pos_ref[b * queries], jnp.int32)
+            for s in range(1, queries):
+                at = jnp.where(row >= s * heads, pos_ref[b * queries + s], at)
+            logits = jnp.where(col <= at, logits, NEG_INF)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            l_scr[...] = alpha * l_scr[...] + p.sum(axis=1, keepdims=True)
+            acc_scr[...] = alpha * acc_scr[...] + _dot(p.astype(v.dtype), v, _NN)
+            m_scr[...] = m_new
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def _held_pages(tables, last, per_step):
+    """The page each table entry's operand holds in its grid step: the
+    entry's own where it is live, else what that operand held in the step
+    before (the pad page if none did), so a dead entry moves no bytes.
+    Grid steps run lane-major; entry ``(b, t)`` is operand ``t % per_step``
+    of step ``(b, t // per_step)``."""
+    B, T = tables.shape
+    steps = T // per_step
+    live = jnp.arange(T)[None, :] <= last[:, None]
+    # [B, steps, per_step] -> one row a grid step, in the grid's order
+    own = jnp.where(live, tables, -1).reshape(B * steps, per_step)
+    at = jnp.where(own >= 0, jnp.arange(B * steps)[:, None], -1)
+    at = jax.lax.cummax(at, axis=0)
+    held = jnp.take_along_axis(own, jnp.maximum(at, 0), axis=0)
+    return jnp.where(at >= 0, held, 0).reshape(B * T)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "scale", "interpret"))
+def paged_attention(q, k_pool, v_pool, layer, tables, positions, *, heads,
+                    scale, interpret=False):
+    """``q`` ``[B, S, heads x dim]``, the pools ``[L, N, page, heads x dim]``
+    whole, ``layer`` a scalar, ``tables`` ``[B, T]`` int32 (page ids, 0 past
+    a lane's pages), ``positions`` ``[B, S]`` int32. Returns ``[B, S, heads x
+    dim]`` in ``q``'s dtype: query ``s`` of lane ``b`` attends over columns
+    ``<= positions[b, s]`` of the lane's pages. Jitted, so the layers of a
+    program share one trace of it (``layer`` is an argument, not a constant)."""
+    B, S, HD = q.shape
+    T = tables.shape[1]
+    page = k_pool.shape[2]
+    per_step = min(T, PAGES)   # the table ladder's rungs are powers of two
+    rows = -(-S * heads // ROWS) * ROWS
+    own = (jnp.arange(HD) // (HD // heads))[None, :] == jnp.arange(heads)[:, None]
+    qbd = jnp.where(own, q[:, :, None, :], 0).reshape(B, S * heads, HD)
+    qbd = jnp.pad(qbd, ((0, 0), (0, rows - S * heads), (0, 0)))
+    positions = positions.astype(jnp.int32)
+    # the last table entry that holds a column some query of the lane may see
+    last = jnp.minimum(positions.max(axis=1) // page, T - 1)
+
+    def lane(b, t, layer, pages, last, pos):
+        return (b, 0, 0)
+
+    def entry(j):
+        return lambda b, t, layer, pages, last, pos: (
+            layer[0], pages[b * T + t * per_step + j], 0, 0)
+
+    q_spec = pl.BlockSpec((None, rows, HD), lane)
+    kv_specs = [pl.BlockSpec((None, None, page, HD), entry(j))
+                for j in range(per_step)]
+    full = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, heads=heads, queries=S,
+                          page=page, per_step=per_step),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, T // per_step),
+            in_specs=[q_spec] + kv_specs + kv_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),    # m
+                pltpu.VMEM((rows, 1), jnp.float32),    # l
+                pltpu.VMEM((rows, HD), jnp.float32),   # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=regions.PAGED_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      _held_pages(tables.astype(jnp.int32), last, per_step), last,
+      positions.reshape(B * S), qbd,
+      *[k_pool] * per_step, *[v_pool] * per_step)
+    # head h keeps its own dim columns of row s x heads + h
+    full = full[:, :S * heads].reshape(B, S, heads, HD)
+    return jnp.where(own, full, 0).sum(axis=2)

@@ -149,6 +149,9 @@ class ServingStats:
                 "spec_accepted": 0, "spec_committed": 0,
                 # program calls that sorted the vocabulary (a lane sampled)
                 "sample_sort_steps": 0,
+                # block-table entries of the paged decode steps, and those
+                # of them that named a page with a visible column
+                "pages_live": 0, "pages_table": 0,
                 "tokens": 0, "t_first": None, "t_last": None,
                 "occ_sum": 0, "occ_samples": 0, "occ_peak": 0,
                 "slots": 0,
@@ -241,6 +244,16 @@ class ServingStats:
         the share of calls that chose by argmax alone."""
         with self._lock:
             self._decode["sample_sort_steps"] += int(calls)
+
+    def record_pages(self, pages_live: int, pages_table: int):
+        """One paged decode step (or speculation round) ran on a block
+        table of ``pages_table`` entries (batch rung x table rung) of
+        which ``pages_live`` named a page that holds a column its lane
+        may see. Their ratio is the share of a dense gathered view's
+        bytes that the step's attention has to read."""
+        with self._lock:
+            self._decode["pages_live"] += int(pages_live)
+            self._decode["pages_table"] += int(pages_table)
 
     def record_spec_round(self, proposed: int, accepted: int,
                           committed: int):
@@ -358,6 +371,11 @@ class ServingStats:
             "prefill_steps": cell["prefill_steps"],
             "decode_steps": cell["decode_steps"],
             "sample_sort_steps": cell["sample_sort_steps"],
+            "pages_live": cell["pages_live"],
+            "pages_table": cell["pages_table"],
+            "pages_live_share": (round(cell["pages_live"]
+                                       / cell["pages_table"], 4)
+                                 if cell["pages_table"] else None),
             "prefill_p50_ms": pct(prefill, 0.50),
             "prefill_p99_ms": pct(prefill, 0.99),
             "decode_p50_ms": pct(decode, 0.50),

@@ -29,7 +29,9 @@ Every program body runs under the regions of ``base/regions.py`` — a root
 per program (``prefill``/``decode``/``draft``/``verify``) and, inside, the
 serving vocabulary (``embed`` ... ``attn/kv_gather`` ... ``sample``). The
 names are HLO metadata only (the program computes the same); a device
-trace carries them as each operation's ``tf_op``.
+trace carries them as each operation's ``tf_op``. On one TPU the paged
+programs hold nothing under ``attn/kv_gather``: their attention is the
+kernel ``paged_attn`` under ``attn/core``.
 """
 from __future__ import annotations
 
@@ -197,6 +199,15 @@ class DecodePrograms:
     #: then keeps a cursor per pending request (one chunk a beat)
     chunked = False
     _extract = staticmethod(_extract_gpt)
+
+    @staticmethod
+    def _kernel() -> bool:
+        """Whether a family's fused step (the paged attention over the
+        pool, the retention state update) is its Pallas kernel: the
+        repo's one gate, asked while the program is traced."""
+        from ..ops import pallas
+
+        return bool(pallas.enabled())
 
     def _bind_config(self, cfg) -> None:
         """The model's constants the traced bodies bake in."""
@@ -440,11 +451,16 @@ class PagedDecodePrograms(DecodePrograms):
       relayout on entry and exit) and ``decode``/``draft``/``verify``
       never split the merged dimension on anything a page's size or
       larger: fresh k/v rows are merged before the write, and the
-      gathered view is contracted whole by :func:`_attend_merged`.
+      attention contracts the merged dimension whole
+      (:meth:`_attend_pages`): on one TPU the kernel of
+      ``ops/pallas/paged_attention.py`` over the pool in place, elsewhere
+      :func:`_attend_merged` over the gathered view.
     - decode rungs key on (batch rung × table rung): ``("decode", b,
-      t)`` where ``t`` walks :func:`~..jit.bucketing.table_ladder` —
-      a short context pays a short gather, a 4k one a long gather, and
-      both replay warm.
+      t)`` where ``t`` walks :func:`~..jit.bucketing.table_ladder`.
+      With the kernel a lane pays for its live pages whatever the rung
+      (a table entry past them costs a skipped grid step, no bytes);
+      with the composition a short context pays a short gather, a 4k
+      one a long gather. Both replay warm.
     - sampling rides as traced per-lane arguments (temperature / top-k
       / top-p / raw uint32 PRNG key pair): sampling is data too, never
       a retrace. ``temp == 0`` lanes take the argmax bit-exactly — the
@@ -605,6 +621,30 @@ class PagedDecodePrograms(DecodePrograms):
             cv = kvc.write_prompt_pages(cv, tables, vrows)
             return ck, cv, next_tok
 
+    def _attend_pages(self, q, ck, cv, li, tables, positions):
+        """Layer ``li``'s attention of ``q`` ``[B, S, heads*dim]`` at
+        ``positions`` ``[B, S]`` over the lanes' pages, the step's own
+        rows already written: query ``s`` sees columns ``<=
+        positions[b, s]``. The traced table maps token position -> page,
+        so column j IS position j and the slot program's mask and softmax
+        carry over. Where the gate says so (a TPU, one device) this is the
+        kernel of ``ops/pallas/paged_attention.py``, which reads each
+        lane's live pages from the pool as it lies; elsewhere
+        :func:`~.kv_cache.gather_pages` builds the dense view and
+        :func:`_attend_merged` reads it, the kernel's oracle."""
+        if self._kernel():
+            from ..ops.pallas import paged_attention as kernel
+
+            with region(regions.ATTN_CORE):
+                return kernel.paged_attention(
+                    q, ck, cv, li, tables, positions, heads=self._heads,
+                    scale=self._scale)
+        keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h*d]
+        vals = kvc.gather_pages(cv, li, tables)
+        with region(regions.ATTN_CORE):
+            return _attend_merged(q, keys, vals, positions, self._heads,
+                                  self._scale)
+
     def _paged_step_trunk(self, params, ck, cv, tokens, tables, positions,
                           *, bounded=False):
         """One paged decode step's transformer body: ``[B]`` tokens at
@@ -652,15 +692,8 @@ class PagedDecodePrograms(DecodePrograms):
                            for i in range(3))
             ck = kvc.append_token_paged(ck, li, pages, offsets, k)
             cv = kvc.append_token_paged(cv, li, pages, offsets, v)
-            # the traced table maps token position -> page: column j of the
-            # gathered view IS position j, so the slot program's mask and
-            # softmax carry over unchanged (bit-exact greedy contract)
-            keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h*d]
-            vals = kvc.gather_pages(cv, li, tables)
-            with region(regions.ATTN_CORE):
-                att = _attend_merged(q[:, None], keys, vals,
-                                     positions[:, None], self._heads,
-                                     self._scale)[:, 0]
+            att = self._attend_pages(q[:, None], ck, cv, li, tables,
+                                     positions[:, None])[:, 0]
             with region(regions.ATTN_OUT):
                 x = x + att @ blk["out_w"] + blk["out_b"]
             x = _mlp(x, blk, eps)
@@ -748,12 +781,8 @@ class PagedDecodePrograms(DecodePrograms):
                                for i in range(3))
                 ck = kvc.append_token_paged(ck, li, pages, offsets, k)
                 cv = kvc.append_token_paged(cv, li, pages, offsets, v)
-                keys = kvc.gather_pages(ck, li, tables)  # [B, T*ps, h*d]
-                vals = kvc.gather_pages(cv, li, tables)
-                with region(regions.ATTN_CORE):
-                    # query j sees cols <= p+j
-                    att = _attend_merged(q, keys, vals, pos, self._heads,
-                                         self._scale)
+                # query j sees cols <= p+j
+                att = self._attend_pages(q, ck, cv, li, tables, pos)
                 with region(regions.ATTN_OUT):
                     x = x + att @ blk["out_w"] + blk["out_b"]
                 x = _mlp(x, blk, eps)
@@ -914,13 +943,6 @@ class RetentionPrograms(DecodePrograms):
         self._max_pos = int(cfg.max_position_embeddings)
         self._eps = float(cfg.rms_norm_eps)
         self._theta = float(cfg.rope_theta)
-
-    @staticmethod
-    def _kernel() -> bool:
-        """Whether the decode step's state update is the Pallas kernel."""
-        from ..ops import pallas
-
-        return bool(pallas.enabled())
 
     # ------------------------------------------------------------ the block
     def _project(self, w, x, positions):

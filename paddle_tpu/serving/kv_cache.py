@@ -115,6 +115,13 @@ def gather_pages(cache, layer, tables):
     ``[B, T*ps, heads*dim]`` — the traced-block-table read the decode
     attention indexes through. One compiled program serves ANY page map
     because the table is data.
+    WHERE THIS RUNS: on a CPU and under a mesh of more than one device,
+    and as the oracle of the kernel's tests. The view is as large as the
+    table, live pages or not (at 64 lanes x 4 pages as large as the whole
+    pool, written once and read twice a layer a step), so on one TPU the
+    decode programs take ``ops/pallas/paged_attention.py`` instead, which
+    reads each lane's live pages from the pool in place and gathers
+    nothing (``decode.PagedDecodePrograms._attend_pages``).
     The view keeps the pool's merged minor dimension: the reader
     contracts against it whole (``decode._attend_merged``) — splitting
     it back into (heads, dim) here would have the TPU compiler pad and
@@ -342,7 +349,10 @@ class KVPagePool:
     are the same array, nothing is copied and nothing padded. Same
     bytes, same page ids; only the rows' shape differs, and the decode
     programs contract against the merged dimension without splitting
-    it (``decode._attend_merged``).
+    it: on one TPU a Pallas kernel whose block index maps name ``(layer,
+    table[b, t])``, so a step reads the live pages from this array as it
+    lies (``ops/pallas/paged_attention.py``); elsewhere
+    :func:`gather_pages` and ``decode._attend_merged``.
 
     The vLLM discipline applied to the slot pool above: instead of one
     full ``max_seq`` row per sequence, a request holds only the fixed-

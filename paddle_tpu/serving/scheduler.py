@@ -860,7 +860,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         """The base class's span plus ``sampling``, the lanes of the
         step with ``temperature > 0``. If there is one, each program
         call of the step (a speculation round makes two) sorts the
-        vocabulary in every lane, and the stats count those calls."""
+        vocabulary in every lane, and the stats count those calls. A
+        step of kind ``decode`` or ``speculate`` also passes
+        ``pages_live`` and ``pages_table`` (:meth:`_step_inputs`)."""
         sampling = sum(1 for r in lanes if r.temperature > 0)
         if sampling and self.stats is not None:
             self.stats.record_sample_sort(2 if kind == "speculate" else 1)
@@ -900,12 +902,20 @@ class PagedDecodeScheduler(DecodeScheduler):
 
     def _step_inputs(self, lookahead: int = 0):
         """The lanes ready to step and their program inputs — (lanes,
-        rung, tokens, tables, positions), or None when every active lane
-        sits this beat out. Under ``serving.build``. The sampling
-        arguments are not built here: a plain step assembles them inside
-        its ``serving.decode`` span and a speculation round just before
-        it, where each always did, so the span that ``decode_step_ms``
-        reads keeps measuring what it measured."""
+        rung, tokens, tables, positions, pages), or None when every
+        active lane sits this beat out. Under ``serving.build``. The
+        sampling arguments are not built here: a plain step assembles
+        them inside its ``serving.decode`` span and a speculation round
+        just before it, where each always did, so the span that
+        ``decode_step_ms`` reads keeps measuring what it measured.
+
+        ``pages`` is what the step's span says of its block table:
+        ``pages_table`` entries (batch rung x table rung), ``pages_live``
+        of them naming a page that holds a column its lane may see
+        (through ``lookahead`` positions past the write position). The
+        dense gathered view costs the table's bytes; the paged-attention
+        kernel reads the live pages (and the pad page once a padded
+        lane). The stats keep both sums."""
         from ..jit.bucketing import bucket_for
 
         with self._span("serving.build", lanes=0, rung=None) as sp:
@@ -924,9 +934,15 @@ class PagedDecodeScheduler(DecodeScheduler):
                 tokens[i] = r.generated[-1]
                 tables[i, :len(r.pages)] = r.pages
                 positions[i] = r.position
+            last = np.minimum(positions[:len(lanes)] + lookahead,
+                              self.max_seq - 1)
+            pages = {"pages_live": int((last // self.pool.page_size + 1).sum()),
+                     "pages_table": b_rung * t_rung}
+            if self.stats is not None:
+                self.stats.record_pages(**pages)
             if sp.id is not None:
                 sp.args.update(lanes=len(lanes), rung=(b_rung, t_rung))
-        return lanes, (b_rung, t_rung), tokens, tables, positions
+        return lanes, (b_rung, t_rung), tokens, tables, positions, pages
 
     def _decode_step(self) -> None:
         if (self.speculate_k > 0 and self.spec_enabled
@@ -936,9 +952,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         built = self._step_inputs()
         if built is None:
             return
-        lanes, rung, tokens, tables, positions = built
+        lanes, rung, tokens, tables, positions, pages = built
         t0 = time.perf_counter()
-        with self._step_span("decode", rung, lanes):
+        with self._step_span("decode", rung, lanes, **pages):
             toks = self._call_and_read("decode", lambda: self.programs.decode(
                 self.pool.k, self.pool.v, tokens, tables, positions,
                 *self._sample_args(lanes, rung[0])))
@@ -958,9 +974,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         built = self._step_inputs(lookahead=k)
         if built is None:
             return
-        lanes, rung, tokens, tables, positions = built
+        lanes, rung, tokens, tables, positions, pages = built
         sample = self._sample_args(lanes, rung[0])
-        with self._step_span("speculate", rung, lanes, k=k):
+        with self._step_span("speculate", rung, lanes, k=k, **pages):
             t0 = time.perf_counter()
             # [b_rung, k] proposals
             drafts = self._call_and_read("draft", lambda: self.programs.draft(

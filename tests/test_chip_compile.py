@@ -88,3 +88,119 @@ def test_the_step_fits_one_chip_with_room_to_spare(compiled_attention):
     # q, k, v in; three gradients out; the saved output and row sums between
     assert m.argument_size_in_bytes == 3 * 4 * 1024 * 16 * 64 * 2
     assert m.temp_size_in_bytes < 1 << 30
+
+
+# ----------------------------------------------------- paged decode attention
+@pytest.fixture(scope="module", params=[1, 5], ids=["S1", "Sk1"])
+def compiled_paged_step(topo, request):
+    """One layer's write-then-attend of the saturated serve cell's top rung
+    (64 lanes, a table of 4 pages of 256, 12 heads of 64, the bf16 pool of
+    257 pages a layer whole and donated), compiled for one chip: the rows
+    scattered into the pool, then ``paged_attn`` over it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.base import regions
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    from paddle_tpu.serving import kv_cache as kvc
+
+    S = request.param
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = shape((12, 257, 256, 768), jnp.bfloat16)
+    rows = shape((64, S, 768), jnp.bfloat16)
+    ints = shape((64, S), jnp.int32)
+
+    def step(ck, cv, q, k, v, tables, positions, pages, offsets):
+        ck = kvc.append_token_paged(ck, 3, pages, offsets, k)
+        cv = kvc.append_token_paged(cv, 3, pages, offsets, v)
+        with regions.region(regions.ATTN_CORE):
+            att = paged_attention(q, ck, cv, 3, tables, positions, heads=12,
+                                  scale=0.125)
+        return ck, cv, att
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
+            pool, pool, rows, rows, rows, shape((64, 4), jnp.int32), ints,
+            ints, ints).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_paged_attention_compiles_for_v5e_under_its_name(compiled_paged_step):
+    calls = _kernel_calls(compiled_paged_step)
+    assert [n.split(".")[0] for n in calls] == ["paged_attn"]
+    (op_name,) = calls.values()
+    assert "attn/core" in op_name and op_name.endswith("/paged_attn/pallas_call")
+
+
+def test_the_pool_is_updated_in_place_beside_the_kernel_that_reads_it(
+        compiled_paged_step):
+    """The write and the kernel's read share the donated pool: both arrays
+    aliased input to output, and temporaries far under one layer of it (a
+    copy the compiler made to keep the update in place would be 1.21 GB)."""
+    m = compiled_paged_step.memory_analysis()
+    pool = 12 * 257 * 256 * 768 * 2
+    assert m.alias_size_in_bytes == 2 * pool
+    assert m.temp_size_in_bytes < 16 << 20
+
+
+def test_a_kernels_cache_key_does_not_depend_on_who_warmed_the_engine(
+        topo, monkeypatch):
+    """The Mosaic payloads (part of JAX's cache key, unlike HLO metadata)
+    of a paged decode program lowered from two call stacks are the same
+    bytes, as ``enable_jax_cache`` limits the locations' frames: the two
+    GPT serve cells warm one engine from two drivers and have to share
+    its executables."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+    from paddle_tpu.serving import decode
+
+    assert jax.config.jax_traceback_in_locations_limit == 3
+    monkeypatch.setattr(decode.PagedDecodePrograms, "_kernel",
+                        staticmethod(lambda: True))
+    paddle.seed(0)
+    model = GPTForCausalLM(gpt_tiny(
+        vocab_size=128, num_hidden_layers=2, hidden_size=256,
+        num_attention_heads=2, max_position_embeddings=512))
+    model.eval()
+    engine = serving.DecodeEngine(model, max_slots=8, max_seq=512,
+                                  seq_buckets=[128], page_size=128,
+                                  kv_dtype="bfloat16", speculate_k=2)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    P = engine.programs
+
+    def payloads(kind):
+        key = (kind, 8, 4)
+        fn = {"decode": P._decode_fn, "verify": P._verify_fn}[kind]
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (P._call_params(key), P.pool.k, P.pool.v, *P._zero_args(key)))
+        text = jax.jit(fn).lower(*args).as_text()
+        return re.findall(r'backend_config\s*=\s*"((?:[^"\\]|\\.)*)"', text)
+
+    def another_driver(kind):
+        return [payloads(k) for k in [kind]][0]
+
+    try:
+        for kind in ("decode", "verify"):
+            first = payloads(kind)
+            assert len(first) == 1     # the layers share one lowering of it
+            jax.clear_caches()
+            assert another_driver(kind) == first
+    finally:
+        engine.shutdown(drain=False)

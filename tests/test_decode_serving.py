@@ -475,7 +475,49 @@ class TestTwoAxisLadder:
         assert out.shape == (2, 11, 512)   # seq pad sliced back off
         assert p.compile_count == 4        # replayed the (2, 16) rung
 
+    @staticmethod
+    def _gpt_float64(w, ids, heads=2, eps=1e-5):
+        """The served GPT's logits for ``ids`` ``[S]``, on float64 copies
+        ``w`` of its exported weights, in plain numpy."""
+        def ln(x, name):
+            mu, var = x.mean(-1, keepdims=True), x.var(-1, keepdims=True)
+            return ((x - mu) / np.sqrt(var + eps) * w[f"{name}.weight"]
+                    + w[f"{name}.bias"])
+
+        def linear(x, name):
+            return x @ w[f"{name}.weight"] + w[f"{name}.bias"]
+
+        wte = w["gpt.embeddings.word_embeddings.weight"]
+        S = len(ids)
+        x = wte[ids] + w["gpt.embeddings.position_embeddings.weight"][:S]
+        causal = np.tril(np.ones((S, S), bool))
+        li = 0
+        while f"gpt.h.{li}.ln_1.weight" in w:
+            blk = f"gpt.h.{li}"
+            qkv = linear(ln(x, f"{blk}.ln_1"), f"{blk}.attn.qkv_proj")
+            q, k, v = np.moveaxis(qkv.reshape(S, heads, 3, -1), 2, 0)
+            logits = np.einsum("shd,thd->hst", q, k) / np.sqrt(q.shape[-1])
+            logits = np.where(causal[None], logits, -np.inf)
+            probs = np.exp(logits - logits.max(-1, keepdims=True))
+            probs /= probs.sum(-1, keepdims=True)
+            att = np.einsum("hst,thd->shd", probs, v).reshape(S, -1)
+            x = x + linear(att, f"{blk}.attn.out_proj")
+            h = linear(ln(x, f"{blk}.ln_2"), f"{blk}.mlp.fc1")
+            h = 0.5 * h * (1 + np.tanh(np.sqrt(2 / np.pi)
+                                       * (h + 0.044715 * h ** 3)))
+            x = x + linear(h, f"{blk}.mlp.fc2")
+            li += 1
+        return ln(x, "gpt.ln_f") @ wte.T
+
     def test_engine_serves_mixed_seq_lengths_bit_exact(self, served_gpt):
+        """Every mixed-length batched result and the single-request
+        ``Predictor.run`` on the same row agree with ONE float64
+        evaluation of the served model within 2e-6 (the float32 programs
+        of two batch shapes differ from each other by 9e-8 here, in
+        whatever order XLA reduces; a half-precision result is off by
+        1e-4 and more). Bit equality between programs of two batch
+        shapes is not a property XLA gives; PR 32 withdrew that claim
+        for this test's sibling in ``tests/test_serving.py``."""
         from paddle_tpu.inference import Config, Predictor
 
         eng = serving.ServingEngine(served_gpt, buckets=[1, 2, 4],
@@ -485,13 +527,21 @@ class TestTwoAxisLadder:
             rs = np.random.RandomState(1)
             xs = [rs.randint(0, 512, size=(1, n)).astype(np.int64)
                   for n in (5, 11, 8, 16, 3)]
+            weights = {k: np.asarray(v.numpy(), np.float64) for k, v in
+                       paddle.jit.load(served_gpt).state_dict().items()}
             reqs = [eng.submit("a", x) for x in xs]
             outs = [r.result(60) for r in reqs]
             single = Predictor(Config(served_gpt))
             for x, (out,) in zip(xs, outs):
                 assert out.shape == (1, x.shape[1], 512)
-                ref = single.run([x])[0]
-                np.testing.assert_array_equal(out, ref)
+                want = single.run([x])[0]
+                assert out.dtype == want.dtype == np.float32
+                ref = self._gpt_float64(weights, x[0])[None]
+                np.testing.assert_allclose(out, ref, rtol=0, atol=2e-6)
+                np.testing.assert_allclose(want, ref, rtol=0, atol=2e-6)
+                # the tolerance resolves a half-precision result
+                low = ref.astype(np.float16).astype(np.float64)
+                assert np.abs(low - ref).max() > 2e-6
             assert eng.compiles_after_warmup == 0
         finally:
             eng.shutdown(drain=True)

@@ -620,7 +620,7 @@ class TestProgramRegions:
             "attn/layout", "optimizer"}
         assert regions.ROOTS == ("prefill", "decode", "draft", "verify")
         assert regions.KERNELS == ("flash_fwd", "flash_bwd_dq",
-                                   "flash_bwd_dkv", "retn_step")
+                                   "flash_bwd_dkv", "retn_step", "paged_attn")
         assert {"retn/gate", "retn/chunk", "retn/state", "norm",
                 "rope"} <= set(regions.RETENTION)
         with regions.region(regions.MLP):

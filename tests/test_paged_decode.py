@@ -5,6 +5,8 @@ same four-bucket shape down to fit the tier-1 budget), mid-flight page
 growth, page reclaim, greedy bit-exactness vs the slot-pool oracle,
 sampled decoding determinism, pool-pressure wait/shed semantics, the
 JX334 fragmentation watermark and the page-pressure chaos scenario."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -289,6 +291,177 @@ class TestMergedHeadsLayout:
             assert np.array_equal(spec, want)
 
 
+# ------------------------------------------- the paged-attention kernel
+def _force_kernel(monkeypatch):
+    """Make the paged programs take ``ops/pallas/paged_attention.py`` in
+    interpret mode, as the gate would make them take it compiled on a
+    TPU: steered here, in the test, not by an option of the program."""
+    import functools
+
+    from paddle_tpu.ops.pallas import paged_attention as kernel
+    from paddle_tpu.serving import decode
+
+    monkeypatch.setattr(decode.PagedDecodePrograms, "_kernel",
+                        staticmethod(lambda: True))
+    monkeypatch.setattr(kernel, "paged_attention", functools.partial(
+        kernel.paged_attention, interpret=True))
+
+
+class TestPagedAttentionKernel:
+    """ISSUE 33: on a TPU the paged programs' attention is one Pallas
+    kernel over the block table. Its oracle is what every other backend
+    runs: ``gather_pages`` + ``_attend_merged`` on the same pool."""
+
+    TOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
+    @staticmethod
+    def _case(ps, heads, dim, queries, dtype, seed=0):
+        """One pool after a step's write, and the step's q, tables and
+        positions. Lanes: position 0; the last row of the first page
+        (255 at the cell's page size); the first row of the second (256);
+        a lane into its third page; a short lane; a padded batch lane
+        (table all page 0, position 0). The table rung (4) is wider than
+        any lane's pages, and lane 3's pages are not in order."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.serving import kv_cache as kvc
+
+        rs = np.random.RandomState(seed)
+        HD, T, L, N = heads * dim, 4, 2, 12
+        tables = np.zeros((6, T), np.int32)
+        first = np.array([0, ps - 1, ps, 2 * ps + 5, ps // 2, 0], np.int32)
+        owned = iter([3, 1, 7, 4, 9, 2, 8, 5, 6, 10])
+        pos = first[:, None] + np.arange(queries, dtype=np.int32)[None, :]
+        for b in range(5):
+            for t in range(pos[b, -1] // ps + 1):
+                tables[b, t] = next(owned)
+        dt = jnp.dtype(dtype)
+        kpool, vpool = (jnp.asarray(rs.randn(L, N, ps, HD), dt)
+                        for _ in range(2))
+        q, k, v = (jnp.asarray(rs.randn(6, queries, HD), dt)
+                   for _ in range(3))
+        pages = np.take_along_axis(tables, pos // ps, axis=1)
+        kpool = kvc.append_token_paged(kpool, 1, pages, pos % ps, k)
+        vpool = kvc.append_token_paged(vpool, 1, pages, pos % ps, v)
+        return kpool, vpool, q, tables, pos
+
+    @staticmethod
+    def _both(kpool, vpool, q, tables, pos, heads, scale):
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.paged_attention import paged_attention
+        from paddle_tpu.serving import kv_cache as kvc
+        from paddle_tpu.serving.decode import _attend_merged
+
+        got = paged_attention(q, kpool, vpool, jnp.int32(1),
+                              jnp.asarray(tables), jnp.asarray(pos),
+                              heads=heads, scale=scale, interpret=True)
+        want = _attend_merged(q, kvc.gather_pages(kpool, 1, tables),
+                              kvc.gather_pages(vpool, 1, tables),
+                              jnp.asarray(pos), heads, scale)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        return (np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("queries", [1, 3], ids=["S1", "Sk1"])
+    @pytest.mark.parametrize("ps,heads,dim", [(256, 2, 8), (16, 12, 64)],
+                             ids=["page256", "heads12x64"])
+    def test_kernel_agrees_with_gather_and_attend(self, ps, heads, dim,
+                                                  queries, dtype):
+        got, want = self._both(*self._case(ps, heads, dim, queries, dtype),
+                               heads, 1.0 / np.sqrt(dim))
+        tol = self.TOL[dtype]
+        np.testing.assert_allclose(got, want, rtol=tol,
+                                   atol=tol * np.abs(want).max())
+
+    @pytest.mark.parametrize("queries", [1, 3], ids=["S1", "Sk1"])
+    def test_a_dead_page_is_never_read(self, queries):
+        """NaN in the pad page and in every page no lane owns changes
+        nothing and reaches no real lane: a table entry past a lane's
+        last live page costs no read. (The padded batch lane reads the
+        pad page; its output is thrown away, here as in the programs.)"""
+        import jax.numpy as jnp
+
+        kpool, vpool, q, tables, pos = self._case(16, 4, 8, queries,
+                                                  "float32", seed=1)
+        clean, _ = self._both(kpool, vpool, q, tables, pos, 4, 0.35)
+        dead = np.setdiff1d(np.arange(kpool.shape[1]), tables[tables > 0])
+        assert 0 in dead and len(dead) >= 3
+        kpool, vpool = (p.at[:, dead].set(jnp.nan) for p in (kpool, vpool))
+        got, want = self._both(kpool, vpool, q, tables, pos, 4, 0.35)
+        assert np.isfinite(got[:5]).all()
+        np.testing.assert_array_equal(got[:5], clean[:5])
+        # the oracle does read them: probabilities of 0 times NaN
+        assert not np.isfinite(want[0]).all()
+
+    @pytest.mark.parametrize("speculate_k", [0, 2], ids=["plain", "speculate"])
+    def test_programs_with_the_kernel_return_the_compositions_tokens(
+            self, monkeypatch, speculate_k):
+        """Four lanes, a prefill and eight decode steps (or speculation
+        rounds: draft and verify take the kernel too): the greedy
+        streams with the kernel forced are the composition's."""
+        model = _tiny_model(num_hidden_layers=2, num_attention_heads=2,
+                            max_position_embeddings=64)
+        prompts = _prompts([5, 15, 16, 30], seed=33)
+        kw = dict(max_seq=64, seq_buckets=[32], page_size=16,
+                  prefill_max_batch=4, speculate_k=speculate_k,
+                  spec_draft_layers=1, spec_min_accept=0.0)
+
+        def streams():
+            eng = _paged(model, **kw).warmup()
+            try:
+                futs = [eng.submit("t", p, max_new_tokens=9) for p in prompts]
+                out = [np.asarray(f.result(120)) for f in futs]
+                assert eng.serving_report()["compiles_after_warmup"] == 0
+                return out
+            finally:
+                eng.shutdown(drain=True)
+
+        want = streams()
+        _force_kernel(monkeypatch)
+        for got, ref in zip(streams(), want):
+            assert len(got) == 9 and np.array_equal(got, ref)
+
+    @pytest.mark.parametrize("kind", ["decode", "draft", "verify"])
+    def test_with_the_kernel_no_program_gathers_a_dense_view(
+            self, monkeypatch, programs, kind):
+        """Traced with the kernel, a program holds one ``pallas_call`` a
+        layer and attention step, named ``paged_attn`` under ``attn/core``,
+        nothing under ``attn/kv_gather``, and no intermediate of the
+        gathered view's shape."""
+        import jax
+
+        from paddle_tpu.analysis.drift_check import _walk
+        from paddle_tpu.base import regions
+
+        _force_kernel(monkeypatch)
+        P = programs
+        key = (kind, 4, 2)
+        fn = {"decode": P._decode_fn, "draft": P._draft_fn,
+              "verify": P._verify_fn}[kind]
+        closed = jax.make_jaxpr(fn)(P._call_params(key), P.pool.k, P.pool.v,
+                                    *P._zero_args(key))
+        layers = {"decode": 2, "draft": 1 * P.speculate_k, "verify": 2}[kind]
+        # one jitted call a layer and attention step, sharing one trace
+        calls = [e for e in closed.jaxpr.eqns
+                 if e.params.get("name") == "paged_attention"]
+        assert len(calls) == layers
+        assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+        for e in calls:
+            assert str(e.source_info.name_stack).endswith(regions.ATTN_CORE)
+            (kernel,) = [x for x in _walk(e.params["jaxpr"].jaxpr)
+                         if x.primitive.name == "pallas_call"]
+            assert str(kernel.source_info.name_stack) == regions.PAGED_ATTN
+        ps, HD = P.pool.page_size, P.pool.num_heads * P.pool.head_dim
+        for e in _walk(closed.jaxpr):
+            assert regions.ATTN_KV_GATHER not in str(e.source_info.name_stack)
+            for var in e.outvars:
+                shape = tuple(getattr(var.aval, "shape", ()))
+                assert shape not in ((4, 2 * ps, HD), (4, 2, ps, HD)), (
+                    e.primitive.name, shape)
+
+
 # ------------------------------------------------- mixed-context matrix
 class TestMixedContextMatrix:
     def test_greedy_bit_exact_vs_slot_oracle(self, engine, oracle):
@@ -419,6 +592,25 @@ def _parent_choose_tokens(head, temps, top_ks, top_ps, rkeys):
     return jnp.where(temps > 0, sampled, greedy)
 
 
+@contextlib.contextmanager
+def _decode_spans():
+    """The program's tracer on for the block; the yielded list holds the
+    block's ``serving.decode`` events once it has ended."""
+    from paddle_tpu.observability import tracer
+
+    tracer.reset()
+    was = tracer.enabled
+    tracer.enable()
+    events = []
+    try:
+        yield events
+        events += [e for e in tracer.to_chrome_trace()["traceEvents"]
+                   if e["ph"] == "X" and e["name"] == "serving.decode"]
+    finally:
+        tracer.enabled = was
+        tracer.reset()
+
+
 def _steps(stats) -> int:
     """Program calls the stats counted, of every kind."""
     cell = stats.summary()["decode"]
@@ -541,14 +733,9 @@ class TestSortOnlyWhenSampling:
             self, speculate_k):
         """``serving.decode`` carries ``sampling``; the stats count no
         call of a greedy run and every call of a sampled request's."""
-        from paddle_tpu.observability import tracer
-
         model = _tiny_model(num_hidden_layers=2, max_position_embeddings=64)
         stats = ServingStats()
-        tracer.reset()
-        was = tracer.enabled
-        tracer.enable()
-        try:
+        with _decode_spans() as steps:
             eng = _paged(model, max_seq=64, seq_buckets=[32, 64],
                          page_size=16, speculate_k=speculate_k,
                          spec_draft_layers=1, spec_min_accept=0.0,
@@ -568,11 +755,6 @@ class TestSortOnlyWhenSampling:
                         == _steps(stats) - n_greedy > 0)
             finally:
                 eng.shutdown(drain=True)
-            steps = [e for e in tracer.to_chrome_trace()["traceEvents"]
-                     if e["ph"] == "X" and e["name"] == "serving.decode"]
-        finally:
-            tracer.enabled = was
-            tracer.reset()
         # the tracer is the process's: keep this engine's steps
         mine = {r.id for r in greedy} | {one.id}
         steps = [e for e in steps if set(e["args"]["requests"]) <= mine]
@@ -594,6 +776,71 @@ class TestSortOnlyWhenSampling:
                 r.result(60)
         assert engine.programs.traces == warmed
         assert engine.serving_report()["compiles_after_warmup"] == 0
+
+
+class TestPagesLiveCounter:
+    """ISSUE 33: a paged decode span says how much of its block table
+    was live, and the stats keep the running share."""
+
+    @staticmethod
+    def _serve(speculate_k, sizes, new_tokens, stats):
+        """Serve ``sizes`` prompts on a traced engine of 16-token pages;
+        return this engine's ``serving.decode`` events of a decode kind."""
+        model = _tiny_model(num_hidden_layers=2, max_position_embeddings=64)
+        with _decode_spans() as events:
+            eng = _paged(model, max_seq=64, seq_buckets=[32, 64],
+                         page_size=16, speculate_k=speculate_k,
+                         spec_draft_layers=1, spec_min_accept=0.0,
+                         stats=stats).warmup()
+            try:
+                reqs = [eng.submit("g", p, max_new_tokens=new_tokens)
+                        for p in _prompts(sizes, seed=47)]
+                for r in reqs:
+                    r.result(60)
+            finally:
+                eng.shutdown(drain=True)
+        mine = {r.id for r in reqs}
+        return [e["args"] for e in sorted(events, key=lambda e: e["ts"])
+                if set(e["args"]["requests"]) <= mine
+                and e["args"]["kind"] != "prefill"]
+
+    @pytest.mark.parametrize("speculate_k", [0, 2], ids=["plain", "speculate"])
+    def test_span_carries_pages_live_and_pages_table(self, speculate_k):
+        stats = ServingStats()
+        steps = self._serve(speculate_k, [5, 20, 30, 40], 8, stats)
+        assert steps
+        for a in steps:
+            assert a["kind"] == ("speculate" if speculate_k else "decode")
+            assert a["pages_table"] == a["rung"][0] * a["rung"][1]
+            assert a["lanes"] <= a["pages_live"] <= a["pages_table"]
+        cell = stats.summary()["decode"]
+        assert cell["pages_live"] == sum(a["pages_live"] for a in steps)
+        assert cell["pages_table"] == sum(a["pages_table"] for a in steps)
+        assert 0 < cell["pages_live_share"] <= 1
+
+    def test_crossing_a_page_boundary_raises_pages_live_by_one(self):
+        """One lane, prompt 14, pages of 16: the steps write positions
+        14, 15, 16, 17 ...; the step that writes 16 is the first to see
+        a second page."""
+        steps = self._serve(0, [14], 6, ServingStats())
+        assert [a["pages_live"] for a in steps] == [1, 1, 2, 2, 2]
+        assert [a["pages_table"] for a in steps] == [1, 1, 2, 2, 2]
+
+    def test_metrics_page_shows_the_share(self):
+        from paddle_tpu.observability.export import prometheus_text
+        from paddle_tpu.observability.metrics import MetricsRegistry
+
+        stats = ServingStats()
+        reg = MetricsRegistry()
+        reg.register_collector("serving", stats.summary)
+        assert "pages_live" not in prometheus_text(reg.snapshot())
+        stats.record_decode_step("decode", 0.001, 3, 3)
+        stats.record_pages(5, 8)
+        stats.record_pages(6, 8)
+        lines = prometheus_text(reg.snapshot()).splitlines()
+        assert "paddle_serving_decode_pages_live 11" in lines
+        assert "paddle_serving_decode_pages_table 16" in lines
+        assert "paddle_serving_decode_pages_live_share 0.6875" in lines
 
 
 # ------------------------------------------------------- pool pressure
