@@ -431,6 +431,47 @@ def _retention_fingerprints(programs, rung_grids) -> None:
     rung_grids["decode/state"] = sorted(grid)
 
 
+def _latent_fingerprints(programs, rung_grids) -> None:
+    """The latent-page residency's representatives: one prefill chunk and
+    one decode rung of a tiny A.X-K1 (one dense layer, one sparse, a quarter
+    of the experts held) over a one-array KVPagePool (the jnp decode path:
+    the Pallas kernel is a TPU's), retraced abstractly."""
+    import jax
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from ..models.axk1 import AXK1ForCausalLM, axk1_tiny
+    from ..serving.decode import LatentPrograms
+    from ..serving.kv_cache import KVPagePool
+
+    paddle.seed(0)
+    model = AXK1ForCausalLM(axk1_tiny(
+        num_hidden_layers=2, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, vocab_size=64, max_position_embeddings=32),
+        expert_share=(1, 4))
+    model.eval()
+    pool = KVPagePool(num_layers=2, num_pages=8, page_size=8, row_width=128,
+                      arrays=1)
+    progs = LatentPrograms(model, pool, seq_ladder=[8], decode_rungs=[2],
+                           max_seq=32)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+
+    donation = tuple(f"arg{i}" for i in progs._donate)
+    fns = {"decode": progs._decode_fn, "prefill": progs._prefill_fn}
+    grid = []
+    for key in progs.rungs:
+        closed = jax.make_jaxpr(fns[key[0]])(
+            jax.tree_util.tree_map(sds, progs.params), sds(pool.k),
+            *(sds(a) for a in progs._zero_args(key)))
+        rung = ":".join(str(p) for p in key)
+        grid.append(rung)
+        programs[f"decode/latent:{rung}"] = fingerprint_jaxpr(
+            closed, donation=donation)
+    rung_grids["decode/latent"] = sorted(grid)
+
+
 def _qpsum_fingerprint(programs) -> None:
     """The quantized-allreduce oracle over an awkward (non-multiple)
     shape — the exact wire math, block size pinned so the trace is
@@ -497,6 +538,7 @@ def record_drift_programs(refresh: bool = False) -> dict:
         _serving_fingerprints(programs, rung_grids)
         _decode_fingerprints(programs, rung_grids)
         _retention_fingerprints(programs, rung_grids)
+        _latent_fingerprints(programs, rung_grids)
         _qpsum_fingerprint(programs)
         _reshard_fingerprints(programs, skipped)
     live = {"programs": programs, "rung_grids": rung_grids,

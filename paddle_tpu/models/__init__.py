@@ -6,6 +6,13 @@ fleet examples built from fleet/layers/mpu/mp_layers.py. Here the language
 flagship (GPT) lives in-tree because it is the hybrid-parallel benchmark
 target (BASELINE.md: "Fleet hybrid-parallel GPT ... tokens/sec").
 """
+from .axk1 import (  # noqa: F401
+    AXK1Config,
+    AXK1ForCausalLM,
+    AXK1Model,
+    AXK1Stack,
+    axk1_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -24,6 +24,11 @@ block-diagonally over the merged minor dimension (row ``s x heads + h`` holds
 head ``h``'s ``head_dim`` columns of query ``s`` and zeros elsewhere), so both
 products contract the whole ``heads x head_dim`` on the matrix unit and the
 minor dimension is never split.
+
+:func:`latent_paged_attention` is the same kernel for a pool whose row is a
+token's LATENT (``models/axk1.py``): a page is K and, in its leading columns,
+V, for every head at once, so it is one operand and not two, and q is dense
+(64 heads are 64 rows, each ``[q_lat | q_rope]``) and not spread.
 """
 from __future__ import annotations
 
@@ -42,9 +47,13 @@ PAGES = 4    # table entries a grid step: 8 double-buffered (256, 768) bf16 bloc
 
 
 def _kernel(layer_ref, pages_ref, last_ref, pos_ref, q_ref, *refs, scale,
-            heads, queries, page, per_step):
+            heads, queries, page, per_step, v_cols=None):
+    """``v_cols`` None: K pages and V pages apart (``per_step`` operands
+    each). ``v_cols`` n: a page is K and V at once, V its leading n columns
+    (the latent caller), so a page is ONE operand and its bytes move once."""
     del layer_ref, pages_ref   # read by the index maps
-    kv_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:2 * per_step], refs[2 * per_step:]
+    n_kv = per_step if v_cols else 2 * per_step
+    kv_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:n_kv], refs[n_kv:]
     b, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
@@ -57,9 +66,11 @@ def _kernel(layer_ref, pages_ref, last_ref, pos_ref, q_ref, *refs, scale,
         entry = t * per_step + j
 
         @pl.when(entry <= last_ref[b])
-        def _(entry=entry, k_ref=kv_refs[j], v_ref=kv_refs[per_step + j]):
+        def _(entry=entry, k_ref=kv_refs[j], v_ref=kv_refs[j if v_cols else per_step + j]):
             v = v_ref[...]
-            logits = _dot(q_ref[...], k_ref[...], _NT) * scale   # [rows, page] f32
+            logits = _dot(q_ref[...], v if v_cols else k_ref[...], _NT) * scale   # [rows, page] f32
+            if v_cols:
+                v = v[:, :v_cols]
             row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
             col = entry * page + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
             # row s x heads + h asks for query s; padding rows keep the last one's
@@ -153,3 +164,59 @@ def paged_attention(q, k_pool, v_pool, layer, tables, positions, *, heads,
     # head h keeps its own dim columns of row s x heads + h
     full = full[:, :S * heads].reshape(B, S, heads, HD)
     return jnp.where(own, full, 0).sum(axis=2)
+
+
+LATENT_PAGES = 8   # table entries a grid step: 8 double-buffered (256, 640) bf16 blocks are 5.2 MB of VMEM
+
+
+@functools.partial(jax.jit, static_argnames=("v_cols", "scale", "interpret"))
+def latent_paged_attention(q, pool, layer, tables, positions, *, v_cols, scale,
+                           interpret=False):
+    """The absorbed latent attention of one query a lane over latent pages:
+    ``q`` ``[B, H, W]`` (head ``h``'s ``[q_lat | q_rope | zeros]``, ``W`` the
+    pool's row width), ``pool`` ``[L, N, page, W]`` whole, whose row is K and,
+    in its leading ``v_cols`` columns, V; ``tables`` ``[B, T]``, ``positions``
+    ``[B]``. Returns ``o_lat`` ``[B, H, v_cols]``: lane ``b`` attends over
+    columns ``<= positions[b]`` of its pages. The page loop, the dead-entry
+    rule and the softmax are :func:`paged_attention`'s (one ``_kernel``); the
+    heads are the rows of one dense q, not a block-diagonal spread, and every
+    head reads the same page."""
+    B, H, W = q.shape
+    T = tables.shape[1]
+    page = pool.shape[2]
+    per_step = next(n for n in range(min(T, LATENT_PAGES), 0, -1) if T % n == 0)
+    rows = -(-H // ROWS) * ROWS
+    q = jnp.pad(q, ((0, 0), (0, rows - H), (0, 0)))
+    positions = positions.astype(jnp.int32)
+    last = jnp.minimum(positions // page, T - 1)
+
+    def lane(b, t, layer, pages, last, pos):
+        return (b, 0, 0)
+
+    def entry(j):
+        return lambda b, t, layer, pages, last, pos: (
+            layer[0], pages[b * T + t * per_step + j], 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, heads=rows, queries=1,
+                          page=page, per_step=per_step, v_cols=v_cols),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, T // per_step),
+            in_specs=[pl.BlockSpec((None, rows, W), lane)]
+            + [pl.BlockSpec((None, None, page, W), entry(j)) for j in range(per_step)],
+            out_specs=pl.BlockSpec((None, rows, v_cols), lane),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),        # m
+                pltpu.VMEM((rows, 1), jnp.float32),        # l
+                pltpu.VMEM((rows, v_cols), jnp.float32),   # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, v_cols), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=regions.LATENT_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      _held_pages(tables.astype(jnp.int32), last, per_step), last,
+      positions, q, *[pool] * per_step)
+    return out[:, :H]

@@ -34,8 +34,15 @@ A model whose layers keep a recurrent state and no keys and values
 (``models.BrumbyForCausalLM``) is served by the same engine over the third
 residency: :class:`StateLanePool` (one float32 state array, a lane a
 request) and :class:`RetentionPrograms` (a chunked prefill that carries
-the state, a decode step that updates it in place); the residency follows
-from the model.
+the state, a decode step that updates it in place). A model with latent
+attention and sparse experts (``models.AXK1ForCausalLM``) is served over
+the fourth: a :class:`KVPagePool` of ONE array whose row is a token's
+latent, K and V at once, and :class:`LatentPrograms` (a prefill chunk
+that attends in the expanded form over the pages before its cursor, an
+absorbed decode step over the latent pages, an expert layer that is told
+which experts it holds), under the paged scheduler with the chunked
+prefill's cursor. The residency follows from the model
+(``serving_residency``): no option selects it.
 
 Latency accounting (enqueue→admit→dispatch→complete, queue depth,
 p50/p99, requests/sec at FLAGS_serving_slo_ms, the prefill-vs-decode
@@ -50,9 +57,10 @@ step split and decode tokens/sec) flows through
     req.result()
     engine.shutdown(drain=True)
 """
-from .decode import DecodeEngine, DecodePrograms, RetentionPrograms
+from .decode import (DecodeEngine, DecodePrograms, LatentPrograms,
+                     RetentionPrograms)
 from .engine import EngineBase, ServingEngine
-from .kv_cache import KVSlotPool, StateLanePool
+from .kv_cache import KVPagePool, KVSlotPool, StateLanePool
 from .request_queue import (AdmissionController, AdmissionError,
                             DecodeRequest, RejectedError, Request,
                             RequestQueue)
@@ -62,7 +70,7 @@ from .scheduler import (DecodeScheduler, Scheduler, scatter_outputs,
 __all__ = [
     "AdmissionController", "AdmissionError", "DecodeEngine",
     "DecodePrograms", "DecodeRequest", "DecodeScheduler", "EngineBase",
-    "KVSlotPool", "RejectedError", "Request", "RequestQueue",
+    "KVPagePool", "KVSlotPool", "LatentPrograms", "RejectedError", "Request", "RequestQueue",
     "RetentionPrograms", "Scheduler", "ServingEngine", "StateLanePool",
     "scatter_outputs", "stack_requests",
 ]

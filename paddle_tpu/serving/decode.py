@@ -52,7 +52,7 @@ from .request_queue import DecodeRequest
 from .scheduler import DecodeScheduler, PagedDecodeScheduler
 
 __all__ = ["DecodeEngine", "DecodePrograms", "PagedDecodePrograms",
-           "RetentionPrograms"]
+           "RetentionPrograms", "LatentPrograms"]
 
 
 def _extract_gpt(model):
@@ -367,9 +367,9 @@ class DecodePrograms:
         # one traced call against the pad slot (harmless writes land in
         # the trash slot); outputs are committed so a donation backend
         # keeps the pool buffers alive
-        *arrays, _ = self._jitted(key)(self._call_params(key),
-                                       *self.pool.arrays(), *args)
-        self.pool.commit(*arrays)
+        held = self.pool.arrays()
+        out = self._jitted(key)(self._call_params(key), *held, *args)
+        self.pool.commit(*out[:len(held)])
 
     def _call_params(self, key) -> dict:
         """The parameter pytree rung ``key`` runs against. The base
@@ -1104,11 +1104,385 @@ class RetentionPrograms(DecodePrograms):
                                 positions)
 
 
-class DecodeEngine(EngineBase):
-    """GPT decode serving with true continuous batching.
+def _extract_axk1(model):
+    """A ``models.axk1.AXK1ForCausalLM``'s parameters as a plain pytree,
+    zero-copy: the dense layers stacked in one tree, the sparse ones in
+    another, as the model holds them."""
+    def stack(layers):
+        return {name: p._value for name, p in layers._parameters.items()}
 
-    ``model`` is a live ``models.gpt.GPTForCausalLM`` (eval mode; its
-    device weights are shared zero-copy with training/export users).
+    return {
+        "embed": model.axk1.embed_tokens._value,
+        "norm": model.axk1.norm._value,
+        "head": model.lm_head._value,
+        "dense": stack(model.axk1.dense),
+        "sparse": stack(model.axk1.sparse),
+    }, model.config
+
+
+class LatentPrograms(PagedDecodePrograms):
+    """The decode program set over a :class:`~.kv_cache.KVPagePool` of
+    LATENT rows, for a model with multi-head latent attention and sparse
+    experts (``models/axk1.py``). The pool is one array ``[layers, pages +
+    1, page, W]``: a token's row in a layer is ``[c_kv | k_rope | zeros]``,
+    K and V at once for every head (``W`` is the latent width rounded up to
+    whole lanes of 128). Pages, block tables, admission by pages, the
+    sampling arguments and :meth:`_choose_tokens` are the paged family's;
+    the programs are not:
+
+    - ``("prefill", 1, c)``: ONE CHUNK of one lane's prompt, ``c`` from the
+      seq ladder (whole chunks run the top rung, a ragged last chunk the
+      smallest rung that holds it), at ``start``, a multiple of the top
+      rung. Each layer writes the chunk's latent rows to the lane's pages,
+      then attends in the EXPANDED form over ``[the pages before the cursor
+      | the chunk]``, a block of top-rung tokens at a time under a running
+      softmax, keys and values formed from the stored rows a block and a
+      group of heads at a time, so the temporaries stay a block's whatever
+      the context. A 16k prompt is eight calls of one program.
+    - ``("decode", b)``: one token a lane, ABSORBED: the key's
+      up-projection moves onto the query, the scores and the weighted sum
+      run over the latent rows themselves (on a TPU the kernel
+      ``latent_paged_attn`` over the live pages where they lie, elsewhere
+      ``gather_pages`` and ``attend_absorbed``), the value's up-projection
+      comes after. There is ONE table rung, the whole table: the kernel
+      skips the entries past a lane's last page, and with 64 lanes of
+      mixed depths one is nearly always long.
+
+    The dense layers run under one ``lax.scan`` and the sparse ones under
+    another, the pool riding the carry. The routed experts' weights do NOT
+    ride the scan: they are read as they lie, ``[layers x held, ...]``, and a
+    layer's grouped product is told where its groups start
+    (``sparse_experts.held_experts``); sliced out layer by layer they would
+    be copied, 0.7 GB a layer a step. Each program returns, beside the
+    tokens, the pairs each held expert computed, ``[sparse layers, held]``,
+    which the scheduler reads in the same read (:meth:`note_step`)."""
+
+    chunked = True
+    _extract = staticmethod(_extract_axk1)
+    HEAD_GROUP = 8   # heads whose scores exist at once in a prefill block
+
+    def __init__(self, model, pool: KVPagePool, *, seq_ladder: Sequence[int],
+                 decode_rungs: Sequence[int], max_seq: int):
+        stack = model.axk1.sparse
+        self._first, self._held = int(stack.first), int(stack.held)
+        super().__init__(model, pool, seq_ladder=seq_ladder,
+                         prefill_batch_rungs=[1], decode_rungs=decode_rungs,
+                         max_seq=max_seq)
+        top, ps = self.seq_ladder[-1], pool.page_size
+        if any(c % ps for c in self.seq_ladder):
+            raise ValueError(f"a prefill chunk writes whole pages: every seq "
+                             f"bucket {self.seq_ladder} must be a multiple of "
+                             f"the page size {ps}")
+        # whole blocks of the top rung, so that no chunk's pages and no
+        # block's run past the table
+        self.table_rungs = [-(-self.max_seq // top) * (top // ps)]
+
+    def _bind_config(self, cfg) -> None:
+        from ..nn.functional import latent_attention as la
+
+        self._cfg = cfg
+        self._heads = int(cfg.num_attention_heads)
+        self._nope = int(cfg.qk_nope_head_dim)
+        self._rope_dim = int(cfg.qk_rope_head_dim)
+        self._rank = int(cfg.kv_lora_rank)
+        self._hidden = int(cfg.hidden_size)
+        self._max_pos = int(cfg.max_position_embeddings)
+        self._eps = float(cfg.rms_norm_eps)
+        self._inv_freq = cfg.inv_freq()
+        self._scale = float(la.softmax_scale(cfg.qk_head_dim, cfg.rope_scaling))
+        self._dense_layers = int(cfg.first_k_dense_replace)
+        if self.pool.row_width < cfg.latent_width:
+            raise ValueError(f"the pool's rows are {self.pool.row_width} wide, a "
+                             f"latent row needs {cfg.latent_width}")
+
+    # ------------------------------------------------------------ the block
+    def _latent_proj(self, w, x, positions):
+        """``x`` ``[N, hidden]`` at ``positions`` ``[N]`` -> ``q_nope`` ``[N,
+        H, dn]``, ``q_rope`` ``[N, H, dr]`` (rotated) and the tokens' cache
+        rows ``[N, W]``: ``[c_kv (normed) | k_rope (rotated) | zeros]``."""
+        from ..nn.functional import latent_attention as la
+
+        N, rank = x.shape[0], self._rank
+        with region(regions.NORM):
+            a = _rms(x, w["input_norm"], self._eps)
+        with region(regions.ATTN_LATENT_PROJ):
+            c_q = _rms(a @ w["q_a_proj"], w["q_a_norm"], self._eps)
+            q = (c_q @ w["q_b_proj"]).reshape(N, self._heads, -1)
+            kv = a @ w["kv_a_proj"]
+            c_kv = _rms(kv[:, :rank], w["kv_a_norm"], self._eps)
+        with region(regions.ROPE):
+            q_rope = la.rope(q[..., self._nope:], positions, self._inv_freq)
+            k_rope = la.rope(kv[:, rank:], positions, self._inv_freq)
+        return q[..., :self._nope], q_rope, self._cache_rows(c_kv, k_rope)
+
+    def _cache_rows(self, c_kv, k_rope):
+        """The tokens' rows as the pool holds them: ``[c_kv (normed) | k_rope
+        (rotated) | zeros]``, ``[N, W]``."""
+        import jax.numpy as jnp
+
+        with region(regions.ATTN_KV_WRITE):
+            pad = jnp.zeros((c_kv.shape[0], self.pool.row_width - c_kv.shape[1]
+                             - k_rope.shape[1]), c_kv.dtype)
+            return jnp.concatenate([c_kv, k_rope, pad], axis=-1)
+
+    def _ffn(self, w, x, experts, group_offset, valid):
+        """The layer's second half on ``x`` ``[N, hidden]``: a dense SwiGLU
+        (``experts`` None), or the router, the held experts' part and the
+        shared expert. ``valid`` ``[N]`` is False for a padding token, which
+        picks no expert. Returns the new ``x`` and the held experts' pair
+        counts (None for a dense layer)."""
+        import jax.numpy as jnp
+
+        from ..nn.functional import sparse_experts as se
+
+        cfg = self._cfg
+        with region(regions.NORM):
+            b = _rms(x, w["post_norm"], self._eps)
+        if experts is None:
+            with region(regions.MLP):
+                return x + se.swiglu(b, w["gate_up_proj"], w["down_proj"]), None
+        idx, wt = se.route(b, w["router"], n_group=cfg.n_group,
+                           topk_group=cfg.topk_group, top_k=cfg.num_experts_per_tok,
+                           scaling=cfg.routed_scaling_factor,
+                           norm_topk=cfg.norm_topk_prob)
+        idx = jnp.where(valid[:, None], idx, -1)
+        routed, counts = se.held_experts(
+            b, idx, wt, *experts, first=self._first, held=self._held,
+            group_offset=group_offset)
+        with region(regions.MOE_SHARED):
+            shared = se.swiglu(b, w["shared_gate_up"], w["shared_down"])
+        return x + shared + routed, counts
+
+    def _stacks(self, params, x, pool, attend, valid):
+        """Every layer: the dense stack, then the sparse one, each under one
+        scan with the pool on the carry. ``attend(w, x, pool, li)`` is the
+        layer's first half (it writes the pool). Returns ``x``, the pool and
+        the pair counts ``[sparse layers, held]``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        def dense(carry, w):
+            x, pool, li = carry
+            x, pool = attend(w, x, pool, li)
+            x, _ = self._ffn(w, x, None, None, valid)
+            return (x, pool, li + 1), None
+
+        sparse_w = dict(params["sparse"])
+        # the routed experts as they lie, every layer's groups in one run
+        experts = tuple(
+            sparse_w.pop(name).reshape((-1,) + params["sparse"][name].shape[2:])
+            for name in ("experts_gate_up", "experts_down"))
+
+        def sparse(carry, w):
+            x, pool, li = carry
+            x, pool = attend(w, x, pool, li)
+            x, counts = self._ffn(w, x, experts,
+                                  (li - self._dense_layers) * self._held, valid)
+            return (x, pool, li + 1), counts
+
+        carry = (x, pool, jnp.zeros((), jnp.int32))
+        carry, _ = lax.scan(dense, carry, params["dense"])
+        (x, pool, _), counts = lax.scan(sparse, carry, sparse_w)
+        return x, pool, counts
+
+    def _logits_head(self, params, x):
+        with region(regions.LM_HEAD):
+            return _rms(x, params["norm"], self._eps) @ params["head"]
+
+    # ------------------------------------------------------------ attention
+    def _attend_chunk(self, w, q_nope, q_rope, pool, li, table, start):
+        """Expanded attention of a chunk's ``C`` queries at ``start ..
+        start + C - 1`` over the lane's pages, the chunk's own rows already
+        written: blocks of ``top rung`` tokens (``start`` is a multiple of
+        it, so the chunk lies in the last block), ``start // top + 1`` of
+        them, each gathered from the pool and expanded for
+        :attr:`HEAD_GROUP` heads at a time. ``table`` ``[T]``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        from ..nn.functional import latent_attention as la
+
+        C, H, dn = q_nope.shape
+        rank, dr, W = self._rank, self._rope_dim, self.pool.row_width
+        blk, ps = self.seq_ladder[-1], self.pool.page_size
+        G = min(self.HEAD_GROUP, H)
+        qpos = start + jnp.arange(C, dtype=jnp.int32)
+        n_blocks = start // blk + 1
+
+        def heads_first(a):     # [N, H, d] -> [H / G, N, G, d]
+            return a.reshape(a.shape[0], H // G, G, -1).transpose(1, 0, 2, 3)
+
+        def group(a):
+            q, wk, wv = a
+
+            def block(j, carry):
+                with region(regions.ATTN_KV_GATHER):
+                    pages = lax.dynamic_slice(table, (j * (blk // ps),), (blk // ps,))
+                    rows = pool[li, pages].reshape(blk, W)
+                col = j * blk + jnp.arange(blk, dtype=jnp.int32)
+                return la.expanded_block(
+                    carry, q, rows[:, :rank], rows[:, rank:rank + dr],
+                    wk, wv, col[None, :] <= qpos[:, None], self._scale)
+
+            carry = lax.fori_loop(0, n_blocks, block,
+                                  la.start_blocks(C, G, wv.shape[-1]))
+            return la.finish_blocks(carry, q_nope.dtype)
+
+        out = lax.map(group, (heads_first(jnp.concatenate([q_nope, q_rope], -1)),
+                              heads_first(w["k_b_proj"].reshape(rank, H, dn)),
+                              heads_first(w["v_b_proj"].reshape(rank, H, -1))))
+        return out.transpose(1, 0, 2, 3).reshape(C, -1)
+
+    def _attend_step(self, w, q_nope, q_rope, pool, li, tables, positions):
+        """Absorbed attention of one query a lane over its latent pages, the
+        step's own rows already written -> ``[B, heads x dv]``."""
+        import jax.numpy as jnp
+
+        from ..nn.functional import latent_attention as la
+
+        with region(regions.ATTN_LATENT_PROJ):
+            q_lat = la.absorb_q(q_nope, w["k_b_proj"])
+        if self._kernel():
+            from ..ops.pallas import paged_attention as kernel
+
+            B, H, _ = q_lat.shape
+            with region(regions.ATTN_CORE):
+                pad = jnp.zeros((B, H, pool.shape[-1] - self._rank - self._rope_dim),
+                                q_lat.dtype)
+                # q in the pool's dtype: the kernel's products take one
+                q = jnp.concatenate([q_lat, q_rope, pad], axis=-1).astype(pool.dtype)
+                o_lat = kernel.latent_paged_attention(
+                    q, pool, li, tables, positions, v_cols=self._rank,
+                    scale=self._scale)
+        else:
+            rows = kvc.gather_pages(pool, li, tables)
+            with region(regions.ATTN_CORE):
+                o_lat = la.attend_absorbed(q_lat, q_rope, rows, positions,
+                                           self._rank, self._scale)
+        with region(regions.ATTN_LATENT_PROJ):
+            return la.unabsorb(o_lat, w["v_b_proj"])
+
+    # ------------------------------------------------------------ programs
+    def _prefill_fn(self, params, pool, tokens, lengths, tables, starts,
+                    temps, top_ks, top_ps, rkeys):
+        import jax.numpy as jnp
+        from jax import lax
+
+        self.traces += 1
+        with region(regions.PREFILL):
+            C, ps = tokens.shape[1], self.pool.page_size
+            n, start, table = lengths[0], starts[0], tables[0]
+            with region(regions.EMBED):
+                x = params["embed"][tokens[0]]
+            positions = start + jnp.arange(C, dtype=jnp.int32)
+            with region(regions.ATTN_KV_WRITE):
+                # the chunk's pages: whole ones, `start` and `C` being
+                # multiples of the page size; entries past the lane's own
+                # are 0, the pad page
+                pages = lax.dynamic_slice(table, (start // ps,), (C // ps,))
+
+            def attend(w, x, pool, li):
+                q_nope, q_rope, rows = self._latent_proj(w, x, positions)
+                pool = kvc.write_chunk_pages(pool, li, pages, rows)
+                att = self._attend_chunk(w, q_nope, q_rope, pool, li, table, start)
+                with region(regions.ATTN_OUT):
+                    return x + att @ w["o_proj"], pool
+
+            x, pool, counts = self._stacks(params, x, pool, attend,
+                                           jnp.arange(C) < n)
+            # the token after the chunk's LAST VALID position
+            with region(regions.LM_HEAD):
+                x_last = lax.dynamic_slice_in_dim(x, n - 1, 1, axis=0)
+            head = self._logits_head(params, x_last)
+            return pool, self._choose_tokens(head, temps, top_ks, top_ps,
+                                             rkeys), counts
+
+    def _decode_fn(self, params, pool, tokens, tables, positions,
+                   temps, top_ks, top_ps, rkeys):
+        import jax.numpy as jnp
+
+        self.traces += 1
+        with region(regions.DECODE):
+            ps = self.pool.page_size
+            with region(regions.EMBED):
+                x = params["embed"][tokens]
+            with region(regions.ATTN_KV_WRITE):
+                pages = jnp.take_along_axis(
+                    tables, (positions // ps).astype(jnp.int32)[:, None], axis=1)[:, 0]
+                offsets = (positions % ps).astype(jnp.int32)
+
+            def attend(w, x, pool, li):
+                q_nope, q_rope, rows = self._latent_proj(w, x, positions)
+                pool = kvc.append_token_paged(pool, li, pages, offsets, rows)
+                att = self._attend_step(w, q_nope, q_rope, pool, li, tables,
+                                        positions)
+                with region(regions.ATTN_OUT):
+                    return x + att @ w["o_proj"], pool
+
+            # a padded batch lane's table names the pad page only
+            x, pool, counts = self._stacks(params, x, pool, attend,
+                                           tables[:, 0] != self.pool.pad_page)
+            head = self._logits_head(params, x)
+            return pool, self._choose_tokens(head, temps, top_ks, top_ps,
+                                             rkeys), counts
+
+    # -------------------------------------------------------------- rungs
+    @property
+    def rungs(self) -> List[tuple]:
+        return ([("decode", b) for b in self.decode_rungs]
+                + [("prefill", 1, c) for c in self.seq_ladder])
+
+    def _zero_args(self, key):
+        t = self.table_rungs[-1]
+        if key[0] == "decode":
+            return PagedDecodePrograms._zero_args(self, ("decode", key[1], t))
+        c = key[2]
+        tokens, lengths, tables, *sample = PagedDecodePrograms._zero_args(
+            self, ("prefill", 1, c))
+        return (tokens, lengths, np.zeros((1, t), np.int32),
+                np.zeros(1, np.int32), *sample)
+
+    # -------------------------------------------------------------- calls
+    def prefill(self, pool, tokens, lengths, tables, starts,
+                temps, top_ks, top_ps, rkeys):
+        return self._jit_prefill(self.params, pool, tokens, lengths, tables,
+                                 starts, temps, top_ks, top_ps, rkeys)
+
+    def decode(self, pool, tokens, tables, positions,
+               temps, top_ks, top_ps, rkeys):
+        return self._jit_decode(self.params, pool, tokens, tables, positions,
+                                temps, top_ks, top_ps, rkeys)
+
+    def note_step(self, counts, tokens: int) -> dict:
+        """What a step's second output says, for its span and the registry:
+        ``counts`` ``[sparse layers, held]`` (already on the host) ->
+        ``pairs`` (token-expert pairs the held experts computed) and
+        ``experts_hit`` (held experts with at least one, over the layers);
+        ``tokens`` latent rows were written in each layer."""
+        from ..observability.metrics import registry
+
+        pairs, hit = int(counts.sum()), int((counts > 0).sum())
+        registry.counter(
+            "serving.moe.pairs",
+            "token-expert pairs computed by the held experts").inc(pairs)
+        registry.counter(
+            "serving.moe.experts_hit",
+            "held experts that computed at least one pair, summed over "
+            "layers and steps").inc(hit)
+        registry.counter(
+            "serving.latent.rows_written",
+            "latent cache rows written (one a token a layer)").inc(
+                int(tokens) * self.pool.num_layers)
+        return {"pairs": pairs, "experts_hit": hit}
+
+
+class DecodeEngine(EngineBase):
+    """Decode serving with true continuous batching.
+
+    ``model`` is a live ``models.gpt.GPTForCausalLM``,
+    ``models.brumby.BrumbyForCausalLM`` or ``models.axk1.AXK1ForCausalLM``
+    (eval mode; its device weights are shared zero-copy with
+    training/export users).
     Requests (:meth:`submit`) join the running batch at the next step
     boundary and leave the step they finish — the scheduler runs ONE
     prefill-or-decode program call per step against the warmed rung
@@ -1117,7 +1491,17 @@ class DecodeEngine(EngineBase):
     moves after warmup (JX332), and greedy tokens are bit-exact with a
     single-request decode of the same prompt.
 
-    Two KV residency modes (``kv_mode``):
+    What a sequence holds on the device between steps follows from the
+    model (its ``serving_residency``): keys and values (a GPT, in pages or
+    slots as ``kv_mode`` says), a recurrent state of constant size in a
+    lane (``"state"``: :class:`RetentionPrograms` over a
+    :class:`~.kv_cache.StateLanePool`, chunked prefill carrying the
+    state), or latent rows in pages, one a token a layer, K and V at once
+    (``"latent"``: :class:`LatentPrograms` over a one-array
+    :class:`~.kv_cache.KVPagePool`, chunked prefill over the pages before
+    the cursor, page and pool sizes from ``page_size``/``pool_pages``).
+    Four program families on one chassis; for a GPT, two KV residency
+    modes (``kv_mode``):
 
     - ``"paged"`` (default, ISSUE 18): a :class:`~.kv_cache.KVPagePool`
       holds fixed-size pages; each request owns only the pages its live
@@ -1164,8 +1548,9 @@ class DecodeEngine(EngineBase):
         # the residency follows from the model: one whose layers keep a
         # recurrent state (``serving_residency = "state"``) has no keys
         # and values to page or slot, whatever ``kv_mode`` says
-        if getattr(model, "serving_residency", "kv") == "state":
-            kv_mode = "state"
+        residency = getattr(model, "serving_residency", "kv")
+        if residency in ("state", "latent"):
+            kv_mode = residency
         max_slots = int(get_flag("serving_max_slots")
                         if max_slots is None else max_slots)
         flag_seq = int(get_flag("serving_max_seq"))
@@ -1178,7 +1563,7 @@ class DecodeEngine(EngineBase):
         prefill_max = int(get_flag("serving_prefill_max_batch")
                           if prefill_max_batch is None else prefill_max_batch)
         prefill_max = min(prefill_max, max_slots)
-        if seq_buckets is None and kv_mode == "state":
+        if seq_buckets is None and kv_mode in ("state", "latent"):
             seq_buckets = [min(2048, max_seq)]  # the prefill chunk
         if seq_buckets is None:
             seq_min = min(int(get_flag("serving_seq_bucket_min")), max_seq)
@@ -1196,10 +1581,14 @@ class DecodeEngine(EngineBase):
         if kv_mode != "paged" and spec_k > 0:
             raise ValueError(
                 "self-speculative decoding rides the paged block tables; "
-                "the slots-mode engine is the greedy oracle and a state "
-                "lane has no rollback — use kv_mode='paged' (a model with "
-                "keys and values) for speculate_k > 0")
+                "the slots-mode engine is the greedy oracle, a state "
+                "lane has no rollback, and a latent-attention model's "
+                "draft would be a truncated stack of the same weights "
+                "whose layer 0 is another kind of layer (dense) than the "
+                "rest — use kv_mode='paged' (a GPT) for speculate_k > 0")
         self.kv_mode = kv_mode
+        #: the residencies that hold pages named by block tables
+        self._pages = kv_mode in ("paged", "latent")
         self.max_slots = max_slots  # max concurrent lanes in either mode
         self.eos_id = eos_id
         self.speculate_k = spec_k
@@ -1219,6 +1608,25 @@ class DecodeEngine(EngineBase):
                 self.queue, self.programs, self.kv_pool,
                 prefill_max_batch=1, eos_id=eos_id, stats=stats,
                 retry=retry, breakers=self.breakers)
+        elif kv_mode == "latent":
+            ps = int(get_flag("serving_page_size")
+                     if page_size is None else page_size)
+            n_pages = int(get_flag("serving_pool_pages")
+                          if pool_pages is None else pool_pages)
+            if n_pages <= 0:
+                n_pages = -(-max_slots * max_seq // ps)
+            # one array; a row is [c_kv | k_rope], padded to whole lanes
+            self.kv_pool = KVPagePool(
+                cfg.num_hidden_layers, n_pages, ps, dtype=kv_dtype,
+                row_width=-(-cfg.latent_width // 128) * 128, arrays=1)
+            self.programs = LatentPrograms(
+                model, self.kv_pool, seq_ladder=seq_buckets,
+                decode_rungs=powers_of_two_buckets(1, max_slots),
+                max_seq=max_seq)
+            self._scheduler = PagedDecodeScheduler(
+                self.queue, self.programs, self.kv_pool,
+                max_lanes=max_slots, prefill_max_batch=1, eos_id=eos_id,
+                stats=stats, retry=retry, breakers=self.breakers)
         elif kv_mode == "slots":
             self.kv_pool = KVSlotPool(
                 cfg.num_hidden_layers, max_slots, max_seq,
@@ -1292,7 +1700,7 @@ class DecodeEngine(EngineBase):
         built with ``speculate_k > 0``). Speculation never changes the
         token stream — committed tokens always come from the full-model
         verify pass — only how many commit per full-model call."""
-        if self.kv_mode != "paged" and temperature > 0:
+        if not self._pages and temperature > 0:
             raise ValueError("sampled decoding needs kv_mode='paged'; "
                              "the slot-pool engine is the greedy oracle "
                              "and the state-lane programs are greedy")
@@ -1307,14 +1715,15 @@ class DecodeEngine(EngineBase):
         req = DecodeRequest(tenant, prompt, max_new_tokens,
                             temperature=temperature, top_k=top_k,
                             top_p=top_p, seed=seed, speculate=spec)
-        top = (self.kv_pool.max_seq - 1 if self.programs.chunked
-               else self.programs.seq_ladder[-1])
+        # chunked programs cut a prompt; the sequence's own limit bounds it
+        limit = getattr(self.programs, "max_seq", None) or self.kv_pool.max_seq
+        top = limit - 1 if self.programs.chunked else self.programs.seq_ladder[-1]
         if req.prompt.size > top:
             raise ValueError(
                 f"prompt of {req.prompt.size} tokens exceeds the largest "
                 f"seq bucket ({top}); raise FLAGS_serving_max_seq or the "
                 "seq ladder")
-        if self.kv_mode == "paged":
+        if self._pages:
             need = -(-int(req.prompt.size) // self.kv_pool.page_size)
             if need > self.kv_pool.num_pages:
                 raise ValueError(
@@ -1418,9 +1827,9 @@ class DecodeEngine(EngineBase):
             kv_slots=self.max_slots,
             active_requests=self.active_requests(),
         )
-        if self.kv_mode == "paged":
+        if self._pages:
             health.update(
-                kv_mode="paged",
+                kv_mode=self.kv_mode,
                 kv_pages=self.kv_pool.num_pages,
                 kv_page_size=self.kv_pool.page_size,
                 kv_pages_in_use=self.kv_pool.in_use(),
@@ -1447,7 +1856,7 @@ class DecodeEngine(EngineBase):
             kv_slots=self.max_slots,
             kv_mode=self.kv_mode,
         )
-        if self.kv_mode == "paged":
+        if self._pages:
             util = self.kv_pool.utilization_report()
             report.update(
                 table_rungs=list(self.programs.table_rungs),

@@ -41,7 +41,7 @@ from ..observability.locks import named_lock
 
 __all__ = ["LanePool", "KVSlotPool", "KVPagePool", "StateLanePool", "write_prompt",
            "write_prompt_batch", "append_token", "write_prompt_pages",
-           "append_token_paged", "gather_pages"]
+           "append_token_paged", "write_chunk_pages", "gather_pages"]
 
 
 # ------------------------------------------------------ functional updates
@@ -105,6 +105,27 @@ def append_token_paged(cache, layer, pages, offsets, rows):
     page_size`` — both traced. Pad lanes carry page 0."""
     with region(regions.ATTN_KV_WRITE):
         return cache.at[layer, pages, offsets].set(rows.astype(cache.dtype))
+
+
+def write_chunk_pages(cache, layer, pages, rows):
+    """One prefill chunk's write for one layer of one lane: ``rows`` ``[C,
+    width]``, ``C`` a multiple of the page size, into the whole pages
+    ``pages`` ``[C / page_size]`` (traced; entries past the lane's own are
+    0, the pad page) of layer ``layer``. A chunk of ONE page goes row by
+    row, as a decode step's write does: the compiler turns a scatter with
+    one index into a ``dynamic-update-slice``, gives it the layout its
+    update was computed in, and re-lays the whole pool out to match (3.7 GB
+    in and out at the A.X-K1 cell's size, past the chip's memory)."""
+    import jax.numpy as jnp
+
+    ps = cache.shape[2]
+    C = rows.shape[0]
+    if C == ps:
+        return append_token_paged(cache, layer, jnp.broadcast_to(pages[0], (C,)),
+                                  jnp.arange(C, dtype=jnp.int32), rows)
+    with region(regions.ATTN_KV_WRITE):
+        return cache.at[layer, pages].set(
+            rows.astype(cache.dtype).reshape(C // ps, ps, -1))
 
 
 def gather_pages(cache, layer, tables):
@@ -336,7 +357,12 @@ class StateLanePool(LanePool):
 # ---------------------------------------------------------- the page pool
 class KVPagePool:
     """Free-list *page* allocator over one device-resident K/V buffer
-    pair shaped ``[layers, num_pages+1, page_size, heads*head_dim]``.
+    pair shaped ``[layers, num_pages+1, page_size, heads*head_dim]``, or,
+    given ``row_width`` and ``arrays``, over that many buffers of rows
+    that wide: a model with latent attention keeps ONE array whose row is
+    a token's latent, K and V at once (``models/axk1.py``; 512 + 64
+    columns padded to 640, whole lanes of 128). The free list, the pad
+    page, ``commit`` and the footprint audit are the same.
 
     The minor dimension is heads and head_dim MERGED. A TPU holds an
     array in tiles of its two minor dimensions (16 sublanes x 128 lanes
@@ -372,7 +398,9 @@ class KVPagePool:
     page-fragmentation watermark."""
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
-                 num_heads: int, head_dim: int, dtype="float32"):
+                 num_heads: Optional[int] = None, head_dim: Optional[int] = None,
+                 dtype="float32", *, row_width: Optional[int] = None,
+                 arrays: int = 2):
         import jax.numpy as jnp
 
         if num_pages < 1:
@@ -380,16 +408,21 @@ class KVPagePool:
         if page_size < 1 or (page_size & (page_size - 1)):
             raise ValueError(
                 f"page_size must be a power of two, got {page_size}")
+        if row_width is None:
+            if num_heads is None or head_dim is None:
+                raise ValueError("KVPagePool needs num_heads and head_dim, "
+                                 "or a row_width")
+            row_width = int(num_heads) * int(head_dim)
         self.num_layers = int(num_layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        self.num_heads = None if num_heads is None else int(num_heads)
+        self.head_dim = None if head_dim is None else int(head_dim)
+        self.row_width = int(row_width)
         # +1: page 0 is the pad page — never allocated, absorbs garbage
         shape = (self.num_layers, self.num_pages + 1, self.page_size,
-                 self.num_heads * self.head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+                 self.row_width)
+        self._held = [jnp.zeros(shape, dtype) for _ in range(int(arrays))]
         # low page ids hand out first: pop() from the tail
         self._free: List[int] = list(range(self.num_pages, 0, -1))
         self._lock = named_lock("serving.kv_pool")
@@ -398,6 +431,23 @@ class KVPagePool:
         self._util_min = 1.0
         self._util_samples = 0
         self._gauge_occupancy()
+
+    @property
+    def k(self):
+        """The first array: the keys, where the pool holds K and V apart."""
+        return self._held[0]
+
+    @k.setter
+    def k(self, array):
+        self._held[0] = array
+
+    @property
+    def v(self):
+        return self._held[1]
+
+    @v.setter
+    def v(self, array):
+        self._held[1] = array
 
     # ------------------------------------------------------------ pages
     @property
@@ -449,29 +499,30 @@ class KVPagePool:
     # ------------------------------------------------------------ buffers
     def arrays(self) -> tuple:
         """The device arrays a program call takes and gives back."""
-        return self.k, self.v
+        return tuple(self._held)
 
-    def commit(self, new_k, new_v) -> None:
+    def commit(self, *new) -> None:
         """Swap in the post-step buffers — same contract as
         :meth:`KVSlotPool.commit`: footprint pinned, ``kv.commit``
-        fault rejects BEFORE assignment, numerics witness on keys."""
+        fault rejects BEFORE assignment, numerics witness on the first
+        array (the keys, or the rows that are K and V at once)."""
         from ..reliability.faults import fault_point
 
         fault_point("kv.commit")
-        if (new_k.shape != self.k.shape or new_v.shape != self.v.shape
-                or new_k.dtype != self.k.dtype):
+        if len(new) != len(self._held) or any(
+                n.shape != h.shape or n.dtype != h.dtype
+                for n, h in zip(new, self._held)):
             raise ValueError(
                 f"KV commit changed the pool footprint: "
-                f"{self.k.shape}/{self.k.dtype} -> "
-                f"{new_k.shape}/{new_k.dtype}")
-        self.k = new_k
-        self.v = new_v
+                f"{[(h.shape, h.dtype) for h in self._held]} -> "
+                f"{[(n.shape, n.dtype) for n in new]}")
+        self._held = list(new)
         from ..observability import numerics
 
-        numerics.watch("serving.kv_commit", new_k)
+        numerics.watch("serving.kv_commit", new[0])
 
     def device_bytes(self) -> int:
-        return int(self.k.nbytes) + int(self.v.nbytes)
+        return sum(int(a.nbytes) for a in self._held)
 
     def mark_warm(self) -> None:
         """Freeze the footprint baseline (end of engine warmup): any

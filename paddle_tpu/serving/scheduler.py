@@ -271,6 +271,7 @@ class DecodeScheduler:
         self._pending: List[object] = []        # slot held, prefill due
         self._step_lanes: List[object] = []     # lanes riding the current call
         self._owe_decode = False  # a prompt's chunk just ran: the lanes decode next
+        self._step_extra = None   # a program's outputs beside its tokens, last call
         self.shed_count = 0
         self._beat = 0            # running index of scheduler beats
         self._beat_kind = "idle"  # what this beat ran (set by the step)
@@ -376,7 +377,9 @@ class DecodeScheduler:
         # that prefill in pieces): then the decoding lanes get their beat,
         # so a prompt of many chunks never starves them for its length
         if self._pending and not (self._owe_decode and self._active):
-            self._guarded(self._prefill_step, monitor)
+            self._guarded(self._prefill_chunk_step
+                          if getattr(self.programs, "chunked", False)
+                          else self._prefill_step, monitor)
             return True
         if self._active:
             self._owe_decode = False
@@ -409,10 +412,20 @@ class DecodeScheduler:
         device may still be running), ``serving.read`` is the wait for
         the tokens."""
         with self._span("serving.dispatch", program=program):
-            *arrays, toks = self._program_call(call)
-        self.pool.commit(*arrays)
+            out = self._program_call(call)
+        held = len(self.pool.arrays())
+        self.pool.commit(*out[:held])
+        toks, *more = out[held:]
         with self._span("serving.read", program=program):
-            return np.asarray(toks)
+            if not more:
+                return np.asarray(toks)
+            # what the program says of its step beside the tokens (the
+            # latent family's pair counts), in the same read
+            for a in more:
+                a.copy_to_host_async()
+            toks = np.asarray(toks)
+            self._step_extra = [np.asarray(a) for a in more]
+            return toks
 
     def _absorb_traced(self, lanes, absorb, *args, **kwargs) -> None:
         """Run one of the absorb methods under ``serving.absorb``."""
@@ -521,8 +534,6 @@ class DecodeScheduler:
     def _prefill_step(self) -> None:
         from ..jit.bucketing import bucket_for
 
-        if getattr(self.programs, "chunked", False):
-            return self._prefill_chunk_step()
         with self._span("serving.build", lanes=0, rung=None) as sp:
             rung = self._pending[0].seq_rung  # oldest request anchors the rung
             group = [r for r in self._pending
@@ -552,13 +563,15 @@ class DecodeScheduler:
 
     def _prefill_chunk_step(self) -> None:
         """One chunk of the OLDEST pending request's prompt, for programs
-        that prefill in pieces (``programs.chunked``: a recurrent state
-        carries what came before). The request keeps a cursor; a whole
-        chunk takes the ladder's top rung, the ragged last one the
-        smallest rung that holds it; the first chunk tells the program
-        that the lane is fresh (its old state is ignored: joining a lane
-        is zeroing it); the last one yields the first token. Between two
-        chunks the decoding lanes get their beat (:meth:`_step`)."""
+        that prefill in pieces (``programs.chunked``: a recurrent state, or
+        the pages before the cursor, carry what came before). The request
+        keeps a cursor; a whole chunk takes the ladder's top rung, the
+        ragged last one the smallest rung that holds it; the last one
+        yields the first token. What the program is told of the lane is
+        the residency's (:meth:`_chunk_args`: a state lane and whether it
+        is fresh, or a block table, grown to hold the chunk first).
+        Between two chunks the decoding lanes get their beat
+        (:meth:`_step`)."""
         from ..jit.bucketing import bucket_for
         from ..observability.metrics import registry
 
@@ -573,17 +586,20 @@ class DecodeScheduler:
             self._step_lanes = [r]  # the fault wall's blast radius
             tokens = np.zeros((1, rung), np.int32)
             tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
-            args = (tokens, np.asarray([n], np.int32),
-                    np.asarray([r.slot], np.int32),
-                    np.asarray([r.cursor], np.int32),
-                    np.asarray([r.cursor == 0], np.int32))
+            args = self._chunk_args(r, n)
+            if args is None:
+                # the chunk waits (for pages): the decoding lanes go first
+                self._step_lanes = []
+                self._owe_decode = True
+                return
             if sp.id is not None:
                 sp.args.update(lanes=1, rung=(1, rung))
         t0 = time.perf_counter()
         with self._step_span("prefill", (1, rung), [r], chunk=r.cursor // top,
-                             chunks=-(-size // top), tokens=n):
+                             chunks=-(-size // top), tokens=n) as sp:
             toks = self._call_and_read("prefill", lambda: self.programs.prefill(
-                *self.pool.arrays(), *args))
+                *self.pool.arrays(), tokens, np.asarray([n], np.int32), *args))
+            self._note_extra(sp, n)
         r.cursor += n
         self._owe_decode = True
         registry.counter(
@@ -601,6 +617,26 @@ class DecodeScheduler:
                 if self.stats is not None:
                     self.stats.record_decode_step(
                         "prefill", time.perf_counter() - t0, 1, 0)
+
+    def _chunk_args(self, r, n: int):
+        """What a chunk's program call takes after the tokens and their
+        count, for ``n`` tokens at the request's cursor: here the state
+        lane, the cursor, and whether the lane is fresh (the first chunk
+        tells the program to ignore the lane's old state: joining a lane
+        is zeroing it). None would mean that the chunk cannot run yet."""
+        return (np.asarray([r.slot], np.int32),
+                np.asarray([r.cursor], np.int32),
+                np.asarray([r.cursor == 0], np.int32))
+
+    def _note_extra(self, sp, tokens: int) -> None:
+        """Hand what the last program call returned beside its tokens to
+        the programs (``note_step``: counters, and what the step's span
+        ``sp`` says of it). Nothing for programs that return tokens only."""
+        extra, self._step_extra = self._step_extra, None
+        if extra:
+            said = self.programs.note_step(*extra, tokens=tokens)
+            if sp.id is not None:
+                sp.args.update(said)
 
     def _decode_step(self) -> None:
         from ..jit.bucketing import bucket_for
@@ -737,7 +773,13 @@ class PagedDecodeScheduler(DecodeScheduler):
         # merely has to wait for a retirement stays queued (FIFO,
         # never shed); growth past the prompt is overcommitted by
         # design and sheds only on true mid-flight exhaustion
-        budget = [self.pool.free_count()]
+        # programs that prefill in pieces take their pages chunk by chunk
+        # (`_chunk_args`); what the pending prompts still lack is theirs
+        # already, so that a prompt admitted now finds its pages later
+        chunked = getattr(self.programs, "chunked", False)
+        owed = sum(max(-(-int(r.prompt.size) // self.pool.page_size)
+                       - len(r.pages), 0) for r in self._pending)
+        budget = [self.pool.free_count() - owed]
 
         def fits(r):
             need = -(-int(r.prompt.size) // self.pool.page_size)
@@ -752,14 +794,41 @@ class PagedDecodeScheduler(DecodeScheduler):
         for r in taken:
             r.seq_rung = self._seq_rung(r)
             r.t_dispatch = now
-            need = -(-int(r.prompt.size) // self.pool.page_size)
+            need = 0 if chunked else -(-int(r.prompt.size) // self.pool.page_size)
             try:
-                r.pages = self.pool.alloc(need)
+                r.pages = self.pool.alloc(need) if need else []
             except Exception as e:  # noqa: BLE001 — shed, don't crash
                 self._shed(r, e)
                 continue
             self._pending.append(r)
         return len(taken)
+
+    def _chunk_args(self, r, n: int):
+        """The lane's block table, grown first to hold the chunk's ``n``
+        tokens at the cursor, the cursor, and the sampling arguments. An
+        injected ``kv.page_alloc`` fault sheds the request; natural
+        pressure leaves it at the head of the pending list and lets the
+        decoding lanes run (a retirement frees pages); with no lane able
+        to step none can come, and the request is shed."""
+        from ..reliability.faults import FaultInjection
+
+        need = -(-(r.cursor + n) // self.pool.page_size)
+        try:
+            if need > len(r.pages):
+                r.pages.extend(self.pool.alloc(need - len(r.pages)))
+        except FaultInjection as e:
+            self._shed(r, e)
+            return None
+        except Exception as e:  # noqa: BLE001 — natural pressure: wait
+            if len(self._starved) < len(self._active):
+                self._pending.insert(0, r)
+            else:   # no lane can step, so no retirement will come
+                self._shed(r, e)
+            return None
+        tables = np.zeros((1, self.programs.table_rungs[-1]), np.int32)
+        tables[0, :len(r.pages)] = r.pages
+        return (tables, np.asarray([r.cursor], np.int32),
+                *self._sample_args([r], 1))
 
     def _shed(self, r, cause) -> None:
         """Page-allocation failure sheds ONE request: its pages return
@@ -954,10 +1023,11 @@ class PagedDecodeScheduler(DecodeScheduler):
             return
         lanes, rung, tokens, tables, positions, pages = built
         t0 = time.perf_counter()
-        with self._step_span("decode", rung, lanes, **pages):
+        with self._step_span("decode", rung, lanes, **pages) as sp:
             toks = self._call_and_read("decode", lambda: self.programs.decode(
-                self.pool.k, self.pool.v, tokens, tables, positions,
+                *self.pool.arrays(), tokens, tables, positions,
                 *self._sample_args(lanes, rung[0])))
+            self._note_extra(sp, len(lanes))
         self._absorb_traced(lanes, self._absorb, toks, kind="decode",
                             seconds=time.perf_counter() - t0, rung=rung)
 

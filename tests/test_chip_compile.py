@@ -154,6 +154,65 @@ def test_the_pool_is_updated_in_place_beside_the_kernel_that_reads_it(
     assert m.temp_size_in_bytes < 16 << 20
 
 
+# ------------------------------------------------ latent pages (A.X-K1, PR 34)
+@pytest.fixture(scope="module")
+def compiled_latent_layer(topo):
+    """One layer's two halves over the A.X-K1 cell's latent pool (rows of
+    640, 300 pages of 256 a layer here, bf16, whole and donated), compiled
+    for one chip: a ONE-PAGE prefill chunk's rows written
+    (``write_chunk_pages``), then a decode step's 64 rows appended and
+    ``latent_paged_attn`` over a table of 72 with 64 dense q rows a lane."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.base import regions
+    from paddle_tpu.ops.pallas.paged_attention import latent_paged_attention
+    from paddle_tpu.serving import kv_cache as kvc
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(pool, chunk, chunk_pages, q, rows, tables, positions, pages, offsets):
+        pool = kvc.write_chunk_pages(pool, 3, chunk_pages, chunk)
+        pool = kvc.append_token_paged(pool, 3, pages, offsets, rows)
+        with regions.region(regions.ATTN_CORE):
+            o_lat = latent_paged_attention(q, pool, 3, tables, positions,
+                                           v_cols=512, scale=0.1309)
+        return pool, o_lat
+
+    ints = shape((64,), jnp.int32)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(step, donate_argnums=0).lower(
+            shape((7, 301, 256, 640), jnp.bfloat16), shape((256, 640), jnp.bfloat16),
+            shape((1,), jnp.int32), shape((64, 64, 640), jnp.bfloat16),
+            shape((64, 640), jnp.bfloat16), shape((64, 72), jnp.int32), ints, ints,
+            ints).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_latent_attention_compiles_for_v5e_and_leaves_the_pool_where_it_lies(
+        compiled_latent_layer):
+    """The kernel under its name, the pool aliased, and temporaries far
+    under the pool: a one-page chunk written as one scatter had the compiler
+    re-lay the WHOLE pool out (3.7 GB at the cell's size, past the chip)."""
+    calls = _kernel_calls(compiled_latent_layer)
+    assert [n.split(".")[0] for n in calls] == ["latent_paged_attn"]
+    (op_name,) = calls.values()
+    assert "attn/core" in op_name and op_name.endswith("/latent_paged_attn/pallas_call")
+    m = compiled_latent_layer.memory_analysis()
+    assert m.alias_size_in_bytes == 7 * 301 * 256 * 640 * 2
+    assert m.temp_size_in_bytes < 32 << 20
+
+
 def test_a_kernels_cache_key_does_not_depend_on_who_warmed_the_engine(
         topo, monkeypatch):
     """The Mosaic payloads (part of JAX's cache key, unlike HLO metadata)

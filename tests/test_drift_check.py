@@ -71,8 +71,10 @@ def test_committed_lockfile_shape_and_coverage():
                                    "comm_bytes", "peak_bytes",
                                    "guard_preds"}, name
     # the rung grids cover the serving + decode groups
+    assert {n for n in progs if n.startswith("decode/latent:")} == {
+        "decode/latent:decode:2", "decode/latent:prefill:1:8"}
     assert set(lock["rung_grids"]) == {"serving/batch", "decode/paged",
-                                       "decode/state"}
+                                       "decode/state", "decode/latent"}
 
 
 def test_lock_digest_matches_committed_bytes():

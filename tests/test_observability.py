@@ -620,7 +620,10 @@ class TestProgramRegions:
             "attn/layout", "optimizer"}
         assert regions.ROOTS == ("prefill", "decode", "draft", "verify")
         assert regions.KERNELS == ("flash_fwd", "flash_bwd_dq",
-                                   "flash_bwd_dkv", "retn_step", "paged_attn")
+                                   "flash_bwd_dkv", "retn_step", "paged_attn",
+                                   "latent_paged_attn")
+        assert {"attn/latent_proj", "attn/expand", "moe/route", "moe/experts",
+                "moe/shared"} <= set(regions.LATENT_MOE)
         assert {"retn/gate", "retn/chunk", "retn/state", "norm",
                 "rope"} <= set(regions.RETENTION)
         with regions.region(regions.MLP):
