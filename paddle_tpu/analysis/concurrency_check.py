@@ -356,7 +356,7 @@ def _check_class_shared_state(cls: ast.ClassDef, filename: str,
             continue
         info = methods[item.name] = _MethodInfo(item)
         # every `self.X` reference is a closure edge, not just calls:
-        # `self._guarded(self._prefill_step)` passes a method as a
+        # `self._guarded(self._decode_step)` passes a method as a
         # callable and the entry thread still runs it (the closure's
         # `not in methods` guard drops plain data attributes)
         for node in ast.walk(item):

@@ -344,6 +344,36 @@ def _serving_fingerprints(programs, rung_grids) -> None:
     rung_grids["serving/batch"] = [f"b{b}" for b in ladder]
 
 
+def _program_set_fingerprints(progs, family, programs, rung_grids) -> None:
+    """Every rung of one decode program set, retraced abstractly
+    (``make_jaxpr`` over the program bodies with the rungs' own zero-arg
+    templates — zero compiles) under ``<family>:<rung>``: the programs over
+    the pool, and the token carry in front of the decode programs
+    (``("carry", p, b)``: no parameters, no pool, nothing donated)."""
+    import jax
+    import numpy as np
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+
+    donation = tuple(f"arg{i}" for i in progs._donate)
+    held = tuple(sds(a) for a in progs.pool.arrays())
+    grid = []
+    for key in progs.rungs:
+        if key[0] == "carry":
+            closed = jax.make_jaxpr(progs._carry_fn)(
+                *(sds(a) for a in progs._carry_zero_args(key)))
+        else:
+            closed = jax.make_jaxpr(getattr(progs, f"_{key[0]}_fn"))(
+                jax.tree_util.tree_map(sds, progs._call_params(key)), *held,
+                *(sds(a) for a in progs._zero_args(key)))
+        rung = ":".join(str(p) for p in key)
+        grid.append(rung)
+        programs[f"{family}:{rung}"] = fingerprint_jaxpr(
+            closed, donation=() if key[0] == "carry" else donation)
+    rung_grids[family] = sorted(grid)
+
+
 def _decode_fingerprints(programs, rung_grids) -> None:
     """The paged-decode rung grid: every ``("decode", b, t)`` /
     ``("prefill", b, s)`` / ``("draft", b, t)`` / ``("verify", b, t)``
@@ -352,9 +382,6 @@ def _decode_fingerprints(programs, rung_grids) -> None:
     own zero-arg templates — zero compiles). Speculation rungs use
     ``speculate_k=2`` with a full-depth (1-layer) draft — the same
     degenerate-draft shape the demo decode engine audits."""
-    import jax
-    import numpy as np
-
     import paddle_tpu as paddle
     from ..models.gpt import GPTForCausalLM, gpt_tiny
     from ..serving.decode import PagedDecodePrograms
@@ -371,33 +398,13 @@ def _decode_fingerprints(programs, rung_grids) -> None:
                                 prefill_batch_rungs=[1, 2],
                                 decode_rungs=[1, 2], max_seq=16,
                                 speculate_k=2, draft_layers=1)
-
-    def sds(a):
-        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
-
-    donation = tuple(f"arg{i}" for i in progs._donate)
-    fns = {"decode": progs._decode_fn, "prefill": progs._prefill_fn,
-           "draft": progs._draft_fn, "verify": progs._verify_fn}
-    grid = []
-    for key in progs.rungs:
-        arg_sds = tuple(sds(a) for a in progs._zero_args(key))
-        params_sds = jax.tree_util.tree_map(sds, progs._call_params(key))
-        closed = jax.make_jaxpr(fns[key[0]])(params_sds, sds(pool.k),
-                                             sds(pool.v), *arg_sds)
-        rung = ":".join(str(p) for p in key)
-        grid.append(rung)
-        programs[f"decode/paged:{rung}"] = fingerprint_jaxpr(
-            closed, donation=donation)
-    rung_grids["decode/paged"] = sorted(grid)
+    _program_set_fingerprints(progs, "decode/paged", programs, rung_grids)
 
 
 def _retention_fingerprints(programs, rung_grids) -> None:
     """The state-lane residency's representatives: one prefill chunk and
     one decode rung of a 1-layer tiny Brumby over a StateLanePool (the jnp
     decode path: the Pallas kernel is a TPU's), retraced abstractly."""
-    import jax
-    import numpy as np
-
     import paddle_tpu as paddle
     from ..models.brumby import BrumbyForCausalLM, brumby_tiny
     from ..serving.decode import RetentionPrograms
@@ -413,22 +420,7 @@ def _retention_fingerprints(programs, rung_grids) -> None:
                          head_dim=16, max_seq=32)
     progs = RetentionPrograms(model, pool, seq_ladder=[8],
                               prefill_batch_rungs=[1], decode_rungs=[2])
-
-    def sds(a):
-        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
-
-    donation = tuple(f"arg{i}" for i in progs._donate)
-    fns = {"decode": progs._decode_fn, "prefill": progs._prefill_fn}
-    grid = []
-    for key in progs.rungs:
-        closed = jax.make_jaxpr(fns[key[0]])(
-            jax.tree_util.tree_map(sds, progs.params), sds(pool.state),
-            *(sds(a) for a in progs._zero_args(key)))
-        rung = ":".join(str(p) for p in key)
-        grid.append(rung)
-        programs[f"decode/state:{rung}"] = fingerprint_jaxpr(
-            closed, donation=donation)
-    rung_grids["decode/state"] = sorted(grid)
+    _program_set_fingerprints(progs, "decode/state", programs, rung_grids)
 
 
 def _latent_fingerprints(programs, rung_grids) -> None:
@@ -436,9 +428,6 @@ def _latent_fingerprints(programs, rung_grids) -> None:
     one decode rung of a tiny A.X-K1 (one dense layer, one sparse, a quarter
     of the experts held) over a one-array KVPagePool (the jnp decode path:
     the Pallas kernel is a TPU's), retraced abstractly."""
-    import jax
-    import numpy as np
-
     import paddle_tpu as paddle
     from ..models.axk1 import AXK1ForCausalLM, axk1_tiny
     from ..serving.decode import LatentPrograms
@@ -454,22 +443,7 @@ def _latent_fingerprints(programs, rung_grids) -> None:
                       arrays=1)
     progs = LatentPrograms(model, pool, seq_ladder=[8], decode_rungs=[2],
                            max_seq=32)
-
-    def sds(a):
-        return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
-
-    donation = tuple(f"arg{i}" for i in progs._donate)
-    fns = {"decode": progs._decode_fn, "prefill": progs._prefill_fn}
-    grid = []
-    for key in progs.rungs:
-        closed = jax.make_jaxpr(fns[key[0]])(
-            jax.tree_util.tree_map(sds, progs.params), sds(pool.k),
-            *(sds(a) for a in progs._zero_args(key)))
-        rung = ":".join(str(p) for p in key)
-        grid.append(rung)
-        programs[f"decode/latent:{rung}"] = fingerprint_jaxpr(
-            closed, donation=donation)
-    rung_grids["decode/latent"] = sorted(grid)
+    _program_set_fingerprints(progs, "decode/latent", programs, rung_grids)
 
 
 def _qpsum_fingerprint(programs) -> None:

@@ -18,7 +18,8 @@ fragments. Three pieces:
   and a ``parent`` (the innermost span open on its thread, or the one
   ``emit(parent=)`` names). The decode scheduler's beat is one
   ``serving.beat`` span tiled by ``serving.admit`` / ``serving.build`` /
-  ``serving.decode`` (holding ``serving.dispatch`` and ``serving.read``)
+  ``serving.decode`` (holding ``serving.dispatch`` and ``serving.read``,
+  the read being of the call dispatched a beat earlier: ``of_beat``)
   / ``serving.absorb``; a request's phases are ``serving.request.queue``
   / ``.prefill`` (or ``.failed``) sharing ``request=<id>``, from its
   ``t_first_token`` stamp. On the device side the programs name their

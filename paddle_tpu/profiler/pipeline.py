@@ -152,6 +152,11 @@ class ServingStats:
                 # block-table entries of the paged decode steps, and those
                 # of them that named a page with a visible column
                 "pages_live": 0, "pages_table": 0,
+                # reads of a call's tokens: after the next call went out
+                # (the device kept working) or with nothing behind them
+                # (a flush); lanes whose input token came from the device
+                "reads_overlapped": 0, "reads_flushed": 0,
+                "lanes_carried": 0,
                 "tokens": 0, "t_first": None, "t_last": None,
                 "occ_sum": 0, "occ_samples": 0, "occ_peak": 0,
                 "slots": 0,
@@ -254,6 +259,20 @@ class ServingStats:
         with self._lock:
             self._decode["pages_live"] += int(pages_live)
             self._decode["pages_table"] += int(pages_table)
+
+    def record_read(self, overlapped: bool, lanes_carried: int = 0):
+        """The decode scheduler read one call's tokens. ``overlapped``: it
+        had dispatched the next call first (the read was of the call
+        before, and the device went on working); else the beat waited the
+        read out with nothing queued behind it (nothing to dispatch, a
+        drain, or a speculation round, counted once a round).
+        ``lanes_carried`` lanes of that next call took their input token
+        from the unread call's output on the device. The share of reads
+        that overlapped says how often the host's round trip is hidden."""
+        with self._lock:
+            cell = self._decode
+            cell["reads_overlapped" if overlapped else "reads_flushed"] += 1
+            cell["lanes_carried"] += int(lanes_carried)
 
     def record_spec_round(self, proposed: int, accepted: int,
                           committed: int):
@@ -376,6 +395,13 @@ class ServingStats:
             "pages_live_share": (round(cell["pages_live"]
                                        / cell["pages_table"], 4)
                                  if cell["pages_table"] else None),
+            "reads_overlapped": cell["reads_overlapped"],
+            "reads_flushed": cell["reads_flushed"],
+            "reads_overlapped_share": (
+                round(cell["reads_overlapped"]
+                      / (cell["reads_overlapped"] + cell["reads_flushed"]), 4)
+                if cell["reads_overlapped"] + cell["reads_flushed"] else None),
+            "lanes_carried": cell["lanes_carried"],
             "prefill_p50_ms": pct(prefill, 0.50),
             "prefill_p99_ms": pct(prefill, 0.99),
             "decode_p50_ms": pct(decode, 0.50),

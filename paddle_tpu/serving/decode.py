@@ -194,6 +194,7 @@ class DecodePrograms:
                                     donate_argnums=self._donate)
         self._jit_decode = jax.jit(self._decode_fn,
                                    donate_argnums=self._donate)
+        self._jit_carry = jax.jit(self._carry_fn)
 
     #: programs that prefill a prompt in pieces set this; the scheduler
     #: then keeps a cursor per pending request (one chunk a beat)
@@ -329,15 +330,45 @@ class DecodePrograms:
                 next_tok = jnp.argmax(head, axis=-1).astype(jnp.int32)
             return ck, cv, next_tok
 
+    def _carry_fn(self, prev, tokens):
+        """A decode call's input tokens, for lanes whose newest token the
+        host has not read: ``tokens`` ``[B]`` holds a token id where the
+        host knows it and ``-1 - row`` where it is row ``row`` of ``prev``
+        ``[P]``, the token output of the call before (any family's prefill
+        or decode, still on the device). The scheduler reads a call's
+        tokens one beat late; this is what lets it dispatch the next call
+        first. One small program in front of the decode programs, whose
+        bodies it leaves alone."""
+        import jax.numpy as jnp
+
+        self.traces += 1
+        with region(regions.DECODE), region(regions.EMBED):
+            rows = jnp.clip(-1 - tokens, 0, prev.shape[0] - 1)
+            return jnp.where(tokens >= 0, tokens, prev[rows])
+
     # ------------------------------------------------------------- rungs
     @property
     def rungs(self) -> List[tuple]:
         """Every specialization warmup arms: ``("decode", b)`` per batch
-        rung plus ``("prefill", b, s)`` over the (batch x seq) grid."""
+        rung plus ``("prefill", b, s)`` over the (batch x seq) grid, and
+        the token carry's (:attr:`carry_rungs`)."""
         out = [("decode", b) for b in self.decode_rungs]
         out += [("prefill", b, s) for b in self.prefill_batch_rungs
                 for s in self.seq_ladder]
-        return out
+        return out + self.carry_rungs
+
+    @property
+    def carry_rungs(self) -> List[tuple]:
+        """``("carry", p, b)``: a decode call of batch rung ``b`` fed from
+        a call whose token output has ``p`` rows, which is any decode rung
+        or any prefill batch rung: every pair the scheduler can meet."""
+        rows = sorted(set(self.decode_rungs) | set(self.prefill_batch_rungs))
+        return [("carry", p, b) for p in rows for b in self.decode_rungs]
+
+    @staticmethod
+    def _carry_zero_args(key):
+        _, p, b = key
+        return np.zeros(p, np.int32), np.zeros(b, np.int32)
 
     def _zero_args(self, key):
         pad = self.pool.pad_slot
@@ -350,7 +381,8 @@ class DecodePrograms:
                 np.full(b, pad, np.int32))
 
     def _jitted(self, key):
-        return self._jit_decode if key[0] == "decode" else self._jit_prefill
+        return {"decode": self._jit_decode, "prefill": self._jit_prefill,
+                "carry": self._jit_carry}[key[0]]
 
     def warmup(self) -> List[tuple]:
         """Arm every rung with one traced call. Idempotent per rung."""
@@ -363,6 +395,9 @@ class DecodePrograms:
         return list(self.warmed)
 
     def _warm(self, key) -> None:
+        if key[0] == "carry":   # no pool, no parameters
+            self._jit_carry(*self._carry_zero_args(key))
+            return
         args = self._zero_args(key)
         # one traced call against the pad slot (harmless writes land in
         # the trash slot); outputs are committed so a donation backend
@@ -431,6 +466,11 @@ class DecodePrograms:
     def decode(self, ck, cv, tokens, slot_ids, positions):
         return self._jit_decode(self.params, ck, cv, tokens, slot_ids,
                                 positions)
+
+    def carry(self, prev, tokens):
+        """:meth:`_carry_fn` of the unread call's tokens ``prev`` (on the
+        device) and the next decode call's ``tokens`` (host)."""
+        return self._jit_carry(prev, tokens)
 
 
 class PagedDecodePrograms(DecodePrograms):
@@ -815,7 +855,7 @@ class PagedDecodePrograms(DecodePrograms):
                     for t in self.table_rungs]
         out += [("prefill", b, s) for b in self.prefill_batch_rungs
                 for s in self.seq_ladder]
-        return out
+        return out + self.carry_rungs
 
     def _zero_args(self, key):
         def sample_args(b):
@@ -1083,7 +1123,8 @@ class RetentionPrograms(DecodePrograms):
     @property
     def rungs(self) -> List[tuple]:
         return ([("decode", b) for b in self.decode_rungs]
-                + [("prefill", 1, c) for c in self.seq_ladder])
+                + [("prefill", 1, c) for c in self.seq_ladder]
+                + self.carry_rungs)
 
     def _zero_args(self, key):
         pad = self.pool.pad_slot
@@ -1430,7 +1471,8 @@ class LatentPrograms(PagedDecodePrograms):
     @property
     def rungs(self) -> List[tuple]:
         return ([("decode", b) for b in self.decode_rungs]
-                + [("prefill", 1, c) for c in self.seq_ladder])
+                + [("prefill", 1, c) for c in self.seq_ladder]
+                + self.carry_rungs)
 
     def _zero_args(self, key):
         t = self.table_rungs[-1]
@@ -1484,11 +1526,13 @@ class DecodeEngine(EngineBase):
     (eval mode; its device weights are shared zero-copy with
     training/export users).
     Requests (:meth:`submit`) join the running batch at the next step
-    boundary and leave the step they finish — the scheduler runs ONE
-    prefill-or-decode program call per step against the warmed rung
-    set, so ``compiles_after_warmup == 0`` holds under any mix of
-    prefill and decode traffic (JX330), the KV pool footprint never
-    moves after warmup (JX332), and greedy tokens are bit-exact with a
+    boundary and leave when their last token is read, one beat after the
+    step that made it — the scheduler dispatches ONE prefill-or-decode
+    program call per beat against the warmed rung set (and reads the call
+    before: :class:`~.scheduler.DecodeScheduler`), so
+    ``compiles_after_warmup == 0`` holds under any mix of prefill and
+    decode traffic (JX330), the KV pool footprint never moves after
+    warmup (JX332), and greedy tokens are bit-exact with a
     single-request decode of the same prompt.
 
     What a sequence holds on the device between steps follows from the
@@ -1686,7 +1730,14 @@ class DecodeEngine(EngineBase):
         """Enqueue one generation request; returns the future. The prompt
         must fit the seq ladder; generation stops at ``max_new_tokens``,
         the engine's ``eos_id``, or the ``max_seq`` capacity — whichever
-        comes first.
+        comes first. The scheduler reads a step's tokens one beat late, so
+        a lane may ride ONE program call past its ``eos``: nothing of that
+        call is emitted (the stream ends with the ``eos`` token, exactly
+        as a loop that read every step at once would end it), and what the
+        call wrote lies past the sequence's last visible position in pages
+        (or a slot, or a state lane) the request gives back at retirement.
+        A stop by length is known before the token is read; such a lane
+        rides no call past its last.
 
         ``temperature == 0`` (default) decodes greedily — the bit-exact
         audit mode. A positive temperature samples with optional top-k /
@@ -1739,8 +1790,9 @@ class DecodeEngine(EngineBase):
         return self.submit(tenant, prompt, max_new_tokens).result(timeout)
 
     def active_requests(self) -> int:
-        """Sequences currently holding a slot (decoding or awaiting
-        prefill) — the JX333 slot-leak audit's liveness source."""
+        """Sequences currently holding a slot (decoding, awaiting
+        prefill, or waiting for their last token to be read) — the JX333
+        slot-leak audit's liveness source."""
         return self._scheduler.active_count()
 
     def set_speculation(self, enabled: bool) -> bool:

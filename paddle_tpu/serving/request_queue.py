@@ -127,10 +127,10 @@ class DecodeRequest(Request):
     ``max_new_tokens`` or the engine's EOS). ``n`` is 1 — admission is
     denominated in slots for the decode tier."""
 
-    __slots__ = ("prompt", "max_new_tokens", "generated", "slot", "seq_rung",
-                 "cursor", "pages", "temperature", "top_k", "top_p", "seed",
-                 "speculate", "spec_live", "spec_proposed", "spec_accepted",
-                 "t_first_token")
+    __slots__ = ("prompt", "max_new_tokens", "generated", "sent", "row",
+                 "slot", "seq_rung", "cursor", "pages", "temperature", "top_k",
+                 "top_p", "seed", "speculate", "spec_live", "spec_proposed",
+                 "spec_accepted", "t_first_token")
 
     def __init__(self, tenant: str, prompt, max_new_tokens: int,
                  temperature: float = 0.0, top_k: int = 0,
@@ -143,6 +143,14 @@ class DecodeRequest(Request):
         self.prompt = prompt
         self.max_new_tokens = max(int(max_new_tokens), 1)
         self.generated: List[int] = []
+        # tokens SENT FOR: program calls dispatched that emit a token of
+        # this request. The scheduler reads a call's tokens one beat late,
+        # so ``len(generated) <= sent <= len(generated) + 1``; while they
+        # differ the newest token is still on the device, in row ``row``
+        # of the unread call's output. The write position and the PRNG
+        # key index count ``sent``, not what the host has read.
+        self.sent = 0
+        self.row = 0
         # host time (perf_counter) at which the first entry of
         # ``generated`` reached the host (the prefill beat's stamp):
         # t_enqueue <= t_dispatch <= t_first_token <= t_complete.
@@ -173,8 +181,9 @@ class DecodeRequest(Request):
     def position(self) -> int:
         """The next KV write position: prompt rows 0..len-1 land at
         prefill; generated token ``i`` (the input of decode step ``i+1``)
-        writes at ``len + i``."""
-        return int(self.prompt.size) + max(len(self.generated) - 1, 0)
+        writes at ``len + i``. Counted in tokens sent for: the newest may
+        not have reached the host yet."""
+        return int(self.prompt.size) + max(self.sent - 1, 0)
 
 
 class AdmissionController:

@@ -15,6 +15,7 @@ one background thread that loops take → stack → execute → scatter.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
@@ -235,14 +236,67 @@ class Scheduler:
         return not self._thread.is_alive()
 
 
+class _Call:
+    """One program call of the decode loop, from the beat that builds and
+    dispatches it to the beat after, which reads its tokens: the lanes
+    that ride it (row ``i`` of its token output is ``lanes[i]``'s), the
+    call itself (``fn``, run under the fault point), and what its
+    ``serving.decode`` span says of it. Dispatched, it holds its outputs,
+    still on the device (``toks``, and ``extra``: what the program says of
+    its step beside the tokens)."""
+
+    __slots__ = ("kind", "rung", "lanes", "fn", "emits", "rows", "carried",
+                 "says", "beat", "toks", "extra")
+
+    def __init__(self, kind: str, rung, lanes, fn, *, emits: bool = True,
+                 rows: Optional[int] = None, carried: int = 0, **says):
+        self.kind = kind          # the program: prefill, decode, draft, verify
+        self.rung = rung
+        self.lanes = lanes
+        self.fn = fn
+        self.emits = emits        # False: a chunk that is not its prompt's last
+        self.rows = len(lanes) if rows is None else rows  # cache rows it writes a layer
+        self.carried = carried    # lanes whose input token is still on the device
+        self.says = says          # the step span's arguments beside kind/rung/lanes
+        self.beat = None
+        self.toks = None
+        self.extra = ()
+
+
 class DecodeScheduler:
     """The decode tier's one executor thread: true continuous batching
-    over a KV slot pool. Every loop iteration is ONE program call —
-    prefill OR decode — and between any two calls requests JOIN (queued →
-    freed slot, priority order) and LEAVE (finished → slot released,
-    future resolved). No full-batch re-assembly ever happens: running
-    sequences keep their device-resident KV rows and simply appear in the
-    next step's gathered lane set.
+    over a KV slot pool. Every loop iteration (a *beat*) dispatches ONE
+    program call — prefill OR decode — and between any two calls requests
+    JOIN (queued → freed slot, priority order) and LEAVE (finished → slot
+    released, future resolved). No full-batch re-assembly ever happens:
+    running sequences keep their device-resident KV rows and simply appear
+    in the next step's gathered lane set.
+
+    **A call's tokens are read one beat late.** A beat builds call N from
+    what the host knows plus what is still on the device, dispatches it,
+    and only then reads call N-1's tokens and absorbs them: the device
+    finished N-1 while the host built N, and N is queued behind it, so
+    the chip goes from call to call without waiting out the host's round
+    trip. One call is in flight at most (``_flight``). What that takes:
+
+    - a lane that rode N-1 and rides N takes its input token from N-1's
+      output on the device (``programs.carry``: the host says which row);
+    - the host counts ahead of what it has read (``DecodeRequest.sent``:
+      write positions and PRNG key indices count tokens *sent for*). A
+      lane whose token in flight is its last by length is not put into
+      call N; it retires when that token is absorbed, a beat later;
+    - ``eos`` is learned late: a lane whose token from N-1 is ``eos_id``
+      has ridden N. It retires at N-1's absorb; its row of N is dropped at
+      N's absorb and never emitted. What N wrote for it lies in its own
+      pages (or slot, or state lane) past its last visible position, and
+      the device runs calls in order, so the next owner's prefill
+      overwrites it (a state lane's next owner starts ``fresh``).
+
+    A beat reads with nothing dispatched behind the read (a *flush*) on
+    three occasions only: there is nothing to dispatch (the last lanes'
+    last tokens: the read does not wait for the queue's timeout), the
+    loop drains for shutdown, or the next step is a speculation round,
+    which compares tokens on the host and so reads its own calls.
 
     Step policy: prefill-first. A waiting prompt joins the batch at the
     very next boundary (its compute also emits its first token), then
@@ -251,8 +305,11 @@ class DecodeScheduler:
     grouping never starves FIFO order across rungs) and are capped at
     ``prefill_max_batch`` lanes.
 
-    Crashes in a program call fail only the lanes that rode it — their
-    slots release, the loop survives and keeps serving."""
+    A call that crashes at dispatch fails only the lanes that rode it —
+    their slots release, the unread call before it is still absorbed for
+    the lanes that survive, the loop keeps serving. An error that
+    surfaces at the read fails the lanes of the call read and of the call
+    queued behind it."""
 
     def __init__(self, queue: RequestQueue, programs, pool, *,
                  prefill_max_batch: int, eos_id: Optional[int] = None,
@@ -261,17 +318,18 @@ class DecodeScheduler:
         self.queue = queue
         self.programs = programs
         self.pool = pool
+        self.max_seq = int(getattr(programs, "max_seq", 0) or pool.max_seq)
         self.prefill_max_batch = max(int(prefill_max_batch), 1)
         self.eos_id = eos_id
         self.stats = stats
         self.on_step = on_step           # (kind, lanes, rung, emitted) tap
         self.retry = retry               # replays a transient program call
         self.breakers = breakers         # per-tenant degraded accounting
-        self._active: Dict[int, object] = {}    # slot -> DecodeRequest
+        self._active: Dict[int, object] = {}    # lanes the next decode call takes
         self._pending: List[object] = []        # slot held, prefill due
-        self._step_lanes: List[object] = []     # lanes riding the current call
+        self._flight: Optional[_Call] = None    # dispatched, its tokens unread
+        self._step_lanes: List[object] = []     # the fault wall's blast radius
         self._owe_decode = False  # a prompt's chunk just ran: the lanes decode next
-        self._step_extra = None   # a program's outputs beside its tokens, last call
         self.shed_count = 0
         self._beat = 0            # running index of scheduler beats
         self._beat_kind = "idle"  # what this beat ran (set by the step)
@@ -299,13 +357,18 @@ class DecodeScheduler:
         self._thread.join(timeout)
         return not self._thread.is_alive()
 
+    def _holding(self) -> List[object]:
+        """The unresolved requests that hold a slot (or pages) right now:
+        decoding, awaiting prefill, or riding a call not yet absorbed."""
+        flight = self._flight
+        held = {id(r): r for r in (*self._active.values(), *self._pending,
+                                   *self._step_lanes,
+                                   *(flight.lanes if flight is not None else ()))}
+        return [r for r in held.values() if not r.done()]
+
     def active_count(self) -> int:
-        """Sequences holding a slot right now (active, awaiting prefill,
-        or riding the in-flight program call)."""
-        seen = {id(r) for r in self._active.values()}
-        seen.update(id(r) for r in self._pending)
-        seen.update(id(r) for r in self._step_lanes)
-        return len(seen)
+        """Sequences holding a slot right now (:meth:`_holding`)."""
+        return len(self._holding())
 
     # ------------------------------------------------------------ the loop
     def _loop(self) -> None:
@@ -316,7 +379,8 @@ class DecodeScheduler:
             stepped = self._admit_and_step(monitor)
             if not stepped:
                 if (self.queue.closed and len(self.queue) == 0
-                        and not self._active and not self._pending):
+                        and not self._active and not self._pending
+                        and self._flight is None):
                     break
             else:
                 # step-boundary memory telemetry (sync-free by contract)
@@ -325,15 +389,23 @@ class DecodeScheduler:
 
     def _admit_and_step(self, monitor) -> bool:
         """One scheduler beat: admit queued requests into free slots,
-        then run one prefill-or-decode call. Returns False when fully
-        idle (nothing admitted, nothing to step).
+        build and dispatch one prefill-or-decode call, then read and
+        absorb the call of the beat BEFORE (one call in flight, read one
+        beat late; a beat with nothing to dispatch reads at once, and a
+        speculation round reads its own calls: the class docstring).
+        Returns False when fully idle (nothing admitted, nothing to
+        dispatch, nothing in flight).
 
         Traced (ONE ``tracer.enabled`` read per beat), a beat is one
         ``serving.beat`` span (``beat`` = running index, ``kind`` =
         prefill/decode/speculate/idle) whose children tile it:
         ``serving.admit`` -> ``serving.build`` -> ``serving.decode``
-        (holding ``serving.dispatch`` and ``serving.read``) ->
-        ``serving.absorb``."""
+        (holding ``serving.dispatch`` and then ``serving.read``, which
+        names the beat whose call it reads: ``of_beat``) ->
+        ``serving.absorb`` (of that call). ``kind``, ``rung``, ``lanes``
+        and the rest of ``serving.decode`` describe the call dispatched
+        in it; a beat that only reads takes the kind of the call it
+        reads, with no lanes."""
         from ..observability.tracing import tracer
 
         self._beat += 1
@@ -357,13 +429,19 @@ class DecodeScheduler:
                 beat.args["kind"] = self._beat_kind
         return stepped
 
+    def _idle(self) -> bool:
+        """Nothing to do but wait for the queue: no lane decoding, no
+        prefill due, no call in flight (its read must not wait)."""
+        return (not self._active and not self._pending
+                and self._flight is None)
+
     def _admit(self) -> int:
         """Move queued requests into free slots; returns how many."""
         free = self.pool.free_count()
         if free <= 0:
             return 0
-        idle = not self._active and not self._pending
-        taken = self.queue.take_slots(free, timeout=0.05 if idle else 0.0)
+        taken = self.queue.take_slots(
+            free, timeout=0.05 if self._idle() else 0.0)
         now = time.perf_counter()
         for r in taken:
             r.slot = self.pool.alloc()
@@ -377,15 +455,19 @@ class DecodeScheduler:
         # that prefill in pieces): then the decoding lanes get their beat,
         # so a prompt of many chunks never starves them for its length
         if self._pending and not (self._owe_decode and self._active):
-            self._guarded(self._prefill_chunk_step
-                          if getattr(self.programs, "chunked", False)
-                          else self._prefill_step, monitor)
-            return True
-        if self._active:
+            step = functools.partial(
+                self._run, self._build_chunk
+                if getattr(self.programs, "chunked", False)
+                else self._build_prefill)
+        elif self._active:
             self._owe_decode = False
-            self._guarded(self._decode_step, monitor)
-            return True
-        return False
+            step = self._decode_step
+        elif self._flight is not None:
+            step = self._run   # nothing to dispatch: read what is in flight
+        else:
+            return False
+        self._guarded(step, monitor)
+        return True
 
     def _span(self, name: str, **args):
         """A child span of this beat on the scheduler's track; the shared
@@ -406,33 +488,123 @@ class DecodeScheduler:
                           lanes=len(lanes), requests=[r.id for r in lanes],
                           **args)
 
-    def _call_and_read(self, program: str, call):
-        """One program call, its pool commit and the host read of its
-        tokens: ``serving.dispatch`` lasts until the call returns (the
-        device may still be running), ``serving.read`` is the wait for
-        the tokens."""
-        with self._span("serving.dispatch", program=program):
-            out = self._program_call(call)
+    # ------------------------------------------------- the body of a beat
+    def _run(self, build: Optional[Callable] = None) -> None:
+        """What a beat does after admission: build call N (``build``;
+        none, or one that has to wait, dispatches nothing), dispatch it,
+        read call N-1, absorb N-1. The device works on N-1 while the host
+        builds and dispatches N, so the read finds its tokens there, and
+        N already waits behind it. With nothing to dispatch the read is a
+        flush: the device goes idle after it."""
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            call = build() if build is not None else None
+            if call is not None and sp.id is not None:
+                sp.args.update(lanes=len(call.lanes), rung=call.rung)
+        prev = self._flight
+        if call is None and prev is None:
+            return    # the build has to wait, and nothing is in flight
+        t0 = time.perf_counter()
+        # a beat that only reads takes the kind of the call it reads
+        kind, rung, lanes, says = ((call.kind, call.rung, call.lanes, call.says)
+                                   if call is not None else (prev.kind, None, (), {}))
+        with self._step_span(kind, rung, lanes, **says) as sp:
+            self._dispatch(call)
+            if call is not None:
+                if sp.id is not None:
+                    call.says = sp.args   # as recorded: the read completes it
+                self._launch(call)
+            toks = self._read(prev)
+            self._flight = call
+        if prev is not None and self.stats is not None:
+            self.stats.record_read(overlapped=call is not None,
+                                   lanes_carried=call.carried if call else 0)
+        self._absorb_traced(prev, toks, time.perf_counter() - t0)
+
+    def _dispatch(self, call: Optional[_Call]) -> None:
+        """``serving.dispatch``: the program call, until it returns (the
+        device may still be running); then the pool commit, and the copy
+        of its small outputs to the host started, so that the read a beat
+        later is a wait for nothing."""
+        with self._span("serving.dispatch", program=call and call.kind):
+            if call is None:
+                return
+            out = self._program_call(call.fn)
         held = len(self.pool.arrays())
         self.pool.commit(*out[:held])
-        toks, *more = out[held:]
-        with self._span("serving.read", program=program):
-            if not more:
-                return np.asarray(toks)
-            # what the program says of its step beside the tokens (the
-            # latent family's pair counts), in the same read
-            for a in more:
-                a.copy_to_host_async()
-            toks = np.asarray(toks)
-            self._step_extra = [np.asarray(a) for a in more]
-            return toks
+        call.toks, *call.extra = out[held:]
+        call.beat = self._beat
+        for a in out[held:]:
+            a.copy_to_host_async()
 
-    def _absorb_traced(self, lanes, absorb, *args, **kwargs) -> None:
-        """Run one of the absorb methods under ``serving.absorb``."""
+    def _launch(self, call: _Call) -> None:
+        """The host's count of a call just dispatched: each of its lanes
+        has one more token sent for, in row ``i`` of the call's output,
+        and decodes on unless that token is its last by length (then it
+        only waits to be absorbed). A chunk that is not its prompt's last
+        sends for nothing; the request returns to the head of the pending
+        list. While the call before is read, the fault wall covers both:
+        an error surfacing there takes this call, queued behind it, too."""
+        prev = self._flight
+        self._step_lanes = call.lanes if prev is None else prev.lanes + call.lanes
+        if not call.emits:
+            self._pending[:0] = call.lanes
+            return
+        for i, r in enumerate(call.lanes):
+            r.sent += 1
+            r.row = i
+            if self._last_by_length(r):
+                self._active.pop(self._lane_key(r), None)
+            else:
+                self._active[self._lane_key(r)] = r
+
+    def _read(self, call: Optional[_Call]):
+        """``serving.read``: call ``call``'s tokens on the host (a wait for
+        nothing when the call after it went out first), and what the
+        program says of its step beside them, which completes that call's
+        own ``serving.decode`` span (the tracer keeps a span's arguments
+        by reference) and the programs' counters."""
+        with self._span("serving.read", program=call and call.kind,
+                        of_beat=call and call.beat):
+            if call is None:
+                return None
+            toks = np.asarray(call.toks)
+            extra = [np.asarray(a) for a in call.extra]
+        call.toks, call.extra = None, ()
+        if extra:
+            call.says.update(self.programs.note_step(*extra, tokens=call.rows))
+        return toks
+
+    def _fed(self, tokens, carried: int):
+        """A decode call's token argument: the host's array, or with
+        ``carried`` lanes' tokens still on the device the programs' carry
+        of it and the unread call's output. Called inside the program
+        call, so inside ``serving.dispatch`` and the fault point."""
+        if not carried:
+            return tokens
+        return self.programs.carry(self._flight.toks, tokens)
+
+    def _token_of(self, r) -> int:
+        """What a decode call is told of a lane's input token: the token,
+        where the host has read it; else ``-1 - row``, its row in the
+        unread call's output (``programs.carry`` resolves it)."""
+        return r.generated[-1] if len(r.generated) == r.sent else -1 - r.row
+
+    def _last_by_length(self, r) -> bool:
+        """Whether the newest token sent for is the request's last
+        whatever it turns out to be: by ``max_new_tokens``, or by the
+        sequence's capacity."""
+        return r.sent >= r.max_new_tokens or r.position >= self.max_seq
+
+    def _absorb_traced(self, call: Optional[_Call], toks,
+                       seconds: float) -> None:
+        """``serving.absorb`` of the call just read (none: an empty span,
+        the beat's children still tile it)."""
         with self._span("serving.absorb", retired=0) as sp:
-            absorb(lanes, *args, **kwargs)
+            if call is None:
+                return
+            retired = self._absorb(call, toks, seconds)
             if sp.id is not None:
-                sp.args["retired"] = sum(1 for r in lanes if r.done())
+                sp.args["retired"] = retired
 
     def _seq_rung(self, r) -> int:
         from ..jit.bucketing import bucket_for
@@ -445,7 +617,11 @@ class DecodeScheduler:
         """Batch-scoped fault wall: a crashed program call fails exactly
         the lanes it carried (``_step_lanes``, set by the step before its
         program call) and frees their slots; pending prefills and active
-        lanes that did NOT ride the call keep serving. Transient program
+        lanes that did NOT ride the call keep serving, and the call in
+        flight before it stays in flight: the next beat absorbs it for
+        the lanes that survive. Once the call is dispatched and the call
+        before is being read, ``_step_lanes`` holds both calls' lanes: an
+        error that surfaces at the read fails them all. Transient program
         faults are absorbed by the retry policy INSIDE the step (around
         the program call only — admission/absorb bookkeeping never
         replays); only a give-up reaches this wall."""
@@ -456,13 +632,20 @@ class DecodeScheduler:
                 monitor.on_exception("serving.decode_worker", e)
             involved, self._step_lanes = self._step_lanes, []
             if self.breakers is not None:
-                for tenant in {r.tenant for r in involved}:
+                for tenant in {r.tenant for r in involved if not r.done()}:
                     self.breakers.record_failure(tenant)
             for r in involved:
+                if r.done():   # retired or shed since, or listed by both calls
+                    continue
+                if r in self._pending:
+                    self._pending.remove(r)
                 self._free_lane(r)
                 self.queue.admission.on_complete(r.tenant, r.n)
                 r._fail(e)
                 self._trace_failed(r, type(e).__name__)
+            flight = self._flight
+            if flight is not None and all(r.done() for r in flight.lanes):
+                self._flight = None   # nobody is left to read it for
 
     # ------------------------------------------------- stamps and phases
     def _first_token(self, r, now: float) -> None:
@@ -497,6 +680,10 @@ class DecodeScheduler:
                          track=_REQUESTS, parent=self._beat_id,
                          request=r.id, reason=reason)
 
+    def _lane_key(self, r):
+        """A lane's key in ``_active``: its slot."""
+        return r.slot
+
     def _free_lane(self, r) -> None:
         """Detach one request from its KV residency — the single cleanup
         path the fault wall and retirement share (slot pools release the
@@ -510,7 +697,7 @@ class DecodeScheduler:
         """One prefill/decode program call through the fault point and
         (when armed) the retry policy — the only part of a step that is
         safe to replay: it reads pool/request state and returns fresh
-        buffers, mutating nothing until ``commit``/``_absorb``.
+        buffers, mutating nothing until ``commit``/``_launch``.
 
         EXCEPT under buffer donation (accelerators donate the KV pool
         args so XLA aliases in place): a failed-after-dispatch attempt
@@ -531,92 +718,81 @@ class DecodeScheduler:
         return attempt()
 
     # ------------------------------------------------------------- steps
-    def _prefill_step(self) -> None:
+    def _decode_step(self) -> None:
+        self._run(self._build_decode)
+
+    def _prefill_group(self):
+        """The pending requests of the next prefill call, taken off the
+        pending list: those of the OLDEST one's seq rung, up to the cap.
+        Returns (group, batch rung, seq rung)."""
         from ..jit.bucketing import bucket_for
 
-        with self._span("serving.build", lanes=0, rung=None) as sp:
-            rung = self._pending[0].seq_rung  # oldest request anchors the rung
-            group = [r for r in self._pending
-                     if r.seq_rung == rung][: self.prefill_max_batch]
-            for r in group:
-                self._pending.remove(r)
-            self._step_lanes = list(group)  # the fault wall's blast radius
-            b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
-            pad = self.pool.pad_slot
-            tokens = np.zeros((b_rung, rung), np.int32)
-            lengths = np.ones(b_rung, np.int32)
-            slots = np.full(b_rung, pad, np.int32)
-            for i, r in enumerate(group):
-                L = int(r.prompt.size)
-                tokens[i, :L] = r.prompt
-                lengths[i] = L
-                slots[i] = r.slot
-            if sp.id is not None:
-                sp.args.update(lanes=len(group), rung=(b_rung, rung))
-        t0 = time.perf_counter()
-        with self._step_span("prefill", (b_rung, rung), group):
-            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
-                *self.pool.arrays(), tokens, lengths, slots))
-        self._absorb_traced(group, self._absorb, toks, kind="prefill",
-                            seconds=time.perf_counter() - t0,
-                            rung=(b_rung, rung))
+        rung = self._pending[0].seq_rung  # oldest request anchors the rung
+        group = [r for r in self._pending
+                 if r.seq_rung == rung][: self.prefill_max_batch]
+        for r in group:
+            self._pending.remove(r)
+        self._step_lanes = list(group)  # the fault wall's blast radius
+        return group, bucket_for(len(group),
+                                 self.programs.prefill_batch_rungs), rung
 
-    def _prefill_chunk_step(self) -> None:
+    def _build_prefill(self) -> _Call:
+        group, b_rung, rung = self._prefill_group()
+        pad = self.pool.pad_slot
+        tokens = np.zeros((b_rung, rung), np.int32)
+        lengths = np.ones(b_rung, np.int32)
+        slots = np.full(b_rung, pad, np.int32)
+        for i, r in enumerate(group):
+            L = int(r.prompt.size)
+            tokens[i, :L] = r.prompt
+            lengths[i] = L
+            slots[i] = r.slot
+        return _Call("prefill", (b_rung, rung), group,
+                     lambda: self.programs.prefill(
+                         *self.pool.arrays(), tokens, lengths, slots))
+
+    def _build_chunk(self) -> Optional[_Call]:
         """One chunk of the OLDEST pending request's prompt, for programs
         that prefill in pieces (``programs.chunked``: a recurrent state, or
         the pages before the cursor, carry what came before). The request
         keeps a cursor; a whole chunk takes the ladder's top rung, the
         ragged last one the smallest rung that holds it; the last one
-        yields the first token. What the program is told of the lane is
-        the residency's (:meth:`_chunk_args`: a state lane and whether it
-        is fresh, or a block table, grown to hold the chunk first).
-        Between two chunks the decoding lanes get their beat
+        yields the first token (a call before it emits nothing: its lane
+        returns to the head of the pending list). What the program is told
+        of the lane is the residency's (:meth:`_chunk_args`: a state lane
+        and whether it is fresh, or a block table, grown to hold the chunk
+        first). Between two chunks the decoding lanes get their beat
         (:meth:`_step`)."""
         from ..jit.bucketing import bucket_for
         from ..observability.metrics import registry
 
-        with self._span("serving.build", lanes=0, rung=None) as sp:
-            r = self._pending.pop(0)  # back at the head if chunks remain
-            ladder = self.programs.seq_ladder
-            size, top = int(r.prompt.size), ladder[-1]
-            left = size - r.cursor
-            rung = top if left >= top else bucket_for(left, ladder)
-            n = min(left, rung)
-            last = r.cursor + n >= size
-            self._step_lanes = [r]  # the fault wall's blast radius
-            tokens = np.zeros((1, rung), np.int32)
-            tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
-            args = self._chunk_args(r, n)
-            if args is None:
-                # the chunk waits (for pages): the decoding lanes go first
-                self._step_lanes = []
-                self._owe_decode = True
-                return
-            if sp.id is not None:
-                sp.args.update(lanes=1, rung=(1, rung))
-        t0 = time.perf_counter()
-        with self._step_span("prefill", (1, rung), [r], chunk=r.cursor // top,
-                             chunks=-(-size // top), tokens=n) as sp:
-            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
-                *self.pool.arrays(), tokens, np.asarray([n], np.int32), *args))
-            self._note_extra(sp, n)
-        r.cursor += n
+        r = self._pending.pop(0)  # back at the head if chunks remain
+        ladder = self.programs.seq_ladder
+        size, top = int(r.prompt.size), ladder[-1]
+        left = size - r.cursor
+        rung = top if left >= top else bucket_for(left, ladder)
+        n = min(left, rung)
+        self._step_lanes = [r]  # the fault wall's blast radius
         self._owe_decode = True
+        tokens = np.zeros((1, rung), np.int32)
+        tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
+        args = self._chunk_args(r, n)
+        if args is None:
+            # the chunk waits (for pages): the decoding lanes go first
+            self._step_lanes = []
+            return None
+        call = _Call("prefill", (1, rung), [r],
+                     lambda: self.programs.prefill(
+                         *self.pool.arrays(), tokens, np.asarray([n], np.int32),
+                         *args),
+                     emits=r.cursor + n >= size, rows=n,
+                     chunk=r.cursor // top, chunks=-(-size // top), tokens=n)
+        r.cursor += n
         registry.counter(
             "serving.prefill_chunks",
             "prefill chunks run by the decode scheduler (a prompt longer "
             "than the chunk rung takes several beats)").inc()
-        if last:
-            self._absorb_traced([r], self._absorb, toks, kind="prefill",
-                                seconds=time.perf_counter() - t0,
-                                rung=(1, rung))
-        else:
-            self._step_lanes = []  # the call succeeded: nothing to fail
-            self._pending.insert(0, r)
-            with self._span("serving.absorb", retired=0):
-                if self.stats is not None:
-                    self.stats.record_decode_step(
-                        "prefill", time.perf_counter() - t0, 1, 0)
+        return call
 
     def _chunk_args(self, r, n: int):
         """What a chunk's program call takes after the tokens and their
@@ -628,70 +804,70 @@ class DecodeScheduler:
                 np.asarray([r.cursor], np.int32),
                 np.asarray([r.cursor == 0], np.int32))
 
-    def _note_extra(self, sp, tokens: int) -> None:
-        """Hand what the last program call returned beside its tokens to
-        the programs (``note_step``: counters, and what the step's span
-        ``sp`` says of it). Nothing for programs that return tokens only."""
-        extra, self._step_extra = self._step_extra, None
-        if extra:
-            said = self.programs.note_step(*extra, tokens=tokens)
-            if sp.id is not None:
-                sp.args.update(said)
-
-    def _decode_step(self) -> None:
+    def _build_decode(self) -> _Call:
         from ..jit.bucketing import bucket_for
 
-        with self._span("serving.build", lanes=0, rung=None) as sp:
-            lanes = sorted(self._active.values(), key=lambda r: r.id)
-            self._step_lanes = list(lanes)  # the fault wall's blast radius
-            b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
-            pad = self.pool.pad_slot
-            tokens = np.zeros(b_rung, np.int32)
-            slots = np.full(b_rung, pad, np.int32)
-            positions = np.zeros(b_rung, np.int32)
-            for i, r in enumerate(lanes):
-                tokens[i] = r.generated[-1]
-                slots[i] = r.slot
-                positions[i] = r.position
-            if sp.id is not None:
-                sp.args.update(lanes=len(lanes), rung=b_rung)
-        t0 = time.perf_counter()
-        with self._step_span("decode", b_rung, lanes):
-            toks = self._call_and_read("decode", lambda: self.programs.decode(
-                *self.pool.arrays(), tokens, slots, positions))
-        self._absorb_traced(lanes, self._absorb, toks, kind="decode",
-                            seconds=time.perf_counter() - t0, rung=b_rung)
-
-    def _absorb(self, lanes, toks, *, kind: str, seconds: float,
-                rung) -> None:
-        """Scatter one step's emitted tokens back to their requests,
-        retire finished sequences (slot released, future resolved), keep
-        the rest active for the next step."""
-        self._step_lanes = []  # the call succeeded: nothing to fail
-        now = time.perf_counter()  # the first-token stamp of new lanes
-        if self.breakers is not None:
-            for tenant in {r.tenant for r in lanes}:
-                self.breakers.record_success(tenant)
+        lanes = sorted(self._active.values(), key=lambda r: r.id)
+        self._step_lanes = list(lanes)  # the fault wall's blast radius
+        b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
+        pad = self.pool.pad_slot
+        tokens = np.zeros(b_rung, np.int32)
+        slots = np.full(b_rung, pad, np.int32)
+        positions = np.zeros(b_rung, np.int32)
         for i, r in enumerate(lanes):
+            tokens[i] = self._token_of(r)
+            slots[i] = r.slot
+            positions[i] = r.position
+        carried = int((tokens < 0).sum())
+        return _Call("decode", b_rung, lanes,
+                     lambda: self.programs.decode(
+                         *self.pool.arrays(), self._fed(tokens, carried),
+                         slots, positions),
+                     carried=carried)
+
+    def _absorb(self, call: _Call, toks, seconds: float) -> int:
+        """Scatter one call's emitted tokens back to their requests, one
+        beat after it was dispatched: retire finished sequences (slot
+        released, future resolved), the rest decode on (they may already
+        ride the call after). A lane resolved since — retired at its eos a
+        beat ago, shed, or failed with a later call — emits nothing.
+        Returns how many retired."""
+        self._step_lanes = []  # both calls got through: nothing to fail
+        now = time.perf_counter()  # the first-token stamp of new lanes
+        lanes = [(i, r) for i, r in enumerate(call.lanes) if not r.done()]
+        if self.breakers is not None:
+            for tenant in {r.tenant for _, r in lanes}:
+                self.breakers.record_success(tenant)
+        emitted = retired = 0
+        for i, r in (lanes if call.emits else ()):
             tok = int(toks[i])
             r.generated.append(tok)
+            emitted += 1
             if r.t_first_token is None:
                 self._first_token(r, now)
-            self.pool.lengths[r.slot] = r.position
-            done = (len(r.generated) >= r.max_new_tokens
-                    or (self.eos_id is not None and tok == self.eos_id)
-                    or r.position >= self.pool.max_seq)
-            if done:
+            # by length only once nothing more is sent for: known a beat ago
+            if tok == self.eos_id or (len(r.generated) == r.sent
+                                      and self._last_by_length(r)):
                 self._retire(r)
-            else:
-                self._active[r.slot] = r
+                retired += 1
+        occupancy = self._absorbed(r for _, r in lanes)
         if self.stats is not None:
-            self.stats.record_decode_step(kind, seconds, len(lanes),
-                                          len(lanes))
-            self.stats.record_slot_occupancy(self.pool.in_use(),
-                                             self.pool.max_slots)
+            self.stats.record_decode_step(call.kind, seconds, len(call.lanes),
+                                          emitted)
+            self.stats.record_slot_occupancy(*occupancy)
         if self.on_step is not None:
-            self.on_step(kind, len(lanes), rung, len(lanes))
+            self.on_step(call.kind, len(call.lanes), call.rung, emitted)
+        return retired
+
+    def _absorbed(self, lanes):
+        """The residency's bookkeeping after an absorb of ``lanes``: the
+        slot pool's per-slot row counts, as far as the host has read.
+        Returns the occupancy, (in use, capacity)."""
+        for r in lanes:
+            if r.slot is not None:
+                self.pool.lengths[r.slot] = (int(r.prompt.size)
+                                             + max(len(r.generated) - 1, 0))
+        return self.pool.in_use(), self.pool.max_slots
 
     def _retire(self, r) -> None:
         from ..observability.anomaly import monitor
@@ -707,11 +883,12 @@ class DecodeScheduler:
                 r.t_complete - r.t_enqueue, r.t_dispatch - r.t_admit,
                 tenant=r.tenant)
 
+
 class PagedDecodeScheduler(DecodeScheduler):
     """The decode loop over a :class:`~.kv_cache.KVPagePool`.
 
-    Same one-program-call-per-beat shape as the slot scheduler; what
-    changes is the residency model:
+    Same beat as the slot scheduler (one program call dispatched, the one
+    before read and absorbed); what changes is the residency model:
 
     - admission is gated on LANES (the batch ladder's width) and on the
       page budget — a taken request allocates ``ceil(prompt/page_size)``
@@ -725,7 +902,9 @@ class PagedDecodeScheduler(DecodeScheduler):
     - the program call carries the batch's block tables as ONE traced
       int32 array padded to the (batch × table) rung — page maps are
       data, so churn never retraces — plus the per-lane sampling
-      arguments (temperature/top-k/top-p/PRNG key pair).
+      arguments (temperature/top-k/top-p/PRNG key pair). The token is
+      chosen on the device, so a sampling lane needs nothing from the
+      host between steps but its key index, which counts tokens sent for.
     - retirement releases the request's pages; the pool's utilization
       watermark (JX334) samples live tokens against in-use pages each
       step.
@@ -744,7 +923,6 @@ class PagedDecodeScheduler(DecodeScheduler):
                          eos_id=eos_id, stats=stats, on_step=on_step,
                          retry=retry, breakers=breakers)
         self.max_lanes = max(int(max_lanes), 1)
-        self.max_seq = int(programs.max_seq)
         # self-speculation lane policy (ISSUE 20): a beat runs one
         # draft+verify round instead of one decode step whenever the
         # master toggle is on AND any lane still speculates — opted-out
@@ -767,7 +945,6 @@ class PagedDecodeScheduler(DecodeScheduler):
         # its pages and starve it forever
         if free <= 0 or self.pool.free_count() <= 0 or self._starved:
             return 0
-        idle = not self._active and not self._pending
         # page-budget admission gate: a request is taken only when
         # its PROMPT pages fit the free list right now — one that
         # merely has to wait for a retirement stays queued (FIFO,
@@ -789,7 +966,7 @@ class PagedDecodeScheduler(DecodeScheduler):
             return True
 
         taken = self.queue.take_slots(
-            free, timeout=0.05 if idle else 0.0, budget_fn=fits)
+            free, timeout=0.05 if self._idle() else 0.0, budget_fn=fits)
         now = time.perf_counter()
         for r in taken:
             r.seq_rung = self._seq_rung(r)
@@ -808,8 +985,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         tokens at the cursor, the cursor, and the sampling arguments. An
         injected ``kv.page_alloc`` fault sheds the request; natural
         pressure leaves it at the head of the pending list and lets the
-        decoding lanes run (a retirement frees pages); with no lane able
-        to step none can come, and the request is shed."""
+        decoding lanes run (a retirement frees pages; one may be in
+        flight); with no lane able to step none can come, and the request
+        is shed."""
         from ..reliability.faults import FaultInjection
 
         need = -(-(r.cursor + n) // self.pool.page_size)
@@ -820,7 +998,8 @@ class PagedDecodeScheduler(DecodeScheduler):
             self._shed(r, e)
             return None
         except Exception as e:  # noqa: BLE001 — natural pressure: wait
-            if len(self._starved) < len(self._active):
+            if (len(self._starved) < len(self._active)
+                    or self._flight is not None):
                 self._pending.insert(0, r)
             else:   # no lane can step, so no retirement will come
                 self._shed(r, e)
@@ -857,6 +1036,9 @@ class PagedDecodeScheduler(DecodeScheduler):
             f"request {r.id} shed: KV page allocation failed ({cause})"))
         self._trace_failed(r, "kv_pages")
 
+    def _lane_key(self, r):
+        return r.id
+
     def _free_lane(self, r) -> None:
         self._active.pop(r.id, None)
         if r.pages:
@@ -876,9 +1058,10 @@ class PagedDecodeScheduler(DecodeScheduler):
         Natural exhaustion is gentler: the starved lane simply sits out
         this step — it keeps its pages and retries next beat, by which
         time a retirement has usually freed some. Only when EVERY active
-        lane is starved (no retirement can ever come) does the deadlock
-        breaker shed the youngest starved lane, freeing its pages for
-        the older ones — guaranteed progress, FIFO-fair."""
+        lane is starved and no call is in flight (no retirement can ever
+        come) does the deadlock breaker shed the youngest starved lane,
+        freeing its pages for the older ones — guaranteed progress,
+        FIFO-fair."""
         from ..reliability.faults import FaultInjection
 
         ready, starved = [], []
@@ -895,7 +1078,8 @@ class PagedDecodeScheduler(DecodeScheduler):
                 starved.append(r)
                 continue
             ready.append(r)
-        if not ready and starved and not self._pending:
+        if (not ready and starved and not self._pending
+                and self._flight is None):
             victim = max(starved, key=lambda r: r.id)
             starved.remove(victim)
             self._shed(victim, RuntimeError(
@@ -910,9 +1094,11 @@ class PagedDecodeScheduler(DecodeScheduler):
         key is ``[request_seed, generated_token_index]`` — a pure
         function of the request, never of batch composition, so sampled
         streams are deterministic per seed under any join/leave order.
-        Pad lanes carry temperature 0: a call whose every lane does runs
-        no vocabulary sort, and a call with one sampling lane runs it
-        for every lane, pads included (``_choose_tokens``)."""
+        The index counts the tokens sent for before this call (the host
+        may not have read the newest). Pad lanes carry temperature 0: a
+        call whose every lane does runs no vocabulary sort, and a call
+        with one sampling lane runs it for every lane, pads included
+        (``_choose_tokens``)."""
         temps = np.zeros(b_rung, np.float32)
         top_ks = np.zeros(b_rung, np.int32)
         top_ps = np.ones(b_rung, np.float32)
@@ -921,8 +1107,7 @@ class PagedDecodeScheduler(DecodeScheduler):
             temps[i] = r.temperature
             top_ks[i] = r.top_k
             top_ps[i] = r.top_p
-            rkeys[i] = (np.uint32(r.seed & 0xFFFFFFFF),
-                        np.uint32(len(r.generated)))
+            rkeys[i] = (np.uint32(r.seed & 0xFFFFFFFF), np.uint32(r.sent))
         return temps, top_ks, top_ps, rkeys
 
     def _step_span(self, kind: str, rung, lanes, **args):
@@ -938,36 +1123,21 @@ class PagedDecodeScheduler(DecodeScheduler):
         return super()._step_span(kind, rung, lanes, sampling=sampling,
                                   **args)
 
-    def _prefill_step(self) -> None:
-        from ..jit.bucketing import bucket_for
-
-        with self._span("serving.build", lanes=0, rung=None) as sp:
-            rung = self._pending[0].seq_rung  # oldest request anchors the rung
-            group = [r for r in self._pending
-                     if r.seq_rung == rung][: self.prefill_max_batch]
-            for r in group:
-                self._pending.remove(r)
-            self._step_lanes = list(group)  # the fault wall's blast radius
-            b_rung = bucket_for(len(group), self.programs.prefill_batch_rungs)
-            t_cols = self.programs._prefill_table_cols(rung)
-            tokens = np.zeros((b_rung, rung), np.int32)
-            lengths = np.ones(b_rung, np.int32)
-            tables = np.zeros((b_rung, t_cols), np.int32)  # 0 = pad page
-            for i, r in enumerate(group):
-                L = int(r.prompt.size)
-                tokens[i, :L] = r.prompt
-                lengths[i] = L
-                tables[i, :len(r.pages)] = r.pages
-            if sp.id is not None:
-                sp.args.update(lanes=len(group), rung=(b_rung, rung))
-        t0 = time.perf_counter()
-        with self._step_span("prefill", (b_rung, rung), group):
-            toks = self._call_and_read("prefill", lambda: self.programs.prefill(
-                self.pool.k, self.pool.v, tokens, lengths, tables,
-                *self._sample_args(group, b_rung)))
-        self._absorb_traced(group, self._absorb, toks, kind="prefill",
-                            seconds=time.perf_counter() - t0,
-                            rung=(b_rung, rung))
+    def _build_prefill(self) -> _Call:
+        group, b_rung, rung = self._prefill_group()
+        t_cols = self.programs._prefill_table_cols(rung)
+        tokens = np.zeros((b_rung, rung), np.int32)
+        lengths = np.ones(b_rung, np.int32)
+        tables = np.zeros((b_rung, t_cols), np.int32)  # 0 = pad page
+        for i, r in enumerate(group):
+            L = int(r.prompt.size)
+            tokens[i, :L] = r.prompt
+            lengths[i] = L
+            tables[i, :len(r.pages)] = r.pages
+        return _Call("prefill", (b_rung, rung), group,
+                     lambda: self.programs.prefill(
+                         self.pool.k, self.pool.v, tokens, lengths, tables,
+                         *self._sample_args(group, b_rung)))
 
     def _step_inputs(self, lookahead: int = 0):
         """The lanes ready to step and their program inputs — (lanes,
@@ -978,6 +1148,9 @@ class PagedDecodeScheduler(DecodeScheduler):
         just before it, where each always did, so the span that
         ``decode_step_ms`` reads keeps measuring what it measured.
 
+        ``tokens`` holds ``-1 - row`` for a lane whose newest token is
+        still on the device (:meth:`_token_of`).
+
         ``pages`` is what the step's span says of its block table:
         ``pages_table`` entries (batch rung x table rung), ``pages_live``
         of them naming a page that holds a column its lane may see
@@ -987,49 +1160,53 @@ class PagedDecodeScheduler(DecodeScheduler):
         lane). The stats keep both sums."""
         from ..jit.bucketing import bucket_for
 
-        with self._span("serving.build", lanes=0, rung=None) as sp:
-            lanes = sorted(self._active.values(), key=lambda r: r.id)
-            lanes = self._ensure_pages(lanes, lookahead=lookahead)
-            if not lanes:
-                return None
-            self._step_lanes = list(lanes)  # the fault wall's blast radius
-            b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
-            t_rung = bucket_for(max(len(r.pages) for r in lanes),
-                                self.programs.table_rungs)
-            tokens = np.zeros(b_rung, np.int32)
-            tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
-            positions = np.zeros(b_rung, np.int32)
-            for i, r in enumerate(lanes):
-                tokens[i] = r.generated[-1]
-                tables[i, :len(r.pages)] = r.pages
-                positions[i] = r.position
-            last = np.minimum(positions[:len(lanes)] + lookahead,
-                              self.max_seq - 1)
-            pages = {"pages_live": int((last // self.pool.page_size + 1).sum()),
-                     "pages_table": b_rung * t_rung}
-            if self.stats is not None:
-                self.stats.record_pages(**pages)
-            if sp.id is not None:
-                sp.args.update(lanes=len(lanes), rung=(b_rung, t_rung))
+        lanes = sorted(self._active.values(), key=lambda r: r.id)
+        lanes = self._ensure_pages(lanes, lookahead=lookahead)
+        if not lanes:
+            return None
+        self._step_lanes = list(lanes)  # the fault wall's blast radius
+        b_rung = bucket_for(len(lanes), self.programs.decode_rungs)
+        t_rung = bucket_for(max(len(r.pages) for r in lanes),
+                            self.programs.table_rungs)
+        tokens = np.zeros(b_rung, np.int32)
+        tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
+        positions = np.zeros(b_rung, np.int32)
+        for i, r in enumerate(lanes):
+            tokens[i] = self._token_of(r)
+            tables[i, :len(r.pages)] = r.pages
+            positions[i] = r.position
+        last = np.minimum(positions[:len(lanes)] + lookahead,
+                          self.max_seq - 1)
+        pages = {"pages_live": int((last // self.pool.page_size + 1).sum()),
+                 "pages_table": b_rung * t_rung}
+        if self.stats is not None:
+            self.stats.record_pages(**pages)
         return lanes, (b_rung, t_rung), tokens, tables, positions, pages
+
+    def _build_decode(self) -> Optional[_Call]:
+        built = self._step_inputs()
+        if built is None:
+            return None
+        lanes, rung, tokens, tables, positions, pages = built
+        carried = int((tokens < 0).sum())
+        return _Call("decode", rung, lanes,
+                     lambda: self.programs.decode(
+                         *self.pool.arrays(), self._fed(tokens, carried),
+                         tables, positions,
+                         *self._sample_args(lanes, rung[0])),
+                     carried=carried, **pages)
 
     def _decode_step(self) -> None:
         if (self.speculate_k > 0 and self.spec_enabled
                 and any(r.spec_live for r in self._active.values())):
-            self._spec_round()
+            # a round compares tokens on the host: what is in flight is
+            # read and absorbed first, in a beat of its own
+            if self._flight is not None:
+                self._run()
+            else:
+                self._spec_round()
             return
-        built = self._step_inputs()
-        if built is None:
-            return
-        lanes, rung, tokens, tables, positions, pages = built
-        t0 = time.perf_counter()
-        with self._step_span("decode", rung, lanes, **pages) as sp:
-            toks = self._call_and_read("decode", lambda: self.programs.decode(
-                *self.pool.arrays(), tokens, tables, positions,
-                *self._sample_args(lanes, rung[0])))
-            self._note_extra(sp, len(lanes))
-        self._absorb_traced(lanes, self._absorb, toks, kind="decode",
-                            seconds=time.perf_counter() - t0, rung=rung)
+        self._run(self._build_decode)
 
     def _spec_round(self) -> None:
         """One self-speculation round (ISSUE 20): ONE draft dispatch
@@ -1039,33 +1216,45 @@ class PagedDecodeScheduler(DecodeScheduler):
         plus the verify pass's own next token — ≥ 1 token per round,
         up to k+1, always bitwise the tokens the plain decode loop
         would have produced. Pages grown for the speculative suffix
-        roll back through the pool free-list in ``_absorb_spec``."""
+        roll back through the pool free-list in ``_absorb_spec``.
+
+        The host compares drafts with verified tokens, so this is the one
+        step that reads its own calls, each right after its dispatch;
+        nothing is in flight when it starts (:meth:`_decode_step`) or
+        ends. The stats count the round as one flushed read."""
         k = self.speculate_k
-        built = self._step_inputs(lookahead=k)
-        if built is None:
-            return
-        lanes, rung, tokens, tables, positions, pages = built
+        with self._span("serving.build", lanes=0, rung=None) as sp:
+            built = self._step_inputs(lookahead=k)
+            if built is None:
+                return
+            lanes, rung, tokens, tables, positions, pages = built
+            if sp.id is not None:
+                sp.args.update(lanes=len(lanes), rung=rung)
         sample = self._sample_args(lanes, rung[0])
         with self._step_span("speculate", rung, lanes, k=k, **pages):
             t0 = time.perf_counter()
-            # [b_rung, k] proposals
-            drafts = self._call_and_read("draft", lambda: self.programs.draft(
-                self.pool.k, self.pool.v, tokens, tables, positions,
-                *sample))
+            draft = _Call("draft", rung, lanes, lambda: self.programs.draft(
+                self.pool.k, self.pool.v, tokens, tables, positions, *sample))
+            self._dispatch(draft)
+            drafts = self._read(draft)        # [b_rung, k] proposals
             t_draft = time.perf_counter() - t0
             vin = np.zeros((len(tokens), k + 1), np.int32)
             vin[:, 0] = tokens                # last committed token at p
             vin[:, 1:] = drafts               # proposals at p+1..p+k
             t1 = time.perf_counter()
-            # [b_rung, k+1] true tokens
-            vtoks = self._call_and_read("verify", lambda: self.programs.verify(
+            verify = _Call("verify", rung, lanes, lambda: self.programs.verify(
                 self.pool.k, self.pool.v, vin, tables, positions, *sample))
+            self._dispatch(verify)
+            vtoks = self._read(verify)        # [b_rung, k+1] true tokens
             t_verify = time.perf_counter() - t1
-        self._absorb_traced(lanes, self._absorb_spec, drafts, vtoks,
-                            t_draft=t_draft, t_verify=t_verify, rung=rung)
+        with self._span("serving.absorb", retired=0) as sp:
+            retired = self._absorb_spec(lanes, drafts, vtoks, t_draft=t_draft,
+                                        t_verify=t_verify, rung=rung)
+            if sp.id is not None:
+                sp.args["retired"] = retired
 
     def _absorb_spec(self, lanes, drafts, vtoks, *, t_draft: float,
-                     t_verify: float, rung) -> None:
+                     t_verify: float, rung) -> int:
         """Acceptance + commit + rollback for one speculation round.
         Lane i's accepted prefix length m is the longest run of draft
         proposals the verify pass reproduced; verify tokens 0..m commit
@@ -1073,13 +1262,13 @@ class PagedDecodeScheduler(DecodeScheduler):
         per-index sampling keys), stopping early at eos/max_new/max_seq
         exactly like ``_absorb``. Block-table pages past the new write
         position — grown for the speculative suffix — release back to
-        the free-list: the rollback contract."""
+        the free-list: the rollback contract. Returns how many retired."""
         self._step_lanes = []  # the calls succeeded: nothing to fail
         if self.breakers is not None:
             for tenant in {r.tenant for r in lanes}:
                 self.breakers.record_success(tenant)
         k = self.speculate_k
-        proposed = accepted = committed = 0
+        proposed = accepted = committed = retired = 0
         for i, r in enumerate(lanes):
             m = 0
             while m < k and int(drafts[i, m]) == int(vtoks[i, m]):
@@ -1092,10 +1281,9 @@ class PagedDecodeScheduler(DecodeScheduler):
             for j in range(m + 1):
                 tok = int(vtoks[i, j])
                 r.generated.append(tok)
+                r.sent += 1       # read as soon as sent for: nothing in flight
                 committed += 1
-                done = (len(r.generated) >= r.max_new_tokens
-                        or (self.eos_id is not None and tok == self.eos_id)
-                        or r.position >= self.max_seq)
+                done = tok == self.eos_id or self._last_by_length(r)
                 if done:
                     break
             # rolling-acceptance lane policy: once a request has seen a
@@ -1109,51 +1297,30 @@ class PagedDecodeScheduler(DecodeScheduler):
                 r.spec_live = False
             if done:
                 self._retire(r)
+                retired += 1
             else:
                 keep = int(r.position) // self.pool.page_size + 1
                 if len(r.pages) > keep:  # speculative-suffix rollback
                     self.pool.release(r.pages[keep:])
                     del r.pages[keep:]
                 self._active[r.id] = r
-        live_tokens = sum(int(r.prompt.size) + len(r.generated)
-                          for r in self._active.values())
-        self.pool.note_utilization(live_tokens)
+        occupancy = self._absorbed(())
         if self.stats is not None:
+            self.stats.record_read(overlapped=False)
             self.stats.record_decode_step("draft", t_draft, len(lanes), 0)
             self.stats.record_decode_step("verify", t_verify, len(lanes),
                                           committed)
             self.stats.record_spec_round(proposed, accepted, committed)
-            self.stats.record_slot_occupancy(self.active_count(),
-                                             self.max_lanes)
+            self.stats.record_slot_occupancy(*occupancy)
         if self.on_step is not None:
             self.on_step("speculate", len(lanes), rung, committed)
+        return retired
 
-    def _absorb(self, lanes, toks, *, kind: str, seconds: float,
-                rung) -> None:
-        self._step_lanes = []  # the call succeeded: nothing to fail
-        now = time.perf_counter()  # the first-token stamp of new lanes
-        if self.breakers is not None:
-            for tenant in {r.tenant for r in lanes}:
-                self.breakers.record_success(tenant)
-        for i, r in enumerate(lanes):
-            tok = int(toks[i])
-            r.generated.append(tok)
-            if r.t_first_token is None:
-                self._first_token(r, now)
-            done = (len(r.generated) >= r.max_new_tokens
-                    or (self.eos_id is not None and tok == self.eos_id)
-                    or r.position >= self.max_seq)
-            if done:
-                self._retire(r)
-            else:
-                self._active[r.id] = r
-        live_tokens = sum(int(r.prompt.size) + len(r.generated)
-                          for r in self._active.values())
-        self.pool.note_utilization(live_tokens)
-        if self.stats is not None:
-            self.stats.record_decode_step(kind, seconds, len(lanes),
-                                          len(lanes))
-            self.stats.record_slot_occupancy(self.active_count(),
-                                             self.max_lanes)
-        if self.on_step is not None:
-            self.on_step(kind, len(lanes), rung, len(lanes))
+    def _absorbed(self, lanes):
+        """The pool's utilization watermark: the rows that the requests
+        holding pages have written or sent for, against the pages in use.
+        Returns the occupancy in lanes."""
+        held = self._holding()
+        self.pool.note_utilization(sum(int(r.prompt.size) + r.sent
+                                       for r in held))
+        return len(held), self.max_lanes

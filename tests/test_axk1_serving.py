@@ -188,7 +188,8 @@ def test_residency_follows_the_model_and_speculation_is_refused(model, engine):
     assert engine.kv_pool.row_width == 128 and len(engine.kv_pool.arrays()) == 1
     assert engine.programs.table_rungs == [16]
     assert sorted(engine.programs.warmed) == sorted(
-        [("decode", b) for b in (1, 2, 4)] + [("prefill", 1, c) for c in (8, 16)])
+        [("decode", b) for b in (1, 2, 4)] + [("prefill", 1, c) for c in (8, 16)]
+        + [("carry", p, b) for p in (1, 2, 4) for b in (1, 2, 4)])
     with pytest.raises(ValueError, match="layer 0 is another kind of layer"):
         serving.DecodeEngine(model, max_slots=2, max_seq=64, speculate_k=2)
     with pytest.raises(ValueError, match="multiple of the page size"):
@@ -241,6 +242,10 @@ def test_steps_say_their_chunk_their_pairs_and_the_experts_hit(engine):
         tracer.disable()
     steps = [e["args"] for e in tracer.to_chrome_trace()["traceEvents"]
              if e.get("ph") == "X" and e["name"] == "serving.decode"]
+    # a call's counts reach its own span with its read, a beat later; the last
+    # beat only reads (nothing to dispatch): its span has no lanes and no counts
+    assert [a["lanes"] for a in steps] == [1] * 7 + [0] and steps[-1]["kind"] == "decode"
+    steps = steps[:-1]
     chunks = [a for a in steps if a["kind"] == "prefill"]
     decodes = [a for a in steps if a["kind"] == "decode"]
     assert [(a["chunk"], a["chunks"], a["tokens"]) for a in chunks] == [(0, 3, 16), (1, 3, 16), (2, 3, 5)]

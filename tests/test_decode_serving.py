@@ -667,6 +667,7 @@ class TestSchedulerTracing:
         kinds = {b["args"]["kind"] for b in beats}
         assert {"prefill", "idle"} <= kinds
         assert kinds - {"idle", "prefill"} <= {"decode", "speculate"}
+        in_flight = None    # the beat whose call is dispatched and unread
         for b in beats:
             kids = sorted((e for e in events if e["parent"] == b["id"]
                            and e["name"] in _SCHEDULER_SPANS),
@@ -688,6 +689,18 @@ class TestSchedulerTracing:
                     * (2 if step["args"]["kind"] == "speculate" else 1))
             assert [e["name"] for e in inner] == want
             assert sum(e["dur"] for e in inner) <= step["dur"]
+            # a read is of the call of the beat BEFORE (none in flight: of
+            # nothing); only a speculation round reads its own calls
+            reads = [e["args"]["of_beat"] for e in inner
+                     if e["name"] == "serving.read"]
+            if step["args"]["kind"] == "speculate":
+                assert in_flight is None
+                assert reads == [b["args"]["beat"]] * 2
+            else:
+                assert reads == [in_flight]
+                assert in_flight is None or in_flight < b["args"]["beat"]
+                in_flight = b["args"]["beat"] if step["args"]["lanes"] else None
+        assert in_flight is None    # the drain read the last call
 
     def test_decode_span_names_the_lanes_it_carried(self, traced):
         reqs, events = traced

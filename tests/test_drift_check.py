@@ -60,7 +60,8 @@ def test_committed_lockfile_shape_and_coverage():
     assert len([n for n in progs if n.startswith("serving/batch:")]) >= 2
     assert len([n for n in progs if n.startswith("decode/paged:")]) >= 2
     assert {n for n in progs if n.startswith("decode/state:")} == {
-        "decode/state:decode:2", "decode/state:prefill:1:8"}
+        "decode/state:decode:2", "decode/state:prefill:1:8",
+        "decode/state:carry:1:2", "decode/state:carry:2:2"}
     assert "collective/qpsum" in progs
     assert "reshard/s_to_s" in progs
     # every fingerprint carries the full canonical schema
@@ -72,7 +73,8 @@ def test_committed_lockfile_shape_and_coverage():
                                    "guard_preds"}, name
     # the rung grids cover the serving + decode groups
     assert {n for n in progs if n.startswith("decode/latent:")} == {
-        "decode/latent:decode:2", "decode/latent:prefill:1:8"}
+        "decode/latent:decode:2", "decode/latent:prefill:1:8",
+        "decode/latent:carry:1:2", "decode/latent:carry:2:2"}
     assert set(lock["rung_grids"]) == {"serving/batch", "decode/paged",
                                        "decode/state", "decode/latent"}
 
