@@ -446,6 +446,29 @@ def _latent_fingerprints(programs, rung_grids) -> None:
     _program_set_fingerprints(progs, "decode/latent", programs, rung_grids)
 
 
+def _windowed_fingerprints(programs, rung_grids) -> None:
+    """The two-lifetime residency's representatives: one prefill chunk and
+    one decode rung of a tiny Command A+ (one window layer and one global,
+    half of the experts held) over a WindowedPagePools (the jnp decode path:
+    the Pallas kernel is a TPU's), retraced abstractly."""
+    import paddle_tpu as paddle
+    from ..models.cohere2_moe import Cohere2MoEForCausalLM, cohere2_moe_tiny
+    from ..serving.decode import WindowedPrograms
+    from ..serving.kv_cache import WindowedPagePools
+
+    paddle.seed(0)
+    model = Cohere2MoEForCausalLM(cohere2_moe_tiny(
+        num_hidden_layers=2, layer_types=["sliding_attention", "full_attention"],
+        hidden_size=32, intermediate_size=16, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, num_experts=8, vocab_size=64,
+        max_position_embeddings=32), expert_share=(1, 2))
+    model.eval()
+    pool = WindowedPagePools(1, 1, 6, 8, 8, 2, 8, window=16)
+    progs = WindowedPrograms(model, pool, seq_ladder=[8], decode_rungs=[2],
+                             max_seq=32)
+    _program_set_fingerprints(progs, "decode/windowed", programs, rung_grids)
+
+
 def _qpsum_fingerprint(programs) -> None:
     """The quantized-allreduce oracle over an awkward (non-multiple)
     shape — the exact wire math, block size pinned so the trace is
@@ -513,6 +536,7 @@ def record_drift_programs(refresh: bool = False) -> dict:
         _decode_fingerprints(programs, rung_grids)
         _retention_fingerprints(programs, rung_grids)
         _latent_fingerprints(programs, rung_grids)
+        _windowed_fingerprints(programs, rung_grids)
         _qpsum_fingerprint(programs)
         _reshard_fingerprints(programs, skipped)
     live = {"programs": programs, "rung_grids": rung_grids,

@@ -16,7 +16,9 @@ around each pullback). A serving program (``serving/decode.py``,
 ``serving/kv_cache.py``) runs under one of ``ROOTS`` and uses ``SERVING``
 inside it; the programs of a model whose layers hold a recurrent state
 (``models/brumby.py``) use ``RETENTION`` there, those of a model with latent attention and sparse
-experts (``models/axk1.py``) ``LATENT_MOE``. ``KERNELS`` are the
+experts (``models/axk1.py``) ``LATENT_MOE``, those of a model with window and
+global layers under a parallel block (``models/cohere2_moe.py``)
+``WINDOWED_MOE``. ``KERNELS`` are the
 ``pl.pallas_call(name=...)`` of ``ops/pallas/flash_attention.py``,
 ``ops/pallas/retention.py`` and ``ops/pallas/paged_attention.py``.
 """
@@ -59,6 +61,7 @@ FLASH_BWD_DKV = "flash_bwd_dkv"
 RETN_STEP = "retn_step"          # ops/pallas/retention.py: the decode step's state kernel
 PAGED_ATTN = "paged_attn"        # ops/pallas/paged_attention.py: decode attention over live pages
 LATENT_ATTN = "latent_paged_attn"  # the same file: absorbed attention over latent pages, a page K and V at once
+GQA_ATTN = "gqa_paged_attn"      # the same file: grouped-query decode attention over live pages, given a window or none
 # XLA's own grouped-product kernel on a TPU (what `lax.ragged_dot` becomes).
 # It names its operations itself (`ragged-dot-none`, `ragged-dot-metadata`)
 # and drops the scope it was traced under: a reader of `moe/experts` adds the
@@ -74,6 +77,11 @@ RETENTION = (EMBED, NORM, ATTN_QKV, ROPE, RETN_GATE, RETN_CHUNK, RETN_STATE,
 LATENT_MOE = (EMBED, NORM, ATTN_LATENT_PROJ, ROPE, ATTN_KV_WRITE, ATTN_EXPAND,
               ATTN_CORE, ATTN_OUT, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, MLP,
               LM_HEAD, SAMPLE)
+# a parallel block (one LayerNorm feeding attention and the experts), window
+# and global layers in one stack (``models/cohere2_moe.py``)
+WINDOWED_MOE = (EMBED, LN, ATTN_QKV, ROPE, ATTN_KV_WRITE, ATTN_KV_GATHER,
+                ATTN_CORE, ATTN_OUT, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
+                LM_HEAD, SAMPLE)
 ROOTS = (PREFILL, DECODE, DRAFT, VERIFY)
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, RETN_STEP, PAGED_ATTN,
-           LATENT_ATTN)
+           LATENT_ATTN, GQA_ATTN)

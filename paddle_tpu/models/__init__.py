@@ -30,6 +30,13 @@ from .brumby import (  # noqa: F401
     BrumbyModel,
     brumby_tiny,
 )
+from .cohere2_moe import (  # noqa: F401
+    Cohere2MoEBlock,
+    Cohere2MoEConfig,
+    Cohere2MoEForCausalLM,
+    Cohere2MoEModel,
+    cohere2_moe_tiny,
+)
 from .gpt import (  # noqa: F401
     GPTConfig,
     GPTModel,

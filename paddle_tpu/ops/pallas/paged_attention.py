@@ -29,6 +29,15 @@ minor dimension is never split.
 token's LATENT (``models/axk1.py``): a page is K and, in its leading columns,
 V, for every head at once, so it is one operand and not two, and q is dense
 (64 heads are 64 rows, each ``[q_lat | q_rope]``) and not spread.
+
+:func:`gqa_paged_attention` is the grouped-query form, for a model whose K/V
+heads are fewer than its query heads (``models/cohere2_moe.py``: 128 on 8):
+the query heads of one K/V head are the rows of one small product against
+that head's ``head_dim`` columns of the page, a lane-aligned slice of the
+merged minor dimension, so nothing is spread and no zero is multiplied. Given
+a ``window`` a lane reads only the table entries that hold a row the window
+still covers: the table is cut to those columns before the call, so entries
+in front of the window are not even skipped grid steps.
 """
 from __future__ import annotations
 
@@ -91,15 +100,18 @@ def _kernel(layer_ref, pages_ref, last_ref, pos_ref, q_ref, *refs, scale,
         o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _held_pages(tables, last, per_step):
+def _held_pages(tables, last, per_step, first=None):
     """The page each table entry's operand holds in its grid step: the
-    entry's own where it is live, else what that operand held in the step
-    before (the pad page if none did), so a dead entry moves no bytes.
+    entry's own where it is live (``first <= t <= last``; ``first`` 0 unless
+    given), else what that operand held in the step before (the pad page if
+    none did), so a dead entry moves no bytes.
     Grid steps run lane-major; entry ``(b, t)`` is operand ``t % per_step``
     of step ``(b, t // per_step)``."""
     B, T = tables.shape
     steps = T // per_step
     live = jnp.arange(T)[None, :] <= last[:, None]
+    if first is not None:
+        live &= jnp.arange(T)[None, :] >= first[:, None]
     # [B, steps, per_step] -> one row a grid step, in the grid's order
     own = jnp.where(live, tables, -1).reshape(B * steps, per_step)
     at = jnp.where(own >= 0, jnp.arange(B * steps)[:, None], -1)
@@ -220,3 +232,140 @@ def latent_paged_attention(q, pool, layer, tables, positions, *, v_cols, scale,
       _held_pages(tables.astype(jnp.int32), last, per_step), last,
       positions, q, *[pool] * per_step)
     return out[:, :H]
+
+
+GQA_PAGES = 4   # table entries a grid step: 16 double-buffered (256, 1024) bf16 blocks are 8.4 MB of VMEM
+
+
+def _gqa_kernel(layer_ref, pages_ref, first_ref, last_ref, base_ref, pos_ref,
+                q_ref, *refs, scale, kv_heads, dim, page, per_step, window):
+    """One lane, ``per_step`` table entries: ``q_ref`` ``[kv_heads x group,
+    dim]`` (a K/V head's query heads are consecutive rows), K and V pages
+    ``[page, kv_heads x dim]``. Entry ``e`` of the call's table is logical
+    column ``base + e`` of the lane's; it is live where ``first <= e <=
+    last``. The running softmax is kept a row (query head) in float32."""
+    del layer_ref, pages_ref   # read by the index maps
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * per_step:]
+    b, t = pl.program_id(0), pl.program_id(1)
+    group = q_ref.shape[0] // kv_heads
+    pos = pos_ref[b]
+
+    @pl.when(t == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    for j in range(per_step):
+        entry = t * per_step + j
+
+        @pl.when((entry >= first_ref[b]) & (entry <= last_ref[b]))
+        def _(entry=entry, k_ref=k_refs[j], v_ref=v_refs[j]):
+            col = ((base_ref[b] + entry) * page
+                   + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1))
+            seen = col <= pos
+            if window is not None:
+                seen &= pos - col < window
+            for g in range(kv_heads):
+                rows, cols = pl.ds(g * group, group), pl.ds(g * dim, dim)
+                logits = _dot(q_ref[rows, :], k_ref[:, cols], _NT) * scale   # [group, page] f32
+                logits = jnp.where(seen, logits, NEG_INF)
+                m_prev = m_scr[rows, :]
+                m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a live entry may hold no visible column for this lane (the
+                # window's first page at its edge): its rows then count as 0
+                p = jnp.where(seen, jnp.exp(logits - m_new), 0.0)
+                l_scr[rows, :] = alpha * l_scr[rows, :] + p.sum(axis=1, keepdims=True)
+                v = v_ref[:, cols]
+                acc_scr[rows, :] = alpha * acc_scr[rows, :] + _dot(p.astype(v.dtype), v, _NN)
+                m_scr[rows, :] = m_new
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def window_columns(window: int, page: int) -> int:
+    """The most table columns that hold a row some query can see through a
+    window of ``window`` keys (its own among them): the query's page and the
+    pages of the ``window - 1`` rows behind it."""
+    return (window - 1 + page - 1) // page + 1
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "scale", "window",
+                                             "interpret"))
+def gqa_paged_attention(q, k_pool, v_pool, layer, tables, positions, *,
+                        kv_heads, scale, window=None, interpret=False):
+    """Grouped-query attention of ONE query a lane over its pages: ``q``
+    ``[B, heads x dim]`` (query head ``h`` uses K/V head ``h // (heads //
+    kv_heads)``), the pools ``[L, N, page, kv_heads x dim]`` whole, ``layer``
+    a scalar, ``tables`` ``[B, T]`` int32 (a lane's pages by logical column;
+    0, the pad page, past its pages and, in a window layer, where a page was
+    released), ``positions`` ``[B]``. Returns ``[B, heads x dim]`` in ``q``'s
+    dtype: lane ``b`` attends over the columns ``j <= positions[b]`` and,
+    given ``window``, ``positions[b] - j < window``.
+
+    With a window the table is cut, lane by lane, to the
+    :func:`window_columns` columns that end at the query's page (``base`` is
+    the first of them), so the grid is that narrow whatever ``T`` is and the
+    columns in front of the window are never visited; without one ``base`` is
+    0. Either way an entry that holds no visible row is dead as in
+    :func:`paged_attention`: it names the page its operand already holds and
+    moves no bytes."""
+    B, HD = q.shape
+    L, N, page, KD = k_pool.shape
+    dim = KD // kv_heads
+    heads = HD // dim
+    T = tables.shape[1]
+    positions = positions.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    col_last = jnp.minimum(positions // page, T - 1)
+    if window is None:
+        base = jnp.zeros((B,), jnp.int32)
+    else:
+        width = min(T, window_columns(window, page))
+        base = jnp.maximum(col_last - (width - 1), 0)
+        tables = jnp.take_along_axis(
+            tables, base[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :], axis=1)
+        T = width
+    per_step = min(T, GQA_PAGES)
+    tables = jnp.pad(tables, ((0, 0), (0, -T % per_step)))
+    T = tables.shape[1]
+    last = col_last - base
+    first = (jnp.zeros((B,), jnp.int32) if window is None else
+             jnp.maximum(positions - (window - 1), 0) // page - base)
+
+    def lane(b, t, layer, pages, first, last, base, pos):
+        return (b, 0, 0)
+
+    def entry(j):
+        return lambda b, t, layer, pages, first, last, base, pos: (
+            layer[0], pages[b * T + t * per_step + j], 0, 0)
+
+    q_spec = pl.BlockSpec((None, heads, dim), lane)
+    kv_specs = [pl.BlockSpec((None, None, page, KD), entry(j))
+                for j in range(per_step)]
+    out = pl.pallas_call(
+        functools.partial(_gqa_kernel, scale=scale, kv_heads=kv_heads, dim=dim,
+                          page=page, per_step=per_step, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(B, T // per_step),
+            in_specs=[q_spec] + kv_specs + kv_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((heads, 1), jnp.float32),     # m
+                pltpu.VMEM((heads, 1), jnp.float32),     # l
+                pltpu.VMEM((heads, dim), jnp.float32),   # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, heads, dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=regions.GQA_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      _held_pages(tables, last, per_step, first), first, last, base, positions,
+      q.reshape(B, heads, dim), *[k_pool] * per_step, *[v_pool] * per_step)
+    return out.reshape(B, HD)

@@ -41,8 +41,14 @@ latent, K and V at once, and :class:`LatentPrograms` (a prefill chunk
 that attends in the expanded form over the pages before its cursor, an
 absorbed decode step over the latent pages, an expert layer that is told
 which experts it holds), under the paged scheduler with the chunked
-prefill's cursor. The residency follows from the model
-(``serving_residency``): no option selects it.
+prefill's cursor. A model whose layers are of two kinds, window and global
+(``models.Cohere2MoEForCausalLM``), is served over the fifth:
+:class:`WindowedPagePools`, two page pools under one manager whose pages
+have two lifetimes (a window layer's page goes back as soon as the window
+has passed it), and :class:`WindowedPrograms` (parallel blocks, a
+grouped-query paged decode kernel that knows the window, the same expert
+layer), under a scheduler that keeps two tables a request. The residency
+follows from the model (``serving_residency``): no option selects it.
 
 Latency accounting (enqueue→admit→dispatch→complete, queue depth,
 p50/p99, requests/sec at FLAGS_serving_slo_ms, the prefill-vs-decode
@@ -58,9 +64,10 @@ step split and decode tokens/sec) flows through
     engine.shutdown(drain=True)
 """
 from .decode import (DecodeEngine, DecodePrograms, LatentPrograms,
-                     RetentionPrograms)
+                     RetentionPrograms, WindowedPrograms)
 from .engine import EngineBase, ServingEngine
-from .kv_cache import KVPagePool, KVSlotPool, StateLanePool
+from .kv_cache import (KVPagePool, KVSlotPool, StateLanePool,
+                       WindowedPagePools)
 from .request_queue import (AdmissionController, AdmissionError,
                             DecodeRequest, RejectedError, Request,
                             RequestQueue)
@@ -72,5 +79,6 @@ __all__ = [
     "DecodePrograms", "DecodeRequest", "DecodeScheduler", "EngineBase",
     "KVPagePool", "KVSlotPool", "LatentPrograms", "RejectedError", "Request", "RequestQueue",
     "RetentionPrograms", "Scheduler", "ServingEngine", "StateLanePool",
-    "scatter_outputs", "stack_requests",
+    "WindowedPagePools", "WindowedPrograms", "scatter_outputs",
+    "stack_requests",
 ]

@@ -47,12 +47,13 @@ from ..observability.locks import named_lock
 from ..profiler.pipeline import serving_stats
 from . import kv_cache as kvc
 from .engine import EngineBase
-from .kv_cache import KVPagePool, KVSlotPool, StateLanePool
+from .kv_cache import KVPagePool, KVSlotPool, StateLanePool, WindowedPagePools
 from .request_queue import DecodeRequest
-from .scheduler import DecodeScheduler, PagedDecodeScheduler
+from .scheduler import (DecodeScheduler, PagedDecodeScheduler,
+                        WindowedDecodeScheduler)
 
 __all__ = ["DecodeEngine", "DecodePrograms", "PagedDecodePrograms",
-           "RetentionPrograms", "LatentPrograms"]
+           "RetentionPrograms", "LatentPrograms", "WindowedPrograms"]
 
 
 def _extract_gpt(model):
@@ -833,6 +834,18 @@ class PagedDecodePrograms(DecodePrograms):
             return ck, cv, jnp.stack(vtoks, axis=1)
 
     # -------------------------------------------------------------- rungs
+    def _one_table_rung(self) -> List[int]:
+        """The table ladder of a family that prefills in chunks of whole
+        pages and decodes over the whole table (the kernel passes by the
+        entries a lane has no page for): ONE rung, whole blocks of the top
+        seq rung, so that no chunk's pages and no block's run past it."""
+        top, ps = self.seq_ladder[-1], self.pool.page_size
+        if any(c % ps for c in self.seq_ladder):
+            raise ValueError(f"a prefill chunk writes whole pages: every seq "
+                             f"bucket {self.seq_ladder} must be a multiple of "
+                             f"the page size {ps}")
+        return [-(-self.max_seq // top) * (top // ps)]
+
     def _prefill_table_cols(self, seq_rung: int) -> int:
         return -(-int(seq_rung) // self.pool.page_size)
 
@@ -1209,14 +1222,7 @@ class LatentPrograms(PagedDecodePrograms):
         super().__init__(model, pool, seq_ladder=seq_ladder,
                          prefill_batch_rungs=[1], decode_rungs=decode_rungs,
                          max_seq=max_seq)
-        top, ps = self.seq_ladder[-1], pool.page_size
-        if any(c % ps for c in self.seq_ladder):
-            raise ValueError(f"a prefill chunk writes whole pages: every seq "
-                             f"bucket {self.seq_ladder} must be a multiple of "
-                             f"the page size {ps}")
-        # whole blocks of the top rung, so that no chunk's pages and no
-        # block's run past the table
-        self.table_rungs = [-(-self.max_seq // top) * (top // ps)]
+        self.table_rungs = self._one_table_rung()
 
     def _bind_config(self, cfg) -> None:
         from ..nn.functional import latent_attention as la
@@ -1503,26 +1509,366 @@ class LatentPrograms(PagedDecodePrograms):
         ``tokens`` latent rows were written in each layer."""
         from ..observability.metrics import registry
 
-        pairs, hit = int(counts.sum()), int((counts > 0).sum())
-        registry.counter(
-            "serving.moe.pairs",
-            "token-expert pairs computed by the held experts").inc(pairs)
-        registry.counter(
-            "serving.moe.experts_hit",
-            "held experts that computed at least one pair, summed over "
-            "layers and steps").inc(hit)
         registry.counter(
             "serving.latent.rows_written",
             "latent cache rows written (one a token a layer)").inc(
                 int(tokens) * self.pool.num_layers)
-        return {"pairs": pairs, "experts_hit": hit}
+        return _note_experts(counts)
+
+
+def _note_experts(counts) -> dict:
+    """``counts`` ``[sparse layers, held]`` (on the host), the pairs each
+    held expert computed in one program call -> what the call's span says of
+    them (``pairs``, ``experts_hit``), and the registry's two counters."""
+    from ..observability.metrics import registry
+
+    pairs, hit = int(counts.sum()), int((counts > 0).sum())
+    registry.counter(
+        "serving.moe.pairs",
+        "token-expert pairs computed by the held experts").inc(pairs)
+    registry.counter(
+        "serving.moe.experts_hit",
+        "held experts that computed at least one pair, summed over "
+        "layers and steps").inc(hit)
+    return {"pairs": pairs, "experts_hit": hit}
+
+
+def _extract_cohere2(model):
+    """A ``models.cohere2_moe.Cohere2MoEForCausalLM``'s parameters as a plain
+    pytree, zero-copy: one tree a layer (nothing is stacked), the embedding
+    (which is the head too) and the final norm."""
+    return {
+        "embed": model.model.embed_tokens._value,
+        "norm": model.model.norm._value,
+        "layers": [{name: p._value for name, p in block._parameters.items()}
+                   for block in model.model.layers],
+    }, model.config
+
+
+def _ln_plain(x, w, eps):
+    """LayerNorm without a bias, the statistics in float32, the result in
+    ``x``'s dtype."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mean) ** 2, axis=-1, keepdims=True)
+    return ((xf - mean) * lax.rsqrt(var + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
+class WindowedPrograms(PagedDecodePrograms):
+    """The decode program set over a :class:`~.kv_cache.WindowedPagePools`,
+    for a model whose layers are parallel blocks over sparse experts, some
+    with a sliding window and rotary positions, some global and without
+    positions (``models/cohere2_moe.py``). Rungs, warm-up, the sampling
+    arguments, :meth:`_choose_tokens`, the token carry and the late read are
+    the paged family's; the expert layer is ``LatentPrograms``' (``route`` and
+    ``held_experts`` told the share, the pair counts returned beside the
+    tokens). What differs:
+
+    - FOUR pool arrays ride a call, the window pool's K and V and the global
+      pool's, and ONE table argument ``[rows, 2, cols]`` names a lane's
+      pages in each (``[:, 0]`` window, ``[:, 1]`` global; the same logical
+      columns, a released window column reading 0, the pad page);
+    - the layers are unrolled (they are of two kinds over two pools; a
+      layer's weights are arrays of their own, nothing is sliced out of a
+      stack) and each is a PARALLEL block: one LayerNorm feeds q, k, v, the
+      router and the experts, and attention and experts are both added to
+      the residual;
+    - ``("decode", b)``: one token a lane, its K/V row appended to the
+      layer's pool, then grouped-query attention over the lane's pages: on a
+      TPU the kernel ``gqa_paged_attn`` (``ops/pallas/paged_attention.py``),
+      told the layer's window or none, elsewhere ``gather_pages`` and
+      ``window_attention.attend_grouped``. One table rung, the whole table;
+    - ``("prefill", 1, c)``: ONE CHUNK of one lane's prompt at ``start``, a
+      multiple of the top rung: each layer writes the chunk's rows to whole
+      pages, then attends over blocks of top-rung keys under a running
+      softmax, a global layer over every block up to the chunk's own, a
+      window layer over the blocks its first query still sees (the chunk's
+      own and at most ``ceil((window - 1) / top)`` before it)."""
+
+    chunked = True
+    _extract = staticmethod(_extract_cohere2)
+    HEAD_GROUP = 8   # query heads whose scores exist at once in a prefill block
+
+    def __init__(self, model, pool: WindowedPagePools, *,
+                 seq_ladder: Sequence[int], decode_rungs: Sequence[int],
+                 max_seq: int):
+        block = model.model.layers[0]
+        self._first, self._held = int(block.first), int(block.held)
+        super().__init__(model, pool, seq_ladder=seq_ladder,
+                         prefill_batch_rungs=[1], decode_rungs=decode_rungs,
+                         max_seq=max_seq)
+        self.table_rungs = self._one_table_rung()
+
+    def _bind_config(self, cfg) -> None:
+        self._cfg = cfg
+        self._heads = int(cfg.num_attention_heads)
+        self._kv_heads = int(cfg.num_key_value_heads)
+        self._head_dim = int(cfg.head_dim)
+        self._hidden = int(cfg.hidden_size)
+        self._max_pos = int(cfg.max_position_embeddings)
+        self._eps = float(cfg.layer_norm_eps)
+        self._theta = float(cfg.rope_theta)
+        self._scale = 1.0 / math.sqrt(cfg.head_dim)
+        self._logit_scale = float(cfg.logit_scale)
+        # a layer's window (None: global) and its index in ITS pool
+        self._windows = [cfg.window_of(i) for i in range(cfg.num_hidden_layers)]
+        seen = {True: 0, False: 0}
+        self._pool_index = []
+        for w in self._windows:
+            self._pool_index.append(seen[w is not None])
+            seen[w is not None] += 1
+        pool = self.pool
+        if (seen[True] != pool.window.num_layers or seen[False] != pool.full.num_layers
+                or pool.window.row_width != self._kv_heads * self._head_dim
+                or any(w != pool.window_rows for w in self._windows if w)):
+            raise ValueError(
+                f"the pools hold {pool.window.num_layers} window and "
+                f"{pool.full.num_layers} global layers of rows "
+                f"{pool.window.row_width} wide under a window of "
+                f"{pool.window_rows}; the model has {seen[True]} and "
+                f"{seen[False]} of {self._kv_heads * self._head_dim} under "
+                f"{cfg.sliding_window}")
+
+    # ------------------------------------------------------------ the block
+    def _qkv(self, w, x, positions, window):
+        """``x`` ``[N, hidden]`` at ``positions`` ``[N]`` -> the block's norm
+        ``n`` and q ``[N, heads x d]``, k, v ``[N, kv_heads x d]``; q and k
+        rotated in a window layer, left alone in a global one."""
+        from ..nn.functional import window_attention as wa
+
+        N, d = x.shape[0], self._head_dim
+        with region(regions.LN):
+            n = _ln_plain(x, w["ln"], self._eps)
+        with region(regions.ATTN_QKV):
+            q, k, v = n @ w["q_proj"], n @ w["k_proj"], n @ w["v_proj"]
+        if window is not None:
+            with region(regions.ROPE):
+                q, k = (wa.rope_interleaved(a.reshape(N, -1, d), positions,
+                                            self._theta).astype(a.dtype).reshape(N, -1)
+                        for a in (q, k))
+        return n, q, k, v
+
+    def _ffn(self, w, n, valid):
+        """The block's experts on its norm ``n`` ``[N, hidden]``: the held
+        experts' part and the shared experts' mean. ``valid`` ``[N]`` is False
+        for a padding token, which picks no expert. Returns the sum and the
+        held experts' pair counts."""
+        import jax.numpy as jnp
+
+        from ..nn.functional import sparse_experts as se
+
+        cfg = self._cfg
+        idx, wt = se.route(n, w["router"], n_group=1, topk_group=1,
+                           top_k=cfg.num_experts_per_tok, scaling=1.0,
+                           norm_topk=cfg.norm_topk_prob, group_limited=False)
+        idx = jnp.where(valid[:, None], idx, -1)
+        routed, counts = se.held_experts(
+            n, idx, wt, w["experts_gate_up"], w["experts_down"],
+            first=self._first, held=self._held)
+        with region(regions.MOE_SHARED):
+            shared = se.swiglu(n, w["shared_gate_up"], w["shared_down"])
+            shared = shared * (1.0 / cfg.num_shared_experts)
+        return shared + routed, counts
+
+    def _layers(self, params, x, positions, pools, attend, valid):
+        """Every layer, unrolled, on ``x`` ``[N, hidden]`` at ``positions``
+        ``[N]``: ``attend(li, window, q, k, v, kp, vp)`` writes the layer's
+        pool and returns ``(attention [N, heads x d], kp, vp)``. ``pools``
+        ``(wk, wv, fk, fv)``. Returns ``x``, the pools and the pair counts
+        ``[layers, held]``."""
+        import jax.numpy as jnp
+
+        wk, wv, fk, fv = pools
+        counts = []
+        for li, w in enumerate(params["layers"]):
+            window = self._windows[li]
+            n, q, k, v = self._qkv(w, x, positions, window)
+            if window is not None:
+                att, wk, wv = attend(li, window, q, k, v, wk, wv)
+            else:
+                att, fk, fv = attend(li, window, q, k, v, fk, fv)
+            with region(regions.ATTN_OUT):
+                att = att @ w["o_proj"]
+            ffn, c = self._ffn(w, n, valid)
+            x = x + att + ffn
+            counts.append(c)
+        return x, (wk, wv, fk, fv), jnp.stack(counts)
+
+    def _logits_head(self, params, x):
+        with region(regions.LM_HEAD):
+            h = _ln_plain(x, params["norm"], self._eps) @ params["embed"].T
+            return h if self._logit_scale == 1 else h * self._logit_scale
+
+    # ------------------------------------------------------------ attention
+    def _attend_chunk(self, q, kp, vp, li, table, start, window):
+        """A chunk's ``C`` queries at ``start .. start + C - 1`` (``q`` ``[C,
+        heads x d]``) over the lane's pages of pool layer ``li``, the chunk's
+        own rows already written: blocks of ``top rung`` keys (``start`` is a
+        multiple of it, so the chunk lies in the last block), each gathered
+        from the pool; a window layer starts at the first block its first
+        query can see. ``table`` ``[T]``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        from ..nn.functional import window_attention as wa
+
+        C = q.shape[0]
+        G, d = self._kv_heads, self._head_dim
+        blk, ps = self.seq_ladder[-1], self.pool.page_size
+        qpos = start + jnp.arange(C, dtype=jnp.int32)
+        last = start // blk
+        first = (jnp.zeros((), jnp.int32) if window is None else
+                 jnp.maximum(last - (-(-(window - 1) // blk)), 0))
+        # a K/V head's query heads in `parts` runs of HEAD_GROUP: so many
+        # heads' scores exist at once, [C x HEAD_GROUP, blk] in float32
+        parts = max(self._heads // G // self.HEAD_GROUP, 1)
+        qg = q.reshape(C, G * parts, -1, d).transpose(1, 0, 2, 3)
+
+        def block(j, carry):
+            with region(regions.ATTN_KV_GATHER):
+                pages = lax.dynamic_slice(table, (j * (blk // ps),), (blk // ps,))
+                k, v = (jnp.repeat(a[li, pages].reshape(blk, G, d).transpose(1, 0, 2),
+                                   parts, axis=0) for a in (kp, vp))
+            col = j * blk + jnp.arange(blk, dtype=jnp.int32)
+            seen = col[None, :] <= qpos[:, None]
+            if window is not None:
+                seen &= qpos[:, None] - col[None, :] < window
+            with region(regions.ATTN_CORE):
+                return wa.grouped_block(carry, qg, k, v, seen, self._scale)
+
+        carry = lax.fori_loop(first, last + 1, block,
+                              wa.start_blocks(C, G * parts, qg.shape[2], d))
+        with region(regions.ATTN_CORE):
+            return wa.finish_blocks(carry, q.dtype)
+
+    def _attend_step(self, q, kp, vp, li, tables, positions, window):
+        """One query a lane (``q`` ``[B, heads x d]`` at ``positions``) over
+        its pages of pool layer ``li``, the step's own rows already written."""
+        from ..nn.functional import window_attention as wa
+
+        if self._kernel():
+            from ..ops.pallas import paged_attention as kernel
+
+            with region(regions.ATTN_CORE):
+                return kernel.gqa_paged_attention(
+                    q, kp, vp, li, tables, positions, kv_heads=self._kv_heads,
+                    scale=self._scale, window=window)
+        keys = kvc.gather_pages(kp, li, tables)
+        vals = kvc.gather_pages(vp, li, tables)
+        with region(regions.ATTN_CORE):
+            return wa.attend_grouped(q, keys, vals, positions, self._kv_heads,
+                                     self._scale, window)
+
+    # ------------------------------------------------------------ programs
+    def _prefill_fn(self, params, wk, wv, fk, fv, tokens, lengths, tables,
+                    starts, temps, top_ks, top_ps, rkeys):
+        import jax.numpy as jnp
+        from jax import lax
+
+        self.traces += 1
+        with region(regions.PREFILL):
+            C, ps = tokens.shape[1], self.pool.page_size
+            n, start = lengths[0], starts[0]
+            with region(regions.EMBED):
+                x = params["embed"][tokens[0]]
+            with region(regions.ATTN_KV_WRITE):
+                # the chunk's pages in each table: whole ones, `start` and
+                # `C` being multiples of the page size; entries past the
+                # lane's own are 0, the pad page
+                pages = [lax.dynamic_slice(tables[0, kind], (start // ps,), (C // ps,))
+                         for kind in (0, 1)]
+
+            def attend(li, window, q, k, v, kp, vp):
+                kind, pli = int(window is None), self._pool_index[li]
+                kp = kvc.write_chunk_pages(kp, pli, pages[kind], k)
+                vp = kvc.write_chunk_pages(vp, pli, pages[kind], v)
+                return (self._attend_chunk(q, kp, vp, pli, tables[0, kind],
+                                           start, window), kp, vp)
+
+            x, pools, counts = self._layers(
+                params, x, start + jnp.arange(C, dtype=jnp.int32),
+                (wk, wv, fk, fv), attend, jnp.arange(C) < n)
+            # the token after the chunk's LAST VALID position
+            with region(regions.LM_HEAD):
+                x_last = lax.dynamic_slice_in_dim(x, n - 1, 1, axis=0)
+            head = self._logits_head(params, x_last)
+            return (*pools, self._choose_tokens(head, temps, top_ks, top_ps,
+                                                rkeys), counts)
+
+    def _decode_fn(self, params, wk, wv, fk, fv, tokens, tables, positions,
+                   temps, top_ks, top_ps, rkeys):
+        import jax.numpy as jnp
+
+        self.traces += 1
+        with region(regions.DECODE):
+            ps = self.pool.page_size
+            with region(regions.EMBED):
+                x = params["embed"][tokens]
+            with region(regions.ATTN_KV_WRITE):
+                col = (positions // ps).astype(jnp.int32)[:, None, None]
+                pages = jnp.take_along_axis(tables, col, axis=2)[:, :, 0]   # [B, 2]
+                offsets = (positions % ps).astype(jnp.int32)
+
+            def attend(li, window, q, k, v, kp, vp):
+                kind, pli = int(window is None), self._pool_index[li]
+                kp = kvc.append_token_paged(kp, pli, pages[:, kind], offsets, k)
+                vp = kvc.append_token_paged(vp, pli, pages[:, kind], offsets, v)
+                return (self._attend_step(q, kp, vp, pli, tables[:, kind],
+                                          positions, window), kp, vp)
+
+            # a padded batch lane's tables name the pad page only
+            x, pools, counts = self._layers(
+                params, x, positions, (wk, wv, fk, fv), attend,
+                tables[:, 1, 0] != self.pool.pad_page)
+            head = self._logits_head(params, x)
+            return (*pools, self._choose_tokens(head, temps, top_ks, top_ps,
+                                                rkeys), counts)
+
+    # -------------------------------------------------------------- rungs
+    @property
+    def rungs(self) -> List[tuple]:
+        return ([("decode", b) for b in self.decode_rungs]
+                + [("prefill", 1, c) for c in self.seq_ladder]
+                + self.carry_rungs)
+
+    def _zero_args(self, key):
+        t = self.table_rungs[-1]
+        if key[0] == "decode":
+            tokens, _, *rest = PagedDecodePrograms._zero_args(
+                self, ("decode", key[1], t))
+            return (tokens, np.zeros((key[1], 2, t), np.int32), *rest)
+        tokens, lengths, _, *sample = PagedDecodePrograms._zero_args(
+            self, ("prefill", 1, key[2]))
+        return (tokens, lengths, np.zeros((1, 2, t), np.int32),
+                np.zeros(1, np.int32), *sample)
+
+    # -------------------------------------------------------------- calls
+    def prefill(self, wk, wv, fk, fv, tokens, lengths, tables, starts,
+                temps, top_ks, top_ps, rkeys):
+        return self._jit_prefill(self.params, wk, wv, fk, fv, tokens, lengths,
+                                 tables, starts, temps, top_ks, top_ps, rkeys)
+
+    def decode(self, wk, wv, fk, fv, tokens, tables, positions,
+               temps, top_ks, top_ps, rkeys):
+        return self._jit_decode(self.params, wk, wv, fk, fv, tokens, tables,
+                                positions, temps, top_ks, top_ps, rkeys)
+
+    def note_step(self, counts, tokens: int) -> dict:
+        """``counts`` ``[layers, held]`` -> the span's ``pairs`` and
+        ``experts_hit``, as the latent family says them."""
+        return _note_experts(counts)
 
 
 class DecodeEngine(EngineBase):
     """Decode serving with true continuous batching.
 
     ``model`` is a live ``models.gpt.GPTForCausalLM``,
-    ``models.brumby.BrumbyForCausalLM`` or ``models.axk1.AXK1ForCausalLM``
+    ``models.brumby.BrumbyForCausalLM``, ``models.axk1.AXK1ForCausalLM`` or
+    ``models.cohere2_moe.Cohere2MoEForCausalLM``
     (eval mode; its device weights are shared zero-copy with
     training/export users).
     Requests (:meth:`submit`) join the running batch at the next step
@@ -1543,8 +1889,12 @@ class DecodeEngine(EngineBase):
     state), or latent rows in pages, one a token a layer, K and V at once
     (``"latent"``: :class:`LatentPrograms` over a one-array
     :class:`~.kv_cache.KVPagePool`, chunked prefill over the pages before
-    the cursor, page and pool sizes from ``page_size``/``pool_pages``).
-    Four program families on one chassis; for a GPT, two KV residency
+    the cursor, page and pool sizes from ``page_size``/``pool_pages``), or
+    K/V pages of two lifetimes (``"windowed"``: :class:`WindowedPrograms`
+    over a :class:`~.kv_cache.WindowedPagePools`, the window layers' pages
+    given back as the window passes them, ``window_pool_pages`` of them
+    beside the global layers' ``pool_pages``).
+    Five program families on one chassis; for a GPT, two KV residency
     modes (``kv_mode``):
 
     - ``"paged"`` (default, ISSUE 18): a :class:`~.kv_cache.KVPagePool`
@@ -1571,6 +1921,7 @@ class DecodeEngine(EngineBase):
                  kv_mode: str = "paged",
                  page_size: Optional[int] = None,
                  pool_pages: Optional[int] = None,
+                 window_pool_pages: Optional[int] = None,
                  speculate_k: Optional[int] = None,
                  spec_draft_layers: Optional[int] = None,
                  spec_min_accept: Optional[float] = None,
@@ -1593,7 +1944,7 @@ class DecodeEngine(EngineBase):
         # recurrent state (``serving_residency = "state"``) has no keys
         # and values to page or slot, whatever ``kv_mode`` says
         residency = getattr(model, "serving_residency", "kv")
-        if residency in ("state", "latent"):
+        if residency in ("state", "latent", "windowed"):
             kv_mode = residency
         max_slots = int(get_flag("serving_max_slots")
                         if max_slots is None else max_slots)
@@ -1607,7 +1958,7 @@ class DecodeEngine(EngineBase):
         prefill_max = int(get_flag("serving_prefill_max_batch")
                           if prefill_max_batch is None else prefill_max_batch)
         prefill_max = min(prefill_max, max_slots)
-        if seq_buckets is None and kv_mode in ("state", "latent"):
+        if seq_buckets is None and kv_mode in ("state", "latent", "windowed"):
             seq_buckets = [min(2048, max_seq)]  # the prefill chunk
         if seq_buckets is None:
             seq_min = min(int(get_flag("serving_seq_bucket_min")), max_seq)
@@ -1629,10 +1980,13 @@ class DecodeEngine(EngineBase):
                 "lane has no rollback, and a latent-attention model's "
                 "draft would be a truncated stack of the same weights "
                 "whose layer 0 is another kind of layer (dense) than the "
-                "rest — use kv_mode='paged' (a GPT) for speculate_k > 0")
+                "rest, and a window table cannot be rolled back (a page "
+                "released as the window passed it is another lane's by the "
+                "time a draft is refused) — use kv_mode='paged' (a GPT) for "
+                "speculate_k > 0")
         self.kv_mode = kv_mode
         #: the residencies that hold pages named by block tables
-        self._pages = kv_mode in ("paged", "latent")
+        self._pages = kv_mode in ("paged", "latent", "windowed")
         self.max_slots = max_slots  # max concurrent lanes in either mode
         self.eos_id = eos_id
         self.speculate_k = spec_k
@@ -1640,6 +1994,17 @@ class DecodeEngine(EngineBase):
         from ..reliability.policy import RetryPolicy
 
         retry = RetryPolicy("serving.decode_step")
+
+        def paged_sizes():
+            """The page size and the count of pages held for a request's
+            life: the arguments, else the flags, else (equal bytes) the
+            token capacity a slot pool of ``max_slots`` full rows holds."""
+            ps = int(get_flag("serving_page_size")
+                     if page_size is None else page_size)
+            n_pages = int(get_flag("serving_pool_pages")
+                          if pool_pages is None else pool_pages)
+            return ps, n_pages if n_pages > 0 else -(-max_slots * max_seq // ps)
+
         if kv_mode == "state":
             self.kv_pool = StateLanePool(
                 cfg.num_hidden_layers, max_slots, cfg.num_key_value_heads,
@@ -1653,12 +2018,7 @@ class DecodeEngine(EngineBase):
                 prefill_max_batch=1, eos_id=eos_id, stats=stats,
                 retry=retry, breakers=self.breakers)
         elif kv_mode == "latent":
-            ps = int(get_flag("serving_page_size")
-                     if page_size is None else page_size)
-            n_pages = int(get_flag("serving_pool_pages")
-                          if pool_pages is None else pool_pages)
-            if n_pages <= 0:
-                n_pages = -(-max_slots * max_seq // ps)
+            ps, n_pages = paged_sizes()
             # one array; a row is [c_kv | k_rope], padded to whole lanes
             self.kv_pool = KVPagePool(
                 cfg.num_hidden_layers, n_pages, ps, dtype=kv_dtype,
@@ -1668,6 +2028,28 @@ class DecodeEngine(EngineBase):
                 decode_rungs=powers_of_two_buckets(1, max_slots),
                 max_seq=max_seq)
             self._scheduler = PagedDecodeScheduler(
+                self.queue, self.programs, self.kv_pool,
+                max_lanes=max_slots, prefill_max_batch=1, eos_id=eos_id,
+                stats=stats, retry=retry, breakers=self.breakers)
+        elif kv_mode == "windowed":
+            ps, n_pages = paged_sizes()
+            windows = [cfg.window_of(i) for i in range(cfg.num_hidden_layers)]
+            n_window = sum(w is not None for w in windows)
+            if window_pool_pages is None:
+                # never what decides admission: every lane's window and one
+                # prefill chunk's own pages on top
+                window_pool_pages = (
+                    max_slots * kvc.window_columns(cfg.sliding_window, ps)
+                    + -(-seq_buckets[-1] // ps))
+            self.kv_pool = WindowedPagePools(
+                n_window, len(windows) - n_window, int(window_pool_pages),
+                n_pages, ps, cfg.num_key_value_heads, cfg.head_dim,
+                cfg.sliding_window, dtype=kv_dtype)
+            self.programs = WindowedPrograms(
+                model, self.kv_pool, seq_ladder=seq_buckets,
+                decode_rungs=powers_of_two_buckets(1, max_slots),
+                max_seq=max_seq)
+            self._scheduler = WindowedDecodeScheduler(
                 self.queue, self.programs, self.kv_pool,
                 max_lanes=max_slots, prefill_max_batch=1, eos_id=eos_id,
                 stats=stats, retry=retry, breakers=self.breakers)
@@ -1685,14 +2067,7 @@ class DecodeEngine(EngineBase):
                 prefill_max_batch=prefill_max, eos_id=eos_id, stats=stats,
                 retry=retry, breakers=self.breakers)
         else:
-            ps = int(get_flag("serving_page_size")
-                     if page_size is None else page_size)
-            n_pages = int(get_flag("serving_pool_pages")
-                          if pool_pages is None else pool_pages)
-            if n_pages <= 0:
-                # equal-bytes default: the token capacity the slot pool
-                # this replaces would have held (max_slots full rows)
-                n_pages = -(-max_slots * max_seq // ps)
+            ps, n_pages = paged_sizes()
             self.kv_pool = KVPagePool(
                 cfg.num_hidden_layers, n_pages, ps,
                 cfg.num_attention_heads, cfg.head_dim, dtype=kv_dtype)
@@ -1776,10 +2151,13 @@ class DecodeEngine(EngineBase):
                 "seq ladder")
         if self._pages:
             need = -(-int(req.prompt.size) // self.kv_pool.page_size)
-            if need > self.kv_pool.num_pages:
+            # pages held for a request's life: where the pool knows two
+            # lifetimes, those of its global layers
+            held = getattr(self.kv_pool, "full", self.kv_pool).num_pages
+            if need > held:
                 raise ValueError(
                     f"prompt needs {need} KV pages but the pool holds "
-                    f"{self.kv_pool.num_pages} total; it could never be "
+                    f"{held} total; it could never be "
                     "admitted — raise FLAGS_serving_pool_pages")
         self.tenant(tenant)
         return self.queue.submit(req)

@@ -39,7 +39,8 @@ from ..base import regions
 from ..base.regions import region
 from ..observability.locks import named_lock
 
-__all__ = ["LanePool", "KVSlotPool", "KVPagePool", "StateLanePool", "write_prompt",
+__all__ = ["LanePool", "KVSlotPool", "KVPagePool", "WindowedPagePools",
+           "window_columns", "StateLanePool", "write_prompt",
            "write_prompt_batch", "append_token", "write_prompt_pages",
            "append_token_paged", "write_chunk_pages", "gather_pages"]
 
@@ -400,7 +401,7 @@ class KVPagePool:
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_heads: Optional[int] = None, head_dim: Optional[int] = None,
                  dtype="float32", *, row_width: Optional[int] = None,
-                 arrays: int = 2):
+                 arrays: int = 2, kind: Optional[str] = None):
         import jax.numpy as jnp
 
         if num_pages < 1:
@@ -419,6 +420,7 @@ class KVPagePool:
         self.num_heads = None if num_heads is None else int(num_heads)
         self.head_dim = None if head_dim is None else int(head_dim)
         self.row_width = int(row_width)
+        self.kind = kind   # names the occupancy gauge where pools of two kinds live side by side
         # +1: page 0 is the pad page — never allocated, absorbs garbage
         shape = (self.num_layers, self.num_pages + 1, self.page_size,
                  self.row_width)
@@ -562,7 +564,109 @@ class KVPagePool:
         from ..observability.metrics import registry
 
         registry.gauge(
-            "serving.kv_pages_in_use",
+            "serving.kv_pages_in_use" + (f".{self.kind}" if self.kind else ""),
             "KV cache pages currently allocated to live decode "
             "sequences (capacity = pool num_pages)").set(
                 self.num_pages - len(self._free))
+
+
+# ----------------------------------------------------- two page lifetimes
+def window_columns(window: int, page_size: int) -> int:
+    """The most table columns that hold a row some query sees through a
+    window of ``window`` keys (its own among them): the query's page and
+    the pages of the ``window - 1`` rows behind it."""
+    return (int(window) - 1 + page_size - 1) // page_size + 1
+
+
+class WindowedPagePools:
+    """The cache manager of a model whose layers are of two kinds
+    (``models/cohere2_moe.py``): *global* layers attend over the whole
+    sequence, so a token's K/V row lives as long as its request; *window*
+    layers attend over the last ``window`` rows, so a page of theirs is dead
+    once every row of it lies more than ``window - 1`` positions behind the
+    next query. One table a request no longer describes every layer. Two
+    :class:`KVPagePool` s of the same page size, ``window`` over the window
+    layers and ``full`` over the global ones, and two tables a request
+    (``DecodeRequest.window_pages`` beside ``.pages``, the same logical
+    columns; a released column names the pad page, so a table walk stays a
+    walk). At a 32k context a lane then holds ``window / page + 1`` window
+    pages a window layer instead of 128.
+
+    To the scheduler this IS a page pool of the request-long kind:
+    ``alloc``/``release``/``free_count`` mean global pages, as
+    :class:`~.scheduler.PagedDecodeScheduler` has always meant them, and the
+    window pages are taken and given back through :attr:`window` by the
+    scheduler that knows two lifetimes. What is asked of the manager WHOLE
+    answers for both kinds together: :meth:`arrays` and :meth:`commit` (the
+    window pool's arrays, then the global pool's: the programs take and
+    return them in that order), :meth:`in_use`, :attr:`num_pages`,
+    :meth:`device_bytes` and the warm-up baseline, so that a leak audit or
+    a peak-occupancy reader sees one manager."""
+
+    def __init__(self, window_layers: int, full_layers: int,
+                 window_pages: int, full_pages: int, page_size: int,
+                 num_heads: int, head_dim: int, window: int, dtype="float32"):
+        if window < 1:
+            raise ValueError("a window holds at least the query's own row")
+        self.window_rows = int(window)
+        self.window = KVPagePool(window_layers, window_pages, page_size,
+                                 num_heads, head_dim, dtype, kind="window")
+        self.full = KVPagePool(full_layers, full_pages, page_size,
+                               num_heads, head_dim, dtype, kind="full")
+        self.page_size = int(page_size)
+        self.bytes_at_warmup: Optional[int] = None
+
+    @property
+    def window_columns(self) -> int:
+        """The most window pages a lane holds between two program calls
+        (:func:`window_columns`)."""
+        return window_columns(self.window_rows, self.page_size)
+
+    def first_live_column(self, position: int) -> int:
+        """The first table column whose page holds a row that a query at
+        ``position`` (or any later one) can still see."""
+        return max(int(position) - (self.window_rows - 1), 0) // self.page_size
+
+    # ------------------------------------------- global pages, as one pool
+    @property
+    def pad_page(self) -> int:
+        return self.full.pad_page
+
+    def alloc(self, n: int = 1) -> List[int]:
+        return self.full.alloc(n)
+
+    def release(self, pages: Iterable[int]) -> None:
+        self.full.release(pages)
+
+    def free_count(self) -> int:
+        return self.full.free_count()
+
+    def note_utilization(self, live_tokens: int) -> None:
+        self.full.note_utilization(live_tokens)
+
+    def utilization_report(self) -> dict:
+        return self.full.utilization_report()
+
+    # ------------------------------------------------ both kinds together
+    @property
+    def num_pages(self) -> int:
+        return self.window.num_pages + self.full.num_pages
+
+    def in_use(self) -> int:
+        return self.window.in_use() + self.full.in_use()
+
+    def arrays(self) -> tuple:
+        return self.window.arrays() + self.full.arrays()
+
+    def commit(self, *new) -> None:
+        held = len(self.window.arrays())
+        self.window.commit(*new[:held])
+        self.full.commit(*new[held:])
+
+    def device_bytes(self) -> int:
+        return self.window.device_bytes() + self.full.device_bytes()
+
+    def mark_warm(self) -> None:
+        self.window.mark_warm()
+        self.full.mark_warm()
+        self.bytes_at_warmup = self.device_bytes()

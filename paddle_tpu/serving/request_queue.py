@@ -128,7 +128,8 @@ class DecodeRequest(Request):
     denominated in slots for the decode tier."""
 
     __slots__ = ("prompt", "max_new_tokens", "generated", "sent", "row",
-                 "slot", "seq_rung", "cursor", "pages", "temperature", "top_k",
+                 "slot", "seq_rung", "cursor", "pages", "window_pages",
+                 "window_from", "temperature", "top_k",
                  "top_p", "seed", "speculate", "spec_live", "spec_proposed",
                  "spec_accepted", "t_first_token")
 
@@ -159,6 +160,11 @@ class DecodeRequest(Request):
         self.seq_rung = None      # prefill seq-ladder rung (scheduler set)
         self.cursor = 0           # prompt tokens already prefilled (chunked programs)
         self.pages: List[int] = []  # block table (paged pools only)
+        # a second table over the same logical columns, for window layers
+        # (two page lifetimes): columns ``< window_from`` were released and
+        # read 0, the pad page
+        self.window_pages: List[int] = []
+        self.window_from = 0
         # sampling knobs ride the programs as traced DATA (never a
         # retrace); temperature 0 = greedy, the bit-exact audit mode
         self.temperature = float(temperature)

@@ -776,6 +776,7 @@ class DecodeScheduler:
         self._owe_decode = True
         tokens = np.zeros((1, rung), np.int32)
         tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
+        says = self._chunk_says(r, n)
         args = self._chunk_args(r, n)
         if args is None:
             # the chunk waits (for pages): the decoding lanes go first
@@ -786,13 +787,19 @@ class DecodeScheduler:
                          *self.pool.arrays(), tokens, np.asarray([n], np.int32),
                          *args),
                      emits=r.cursor + n >= size, rows=n,
-                     chunk=r.cursor // top, chunks=-(-size // top), tokens=n)
+                     chunk=r.cursor // top, chunks=-(-size // top), tokens=n,
+                     **says)
         r.cursor += n
         registry.counter(
             "serving.prefill_chunks",
             "prefill chunks run by the decode scheduler (a prompt longer "
             "than the chunk rung takes several beats)").inc()
         return call
+
+    def _chunk_says(self, r, n: int) -> dict:
+        """What a chunk's span says of its residency beside ``chunk``,
+        ``chunks`` and ``tokens``: nothing here."""
+        return {}
 
     def _chunk_args(self, r, n: int):
         """What a chunk's program call takes after the tokens and their
@@ -954,15 +961,18 @@ class PagedDecodeScheduler(DecodeScheduler):
         # (`_chunk_args`); what the pending prompts still lack is theirs
         # already, so that a prompt admitted now finds its pages later
         chunked = getattr(self.programs, "chunked", False)
-        owed = sum(max(-(-int(r.prompt.size) // self.pool.page_size)
-                       - len(r.pages), 0) for r in self._pending)
-        budget = [self.pool.free_count() - owed]
+        pending = [(self._page_need(r), self._page_held(r))
+                   for r in self._pending]
+        # by kind of page (one kind, unless the pool knows two lifetimes)
+        budget = [free - sum(max(need[i] - held[i], 0) for need, held in pending)
+                  for i, free in enumerate(self._page_free())]
 
         def fits(r):
-            need = -(-int(r.prompt.size) // self.pool.page_size)
-            if need > budget[0]:
+            need = self._page_need(r)
+            if any(n > b for n, b in zip(need, budget)):
                 return False
-            budget[0] -= need
+            for i, n in enumerate(need):
+                budget[i] -= n
             return True
 
         taken = self.queue.take_slots(
@@ -992,8 +1002,7 @@ class PagedDecodeScheduler(DecodeScheduler):
 
         need = -(-(r.cursor + n) // self.pool.page_size)
         try:
-            if need > len(r.pages):
-                r.pages.extend(self.pool.alloc(need - len(r.pages)))
+            self._grow(r, need)
         except FaultInjection as e:
             self._shed(r, e)
             return None
@@ -1004,10 +1013,59 @@ class PagedDecodeScheduler(DecodeScheduler):
             else:   # no lane can step, so no retirement will come
                 self._shed(r, e)
             return None
-        tables = np.zeros((1, self.programs.table_rungs[-1]), np.int32)
-        tables[0, :len(r.pages)] = r.pages
+        tables = self._tables([r], 1, self.programs.table_rungs[-1])
+        # the call reads its table as built; what no later query sees can go
+        self._trim(r, r.cursor + n)
         return (tables, np.asarray([r.cursor], np.int32),
                 *self._sample_args([r], 1))
+
+    # ------------------------------------------------- pages, by lifetime
+    def _page_need(self, r) -> tuple:
+        """The pages the request's prompt takes, by kind of page: one kind
+        here, held for the request's life."""
+        return (-(-int(r.prompt.size) // self.pool.page_size),)
+
+    def _page_held(self, r) -> tuple:
+        return (len(r.pages),)
+
+    def _page_free(self) -> tuple:
+        return (self.pool.free_count(),)
+
+    def _columns(self, r) -> int:
+        """The logical table columns the lane holds a page for."""
+        return len(r.pages)
+
+    def _grow(self, r, need: int) -> None:
+        """The lane's table grown to ``need`` columns; raises what the
+        pool's ``alloc`` raises, a kind of page then grown whole or not."""
+        if need > len(r.pages):
+            r.pages.extend(self.pool.alloc(need - len(r.pages)))
+
+    #: whether pages go back before their request retires (:meth:`_trim`)
+    _trims = False
+
+    def _trim(self, r, position: int) -> None:
+        """Give back what no query at ``position`` or later can see:
+        nothing, where every page lives as long as its request."""
+
+    def _tables(self, lanes, rows: int, cols: int):
+        """The call's block tables, ``[rows, cols]``, lane ``i`` in row
+        ``i``; 0 is the pad page."""
+        tables = np.zeros((rows, cols), np.int32)
+        for i, r in enumerate(lanes):
+            tables[i, :len(r.pages)] = r.pages
+        return tables
+
+    def _pages_said(self, positions, rows: int, cols: int) -> dict:
+        """What a decode step's span says of its tables: ``pages_table``
+        entries (batch rung x table rung), ``pages_live`` of them naming a
+        page that holds a column its lane may see (``positions``: each
+        lane's last visible one). The stats keep both sums."""
+        pages = {"pages_live": int((positions // self.pool.page_size + 1).sum()),
+                 "pages_table": rows * cols}
+        if self.stats is not None:
+            self.stats.record_pages(**pages)
+        return pages
 
     def _shed(self, r, cause) -> None:
         """Page-allocation failure sheds ONE request: its pages return
@@ -1068,9 +1126,11 @@ class PagedDecodeScheduler(DecodeScheduler):
         for r in lanes:
             last = min(int(r.position) + lookahead, self.max_seq - 1)
             need = last // self.pool.page_size + 1
+            if self._trims:
+                self._trim(r, int(r.position))
             try:
-                while len(r.pages) < need:
-                    r.pages.extend(self.pool.alloc(1))
+                while self._columns(r) < need:
+                    self._grow(r, self._columns(r) + 1)
             except FaultInjection as e:
                 self._shed(r, e)
                 continue
@@ -1169,18 +1229,14 @@ class PagedDecodeScheduler(DecodeScheduler):
         t_rung = bucket_for(max(len(r.pages) for r in lanes),
                             self.programs.table_rungs)
         tokens = np.zeros(b_rung, np.int32)
-        tables = np.zeros((b_rung, t_rung), np.int32)  # 0 = pad page
+        tables = self._tables(lanes, b_rung, t_rung)
         positions = np.zeros(b_rung, np.int32)
         for i, r in enumerate(lanes):
             tokens[i] = self._token_of(r)
-            tables[i, :len(r.pages)] = r.pages
             positions[i] = r.position
         last = np.minimum(positions[:len(lanes)] + lookahead,
                           self.max_seq - 1)
-        pages = {"pages_live": int((last // self.pool.page_size + 1).sum()),
-                 "pages_table": b_rung * t_rung}
-        if self.stats is not None:
-            self.stats.record_pages(**pages)
+        pages = self._pages_said(last, b_rung, t_rung)
         return lanes, (b_rung, t_rung), tokens, tables, positions, pages
 
     def _build_decode(self) -> Optional[_Call]:
@@ -1324,3 +1380,93 @@ class PagedDecodeScheduler(DecodeScheduler):
         self.pool.note_utilization(sum(int(r.prompt.size) + r.sent
                                        for r in held))
         return len(held), self.max_lanes
+
+
+class WindowedDecodeScheduler(PagedDecodeScheduler):
+    """The paged decode loop over a :class:`~.kv_cache.WindowedPagePools`:
+    a request holds pages of TWO lifetimes. Its global-layer pages
+    (``r.pages``) are the paged scheduler's, taken as the sequence grows and
+    held until it retires. Its window-layer pages (``r.window_pages``, the
+    same logical columns) are taken with them and given back as soon as the
+    window has passed them: before a lane's decode step, and right after a
+    prefill chunk's table is built (the call reads the table as built, and
+    the device runs calls in order, so a page given back now is rewritten
+    only by a later call). A released column reads 0, the pad page;
+    ``r.window_from`` counts them. Between two program calls a lane holds at
+    most ``pool.window_columns`` window pages; a prefill chunk's call holds
+    the chunk's own on top of the window behind its first query.
+
+    Admission counts both kinds. A program call takes ONE table argument
+    ``[rows, 2, cols]``: ``[:, 0]`` the window table, ``[:, 1]`` the global
+    one. A step's span says ``window_pages_live`` beside ``pages_live``."""
+
+    _trims = True
+
+    def _prefill_window_columns(self) -> int:
+        """The most window pages a prompt holds at once: the window behind
+        a chunk's first query (whole pages) and the chunk's own."""
+        chunk = -(-self.programs.seq_ladder[-1] // self.pool.page_size)
+        return self.pool.window_columns - 1 + chunk
+
+    def _page_need(self, r) -> tuple:
+        (need,) = super()._page_need(r)
+        return need, min(need, self._prefill_window_columns())
+
+    def _page_held(self, r) -> tuple:
+        return len(r.pages), len(r.window_pages) - r.window_from
+
+    def _page_free(self) -> tuple:
+        return self.pool.full.free_count(), self.pool.window.free_count()
+
+    def _columns(self, r) -> int:
+        return min(len(r.pages), len(r.window_pages))
+
+    def _grow(self, r, need: int) -> None:
+        super()._grow(r, need)
+        if need > len(r.window_pages):
+            r.window_pages.extend(
+                self.pool.window.alloc(need - len(r.window_pages)))
+
+    def _trim(self, r, position: int) -> None:
+        stop = min(self.pool.first_live_column(position), len(r.window_pages))
+        if stop <= r.window_from:
+            return
+        from ..observability.metrics import registry
+
+        self.pool.window.release(r.window_pages[r.window_from:stop])
+        r.window_pages[r.window_from:stop] = [0] * (stop - r.window_from)
+        registry.counter(
+            "serving.kv_window_pages_released",
+            "window-layer pages given back before their request retired: "
+            "the window had passed every row of them").inc(stop - r.window_from)
+        r.window_from = stop
+
+    def _free_lane(self, r) -> None:
+        if len(r.window_pages) > r.window_from:
+            self.pool.window.release(r.window_pages[r.window_from:])
+        r.window_pages, r.window_from = [], 0
+        super()._free_lane(r)
+
+    def _tables(self, lanes, rows: int, cols: int):
+        tables = np.zeros((rows, 2, cols), np.int32)
+        for i, r in enumerate(lanes):
+            tables[i, 0, :len(r.window_pages)] = r.window_pages
+            tables[i, 1, :len(r.pages)] = r.pages
+        return tables
+
+    def _window_live(self, first_query, last_query) -> int:
+        """Window pages that hold a row some query in ``first_query ..
+        last_query`` sees (one lane's, or arrays of one entry a lane)."""
+        ps = self.pool.page_size
+        first = np.maximum(first_query - (self.pool.window_rows - 1), 0) // ps
+        return int(np.sum(last_query // ps - first + 1))
+
+    def _pages_said(self, positions, rows: int, cols: int) -> dict:
+        pages = super()._pages_said(positions, rows, cols)
+        pages["window_pages_live"] = self._window_live(positions, positions)
+        return pages
+
+    def _chunk_says(self, r, n: int) -> dict:
+        last = r.cursor + n - 1
+        return {"pages_live": last // self.pool.page_size + 1,
+                "window_pages_live": self._window_live(r.cursor, last)}

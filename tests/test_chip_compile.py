@@ -263,3 +263,80 @@ def test_a_kernels_cache_key_does_not_depend_on_who_warmed_the_engine(
             assert another_driver(kind) == first
     finally:
         engine.shutdown(drain=False)
+
+
+# ------------------------------- two page lifetimes (Command A+, PR 36)
+@pytest.fixture(scope="module")
+def compiled_windowed_layers(topo):
+    """A window layer's and a global layer's write-then-attend over the
+    Command A+ cell's two pools (rows of 8 x 128, pages of 256, bf16, whole
+    and donated; 300 pages a layer here), compiled for one chip: a ONE-PAGE
+    prefill chunk's rows written, then a decode step's 64 rows appended and
+    ``gqa_paged_attn`` with 128 query heads on 8 K/V heads over a table of
+    136 columns, once told the window of 4096 and once told none."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.base import regions
+    from paddle_tpu.ops.pallas.paged_attention import gqa_paged_attention
+    from paddle_tpu.serving import kv_cache as kvc
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def layer(kp, vp, li, window, chunk, chunk_pages, q, k, v, table, positions,
+              pages, offsets):
+        kp = kvc.write_chunk_pages(kp, li, chunk_pages, chunk)
+        vp = kvc.write_chunk_pages(vp, li, chunk_pages, chunk)
+        kp = kvc.append_token_paged(kp, li, pages, offsets, k)
+        vp = kvc.append_token_paged(vp, li, pages, offsets, v)
+        with regions.region(regions.ATTN_CORE):
+            att = gqa_paged_attention(q, kp, vp, li, table, positions, kv_heads=8,
+                                      scale=128 ** -0.5, window=window)
+        return kp, vp, att
+
+    def step(wk, wv, fk, fv, chunk, chunk_pages, q, k, v, tables, positions, pages,
+             offsets):
+        wk, wv, a = layer(wk, wv, 2, 4096, chunk, chunk_pages, q, k, v, tables[:, 0],
+                          positions, pages, offsets)
+        fk, fv, b = layer(fk, fv, 0, None, chunk, chunk_pages, q, k, v, tables[:, 1],
+                          positions, pages, offsets)
+        return wk, wv, fk, fv, a + b
+
+    ints = shape((64,), jnp.int32)
+    window, full = shape((3, 301, 256, 1024), jnp.bfloat16), shape((1, 301, 256, 1024),
+                                                                   jnp.bfloat16)
+    rows = shape((64, 1024), jnp.bfloat16)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
+            window, window, full, full, shape((256, 1024), jnp.bfloat16),
+            shape((1,), jnp.int32), shape((64, 16384), jnp.bfloat16), rows, rows,
+            shape((64, 2, 136), jnp.int32), ints, ints, ints).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_gqa_paged_attention_compiles_for_v5e_under_its_name_with_and_without_a_window(
+        compiled_windowed_layers):
+    calls = _kernel_calls(compiled_windowed_layers)
+    assert sorted(n.split(".")[0] for n in calls) == ["gqa_paged_attn"] * 2
+    for op_name in calls.values():
+        assert "attn/core" in op_name and op_name.endswith("/gqa_paged_attn/pallas_call")
+
+
+def test_both_pools_are_updated_in_place_beside_the_kernel_that_reads_them(
+        compiled_windowed_layers):
+    """The four pool arrays aliased input to output and temporaries far under
+    a layer of either: nothing re-lays a pool out, for a one-page chunk
+    either (the fault PR 34 met)."""
+    m = compiled_windowed_layers.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * (3 + 1) * 301 * 256 * 1024 * 2
+    assert m.temp_size_in_bytes < 32 << 20

@@ -75,8 +75,12 @@ def test_committed_lockfile_shape_and_coverage():
     assert {n for n in progs if n.startswith("decode/latent:")} == {
         "decode/latent:decode:2", "decode/latent:prefill:1:8",
         "decode/latent:carry:1:2", "decode/latent:carry:2:2"}
+    assert {n for n in progs if n.startswith("decode/windowed:")} == {
+        "decode/windowed:decode:2", "decode/windowed:prefill:1:8",
+        "decode/windowed:carry:1:2", "decode/windowed:carry:2:2"}
     assert set(lock["rung_grids"]) == {"serving/batch", "decode/paged",
-                                       "decode/state", "decode/latent"}
+                                       "decode/state", "decode/latent",
+                                       "decode/windowed"}
 
 
 def test_lock_digest_matches_committed_bytes():
