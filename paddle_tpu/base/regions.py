@@ -20,7 +20,10 @@ experts (``models/axk1.py``) ``LATENT_MOE``, those of a model with window and
 global layers under a parallel block (``models/cohere2_moe.py``)
 ``WINDOWED_MOE``. ``KERNELS`` are the
 ``pl.pallas_call(name=...)`` of ``ops/pallas/flash_attention.py``,
-``ops/pallas/retention.py`` and ``ops/pallas/paged_attention.py``.
+``ops/pallas/retention.py`` and ``ops/pallas/paged_attention.py``. A kernel
+has a name of its own even where it shares a file and a region with another:
+a reader asks for decode's ``gqa_paged_attn`` and a prefill chunk's
+``gqa_chunk_attn``, both under ``attn/core``, apart.
 """
 from __future__ import annotations
 
@@ -62,6 +65,7 @@ RETN_STEP = "retn_step"          # ops/pallas/retention.py: the decode step's st
 PAGED_ATTN = "paged_attn"        # ops/pallas/paged_attention.py: decode attention over live pages
 LATENT_ATTN = "latent_paged_attn"  # the same file: absorbed attention over latent pages, a page K and V at once
 GQA_ATTN = "gqa_paged_attn"      # the same file: grouped-query decode attention over live pages, given a window or none
+GQA_CHUNK_ATTN = "gqa_chunk_attn"  # the same file: a prefill chunk's grouped-query attention over the lane's pages, a flash kernel
 # XLA's own grouped-product kernel on a TPU (what `lax.ragged_dot` becomes).
 # It names its operations itself (`ragged-dot-none`, `ragged-dot-metadata`)
 # and drops the scope it was traced under: a reader of `moe/experts` adds the
@@ -84,4 +88,4 @@ WINDOWED_MOE = (EMBED, LN, ATTN_QKV, ROPE, ATTN_KV_WRITE, ATTN_KV_GATHER,
                 LM_HEAD, SAMPLE)
 ROOTS = (PREFILL, DECODE, DRAFT, VERIFY)
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV, RETN_STEP, PAGED_ATTN,
-           LATENT_ATTN, GQA_ATTN)
+           LATENT_ATTN, GQA_ATTN, GQA_CHUNK_ATTN)

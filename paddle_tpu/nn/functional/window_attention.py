@@ -11,7 +11,10 @@ kv_heads)``), key ``j`` visible to query ``i`` iff ``j <= i`` and, given a
 - :func:`start_blocks` / :func:`grouped_block` / :func:`finish_blocks`: a
   chunk of queries against one block of keys under a running softmax, one
   K/V head's scores at a time (a ``[chunk x group, block]`` float32 matrix
-  is all that exists at once).
+  is all that exists at once, and it goes to memory and back). What a
+  prefill chunk runs off the TPU, the Layer path's core, and the oracle of
+  the kernel ``ops/pallas/paged_attention.py:gqa_chunk_attention``, which
+  is what a prefill chunk runs on a TPU.
 - :func:`rope_interleaved`: GPT-J rotary, the pairs ``(x[2i], x[2i+1])``.
 - :func:`windowed_attention`: the Layer path, whole sequences from 0.
 

@@ -38,10 +38,21 @@ merged minor dimension, so nothing is spread and no zero is multiplied. Given
 a ``window`` a lane reads only the table entries that hold a row the window
 still covers: the table is cut to those columns before the call, so entries
 in front of the window are not even skipped grid steps.
+
+:func:`gqa_chunk_attention` is the same model's PREFILL attention: a chunk
+of one lane's queries over that lane's pages, the chunk's own among them, as
+a flash kernel. The grid is (K/V head, tile of queries, table columns), the
+K/V head's query heads share each page read, a tile's scores live in VMEM
+only, and a (tile, column) pair in which no query sees a key (behind the
+window, or past the diagonal) is a dead entry as above. It takes the place
+of XLA fusions over gathered blocks of keys
+(``serving/decode.py:WindowedPrograms._attend_chunk_blocks``), which wrote
+each block's float32 scores to memory three times over.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -369,3 +380,191 @@ def gqa_paged_attention(q, k_pool, v_pool, layer, tables, positions, *,
       _held_pages(tables, last, per_step, first), first, last, base, positions,
       q.reshape(B, heads, dim), *[k_pool] * per_step, *[v_pool] * per_step)
     return out.reshape(B, HD)
+
+
+CHUNK_ROWS = 256    # queries a tile: q twice, out and the float32 accumulator of a K/V head's 16 query heads are 1 MB each and 2 MB
+CHUNK_PAGES = 4     # table columns a grid step: one softmax update over their keys together
+CHUNK_HEADS = 2     # query heads a body of the kernel's loop: all 16 unrolled run 3% more tokens a second in the cell and load a minute longer
+CHUNK_VMEM = 48 << 20   # the tile's blocks, scratch and score temporaries pass the compiler's default 16 MiB
+
+
+def chunk_columns(start: int, size: int, window, page: int) -> int:
+    """The table columns that hold a key some query of a chunk at ``start ..
+    start + size - 1`` sees (``start`` a multiple of ``page``): the chunk's
+    own and, behind them, every column (``window`` None) or those of the
+    ``window - 1`` keys before its first query. Never more than at a deep
+    ``start``, which is the width of :func:`gqa_chunk_attention`'s grid."""
+    last = (start + size - 1) // page
+    if window is None:
+        return last + 1
+    return min(last + 1, -(-size // page) + -(-(window - 1) // page))
+
+
+def _chunk_kernel(layer_ref, pages_ref, first_ref, last_ref, lo_ref, hi_ref,
+                  at_ref, q_ref, *refs, scale, page, per_step, window, unroll):
+    """One K/V head, one tile of ``rows`` queries, ``per_step`` table
+    columns: ``q_ref`` ``[rows, group x dim]`` (the K/V head's query heads
+    side by side, each a lane-aligned slice), K and V pages ``[page, dim]``
+    (that head's columns of the pool's row). Column ``c`` of the call (its
+    first is the table's ``at_ref[1]``) is live for tile ``i`` where
+    ``first[i] <= c <= last[i]`` and needs no mask where ``lo[i] <= c <=
+    hi[i]``: every query of the tile then sees it whole. A step of such
+    columns only scores their keys together, one update of the running
+    softmax a query head; any other step goes page by page, live pages only,
+    under the mask. The query heads go one after another in a loop (its body
+    is the kernel's whole code), their q, running softmax and accumulator
+    head-major in scratch, in float32."""
+    del layer_ref, pages_ref   # read by the index maps
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    o_ref, q_scr, m_scr, l_scr, acc_scr = refs[2 * per_step:]
+    i, t = pl.program_id(1), pl.program_id(2)
+    group, rows, dim = q_scr.shape
+    col = t * per_step
+
+    @pl.when(t == 0)
+    def _():
+        for h in range(group):
+            q_scr[h] = q_ref[:, h * dim:(h + 1) * dim]
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def attend(k_refs, v_refs, seen=None):
+        """The tile's query heads against these pages' keys, all visible
+        (``seen`` None) or those of ``seen`` ``[rows, keys]``."""
+        def head(h):
+            logits = jnp.concatenate(
+                [_dot(q_scr[h], k_ref[...], _NT) for k_ref in k_refs], axis=1) * scale   # [rows, keys] f32
+            if seen is not None:
+                logits = jnp.where(seen, logits, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            if seen is not None:
+                # a query may see no key of a live page (the window's first,
+                # past its own edge): its row then counts as 0
+                p = jnp.where(seen, p, 0.0)
+            l_scr[h] = alpha * l_scr[h] + p.sum(axis=1, keepdims=True)
+            p = p.astype(v_refs[0].dtype)
+            weighted = _dot(p[:, :page], v_refs[0][...], _NN)
+            for j in range(1, len(v_refs)):
+                weighted += _dot(p[:, j * page:(j + 1) * page], v_refs[j][...], _NN)
+            acc_scr[h] = alpha * acc_scr[h] + weighted
+            m_scr[h] = m_new
+
+        def heads(n, carry):
+            for u in range(unroll):
+                head(n * unroll + u)
+            return carry
+
+        jax.lax.fori_loop(0, group // unroll, heads, 0)
+
+    clear = (col >= lo_ref[i]) & (col + per_step - 1 <= hi_ref[i])
+    pl.when(clear)(lambda: attend(k_refs, v_refs))
+    for j in range(per_step):
+        @pl.when(jnp.logical_not(clear) & (col + j >= first_ref[i]) & (col + j <= last_ref[i]))
+        def _(j=j):
+            pos = (at_ref[0] + i * rows
+                   + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0))
+            key = ((at_ref[1] + col + j) * page
+                   + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1))
+            seen = key <= pos
+            if window is not None:
+                seen &= pos - key < window
+            attend(k_refs[j:j + 1], v_refs[j:j + 1], seen)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _():
+        for h in range(group):
+            o_ref[:, h * dim:(h + 1) * dim] = (acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "scale", "window",
+                                             "interpret", "rows", "per_step"))
+def gqa_chunk_attention(q, k_pool, v_pool, layer, table, start, *, kv_heads,
+                        scale, window=None, interpret=False, rows=CHUNK_ROWS,
+                        per_step=CHUNK_PAGES):
+    """Grouped-query attention of a prefill CHUNK over one lane's pages, the
+    chunk's own rows already written: ``q`` ``[C, heads x dim]`` at positions
+    ``start .. start + C - 1`` (``start`` a multiple of the page; query head
+    ``h`` uses K/V head ``h // (heads // kv_heads)``), the pools ``[L, N,
+    page, kv_heads x dim]`` whole, ``layer`` and ``start`` scalars, ``table``
+    ``[T]`` int32 (the lane's pages by logical column; 0, the pad page, past
+    its pages and where a window page was released). Returns ``[C, heads x
+    dim]`` in ``q``'s dtype: query ``i`` attends over the keys ``j <= i``
+    and, given ``window``, ``i - j < window``.
+
+    A flash kernel: the grid is (K/V head, tile of ``rows`` queries,
+    ``per_step`` table columns), the columns innermost under a running
+    softmax, so a tile's scores never leave VMEM and a K/V head's query
+    heads share each page read. With a window the column axis is cut to the
+    :func:`chunk_columns` columns that end at the chunk's last; without one
+    it is the table's width. Either way a column that holds no key the
+    tile's queries see is dead as in :func:`paged_attention` (it names the
+    page its operand already holds and moves no bytes), and only the pages
+    on the diagonal or the window's edge are scored under a mask. ``rows``
+    and ``per_step`` are the tile, picked on the chip (``.chip_scratch/
+    pr37_chunk_bench.py``); tests shrink them to meet several tiles."""
+    C, HD = q.shape
+    L, N, page, KD = k_pool.shape
+    dim = KD // kv_heads
+    width = HD // kv_heads          # a K/V head's query heads, side by side
+    group = width // dim
+    T = table.shape[0]
+    rows = min(rows, C)
+    tiles = C // rows
+    start = jnp.asarray(start, jnp.int32)
+    table = table.astype(jnp.int32)
+    cols = min(T, chunk_columns(T * page, C, window, page))
+    per_step = min(per_step, cols)
+    base = (jnp.zeros((), jnp.int32) if window is None else
+            jnp.maximum((start + C - 1) // page - (cols - 1), 0))
+    cols = -(-cols // per_step) * per_step
+    # the call's columns, one row of them a tile (past the table: dead)
+    pages = jnp.take(table, base + jnp.arange(cols, dtype=jnp.int32), mode="clip")
+    head = start + rows * jnp.arange(tiles, dtype=jnp.int32)      # a tile's first query
+    tail = head + rows - 1
+    last = tail // page - base
+    first = (jnp.zeros((tiles,), jnp.int32) if window is None else
+             jnp.maximum(head - (window - 1), 0) // page - base)
+    # the columns every query of a tile sees whole: behind the first query's
+    # own, and from where the last query's window begins
+    hi = (head + 1) // page - 1 - base
+    lo = (jnp.zeros((tiles,), jnp.int32) if window is None else
+          jnp.maximum((tail - window) // page + 1 - base, 0))
+    held = _held_pages(jnp.broadcast_to(pages, (tiles, cols)), last, per_step, first)
+
+    def tile(g, i, t, *_):
+        return (i, g)
+
+    def entry(j):
+        return lambda g, i, t, layer, pages, *_: (
+            layer[0], pages[i * cols + t * per_step + j], 0, g)
+
+    q_spec = pl.BlockSpec((rows, width), tile)
+    kv_specs = [pl.BlockSpec((None, None, page, dim), entry(j))
+                for j in range(per_step)]
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, scale=scale, page=page,
+                          per_step=per_step, window=window,
+                          unroll=math.gcd(CHUNK_HEADS, group)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(kv_heads, tiles, cols // per_step),
+            in_specs=[q_spec] + kv_specs + kv_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((group, rows, dim), q.dtype),       # q, head-major
+                pltpu.VMEM((group, rows, 1), jnp.float32),     # m
+                pltpu.VMEM((group, rows, 1), jnp.float32),     # l
+                pltpu.VMEM((group, rows, dim), jnp.float32),   # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((C, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=CHUNK_VMEM),
+        interpret=interpret,
+        name=regions.GQA_CHUNK_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), held, first, last, lo, hi,
+      jnp.stack([start, base]), q, *[k_pool] * per_step, *[v_pool] * per_step)

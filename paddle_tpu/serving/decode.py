@@ -1584,14 +1584,23 @@ class WindowedPrograms(PagedDecodePrograms):
       ``window_attention.attend_grouped``. One table rung, the whole table;
     - ``("prefill", 1, c)``: ONE CHUNK of one lane's prompt at ``start``, a
       multiple of the top rung: each layer writes the chunk's rows to whole
-      pages, then attends over blocks of top-rung keys under a running
-      softmax, a global layer over every block up to the chunk's own, a
-      window layer over the blocks its first query still sees (the chunk's
-      own and at most ``ceil((window - 1) / top)`` before it)."""
+      pages, then attends over the lane's pages. On a TPU that is the flash
+      kernel ``gqa_chunk_attn`` (``ops/pallas/paged_attention.py``): K and V
+      read through the table where they lie, a tile's scores kept in VMEM,
+      only the table columns a tile of queries sees visited (a global layer's
+      up to the tile's own, a window layer's from the window's first).
+      Elsewhere (:meth:`_attend_chunk_blocks`, the kernel's oracle) blocks
+      of top-rung keys are gathered and scored under a running softmax, a
+      global layer over every block up to the chunk's own, a window layer
+      over the blocks its first query still sees (the chunk's own and at
+      most ``ceil((window - 1) / top)`` before it). Blocks of
+      :attr:`ROPE_ROWS` rows of q are rotated one after another, so the
+      float32 copies the rotation makes are a block's, not the chunk's."""
 
     chunked = True
     _extract = staticmethod(_extract_cohere2)
-    HEAD_GROUP = 8   # query heads whose scores exist at once in a prefill block
+    HEAD_GROUP = 8   # off the TPU: query heads whose scores exist at once in a prefill block
+    ROPE_ROWS = 256  # rows rotated at once: the float32 copies of a chunk's q are a block's, not the chunk's
 
     def __init__(self, model, pool: WindowedPagePools, *,
                  seq_ladder: Sequence[int], decode_rungs: Sequence[int],
@@ -1638,19 +1647,32 @@ class WindowedPrograms(PagedDecodePrograms):
         """``x`` ``[N, hidden]`` at ``positions`` ``[N]`` -> the block's norm
         ``n`` and q ``[N, heads x d]``, k, v ``[N, kv_heads x d]``; q and k
         rotated in a window layer, left alone in a global one."""
-        from ..nn.functional import window_attention as wa
-
-        N, d = x.shape[0], self._head_dim
         with region(regions.LN):
             n = _ln_plain(x, w["ln"], self._eps)
         with region(regions.ATTN_QKV):
             q, k, v = n @ w["q_proj"], n @ w["k_proj"], n @ w["v_proj"]
         if window is not None:
             with region(regions.ROPE):
-                q, k = (wa.rope_interleaved(a.reshape(N, -1, d), positions,
-                                            self._theta).astype(a.dtype).reshape(N, -1)
-                        for a in (q, k))
+                q, k = (self._rotated(a, positions) for a in (q, k))
         return n, q, k, v
+
+    def _rotated(self, a, positions):
+        """``a`` ``[N, heads x d]`` turned by ``positions`` ``[N]``, blocks of
+        :attr:`ROPE_ROWS` rows one after another."""
+        from jax import lax
+
+        from ..nn.functional import window_attention as wa
+
+        def block(a, positions):
+            return wa.rope_interleaved(
+                a.reshape(a.shape[0], -1, self._head_dim), positions,
+                self._theta).astype(a.dtype).reshape(a.shape)
+
+        N, blocks = a.shape[0], a.shape[0] // self.ROPE_ROWS
+        if blocks < 2 or N % self.ROPE_ROWS:
+            return block(a, positions)
+        return lax.map(lambda x: block(*x), (a.reshape(blocks, self.ROPE_ROWS, -1),
+                                             positions.reshape(blocks, -1))).reshape(N, -1)
 
     def _ffn(self, w, n, valid):
         """The block's experts on its norm ``n`` ``[N, hidden]``: the held
@@ -1706,11 +1728,25 @@ class WindowedPrograms(PagedDecodePrograms):
     # ------------------------------------------------------------ attention
     def _attend_chunk(self, q, kp, vp, li, table, start, window):
         """A chunk's ``C`` queries at ``start .. start + C - 1`` (``q`` ``[C,
-        heads x d]``) over the lane's pages of pool layer ``li``, the chunk's
-        own rows already written: blocks of ``top rung`` keys (``start`` is a
-        multiple of it, so the chunk lies in the last block), each gathered
-        from the pool; a window layer starts at the first block its first
-        query can see. ``table`` ``[T]``."""
+        heads x d]``; ``start`` a multiple of the page, the chunk inside one
+        block of ``top rung`` rows) over the lane's pages of pool layer
+        ``li``, the chunk's own rows already written. ``table`` ``[T]``. On a
+        TPU the flash kernel ``gqa_chunk_attn``, which reads the pages where
+        they lie, elsewhere :meth:`_attend_chunk_blocks`."""
+        if self._kernel():
+            from ..ops.pallas import paged_attention as kernel
+
+            with region(regions.ATTN_CORE):
+                return kernel.gqa_chunk_attention(
+                    q, kp, vp, li, table, start, kv_heads=self._kv_heads,
+                    scale=self._scale, window=window)
+        return self._attend_chunk_blocks(q, kp, vp, li, table, start, window)
+
+    def _attend_chunk_blocks(self, q, kp, vp, li, table, start, window):
+        """:meth:`_attend_chunk` off the TPU, and the kernel's oracle: blocks
+        of ``top rung`` keys under a running softmax, each gathered from the
+        pool, the chunk in the last one; a window layer starts at the first
+        block its first query can see."""
         import jax.numpy as jnp
         from jax import lax
 

@@ -776,7 +776,7 @@ class DecodeScheduler:
         self._owe_decode = True
         tokens = np.zeros((1, rung), np.int32)
         tokens[0, :n] = r.prompt[r.cursor:r.cursor + n]
-        says = self._chunk_says(r, n)
+        says = self._chunk_says(r, n, rung)
         args = self._chunk_args(r, n)
         if args is None:
             # the chunk waits (for pages): the decoding lanes go first
@@ -796,9 +796,10 @@ class DecodeScheduler:
             "than the chunk rung takes several beats)").inc()
         return call
 
-    def _chunk_says(self, r, n: int) -> dict:
+    def _chunk_says(self, r, n: int, rung: int) -> dict:
         """What a chunk's span says of its residency beside ``chunk``,
-        ``chunks`` and ``tokens``: nothing here."""
+        ``chunks`` and ``tokens`` (``n`` of them on the ``rung`` program):
+        nothing here."""
         return {}
 
     def _chunk_args(self, r, n: int):
@@ -1466,7 +1467,23 @@ class WindowedDecodeScheduler(PagedDecodeScheduler):
         pages["window_pages_live"] = self._window_live(positions, positions)
         return pages
 
-    def _chunk_says(self, r, n: int) -> dict:
+    def _chunk_says(self, r, n: int, rung: int) -> dict:
+        """Beside the pages that hold a row the chunk's ``n`` tokens see:
+        ``attn_columns_live``, the table columns the ``rung`` program's
+        attention visits over all its layers (the kernel's grid is cut by
+        the same ``chunk_columns``), and ``attn_columns_dense``, what whole
+        blocks of top-rung keys would visit (the path off the TPU): their
+        ratio is the share of a block path's key work that is real."""
+        from ..ops.pallas.paged_attention import chunk_columns
+
+        pool, ps, top = self.pool, self.pool.page_size, self.programs.seq_ladder[-1]
         last = r.cursor + n - 1
-        return {"pages_live": last // self.pool.page_size + 1,
-                "window_pages_live": self._window_live(r.cursor, last)}
+        block = r.cursor // top * top
+        live = dense = 0
+        for layers, window in ((pool.window.num_layers, pool.window_rows),
+                               (pool.full.num_layers, None)):
+            live += layers * chunk_columns(r.cursor, rung, window, ps)
+            dense += layers * chunk_columns(block, top, window, top) * (top // ps)
+        return {"pages_live": last // ps + 1,
+                "window_pages_live": self._window_live(r.cursor, last),
+                "attn_columns_live": live, "attn_columns_dense": dense}

@@ -340,3 +340,77 @@ def test_both_pools_are_updated_in_place_beside_the_kernel_that_reads_them(
     m = compiled_windowed_layers.memory_analysis()
     assert m.alias_size_in_bytes == 2 * (3 + 1) * 301 * 256 * 1024 * 2
     assert m.temp_size_in_bytes < 32 << 20
+
+
+# ------------------- a prefill chunk's attention over the pages (PR 37)
+@pytest.fixture(scope="module")
+def compiled_chunk_layers(topo):
+    """A window layer's and a global layer's write-then-attend of a 2048-row
+    prefill chunk over the Command A+ cell's two pools (300 pages a layer
+    here, whole and donated), compiled for one chip: the chunk's eight pages
+    written, then ``gqa_chunk_attn`` with 128 query heads on 8 K/V heads
+    over a table of 136 columns, ``layer`` and ``start`` traced, once told
+    the window of 4096 and once told none."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.base import regions
+    from paddle_tpu.ops.pallas.paged_attention import gqa_chunk_attention
+    from paddle_tpu.serving import kv_cache as kvc
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def layer(kp, vp, li, window, q, k, v, table, start):
+        pages = jax.lax.dynamic_slice(table, (start // 256,), (8,))
+        kp = kvc.write_chunk_pages(kp, li, pages, k)
+        vp = kvc.write_chunk_pages(vp, li, pages, v)
+        with regions.region(regions.ATTN_CORE):
+            att = gqa_chunk_attention(q, kp, vp, li, table, start, kv_heads=8,
+                                      scale=128 ** -0.5, window=window)
+        return kp, vp, att
+
+    def step(wk, wv, fk, fv, li, q, k, v, tables, start):
+        wk, wv, a = layer(wk, wv, li, 4096, q, k, v, tables[0], start)
+        fk, fv, b = layer(fk, fv, 0, None, q, k, v, tables[1], start)
+        return wk, wv, fk, fv, a + b
+
+    window, full = shape((3, 301, 256, 1024), jnp.bfloat16), shape((1, 301, 256, 1024),
+                                                                   jnp.bfloat16)
+    rows = shape((2048, 1024), jnp.bfloat16)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
+            window, window, full, full, shape((), jnp.int32),
+            shape((2048, 16384), jnp.bfloat16), rows, rows,
+            shape((2, 136), jnp.int32), shape((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_gqa_chunk_attention_compiles_for_v5e_under_a_name_of_its_own(compiled_chunk_layers):
+    """Mosaic takes the kernel at the cell's shapes with a window and without
+    (lane-aligned slices, the scratch and the blocks inside its VMEM limit),
+    and a reader of decode's ``gqa_paged_attn`` does not find it."""
+    calls = _kernel_calls(compiled_chunk_layers)
+    assert sorted(n.split(".")[0] for n in calls) == ["gqa_chunk_attn"] * 2
+    for op_name in calls.values():
+        assert "attn/core" in op_name and op_name.endswith("/gqa_chunk_attn/pallas_call")
+        assert "gqa_paged_attn" not in op_name
+
+
+def test_a_chunks_attention_leaves_no_scores_and_no_gathered_keys_in_memory(
+        compiled_chunk_layers):
+    """The pools aliased, and temporaries a few copies of q (64 MB): a block
+    of 2048 keys gathered for 8 K/V heads twice over and the float32 scores
+    of 8 query heads against it were 0.5 GB."""
+    m = compiled_chunk_layers.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * (3 + 1) * 301 * 256 * 1024 * 2
+    assert m.temp_size_in_bytes < 200 << 20

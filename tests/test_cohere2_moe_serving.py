@@ -88,9 +88,11 @@ def test_a_batch_of_lanes_at_mixed_depths(engine, model):
 
 
 def _force_kernel(monkeypatch):
+    """Both kernels of the family, decode's and the prefill chunk's, interpreted."""
     monkeypatch.setattr(decode_mod.WindowedPrograms, "_kernel", staticmethod(lambda: True))
-    monkeypatch.setattr(kernel, "gqa_paged_attention", functools.partial(
-        kernel.gqa_paged_attention, interpret=True))
+    for name in ("gqa_paged_attention", "gqa_chunk_attention"):
+        monkeypatch.setattr(kernel, name, functools.partial(getattr(kernel, name),
+                                                            interpret=True))
 
 
 def test_with_the_kernel_the_engine_returns_the_same_tokens(monkeypatch, model):
@@ -109,6 +111,138 @@ def test_with_the_kernel_the_engine_returns_the_same_tokens(monkeypatch, model):
     _force_kernel(monkeypatch)
     for got, expected in zip(streams(), want):
         assert np.array_equal(got, expected)
+
+
+def test_with_both_kernels_a_mixed_load_returns_the_same_tokens(monkeypatch, model):
+    """Short and long prompts in one queue (window 16, chunk 32): one-page
+    prompts on the smallest rung, prompts on the window's edge, and prompts
+    of several chunks whose window pages go back during prefill, decoding in
+    one batch; the chunk kernel and the decode kernel against the XLA path."""
+    def streams():
+        eng = serving.DecodeEngine(model, **ENGINE)
+        eng.warmup()
+        try:
+            rng = np.random.default_rng(6)
+            sent = [eng.submit("t", rng.integers(0, 256, n).astype(np.int32),
+                               max_new_tokens=m)
+                    for n, m in ((3, 9), (100, 6), (16, 12), (33, 5), (8, 10), (64, 7), (90, 8))]
+            out = [r.result(timeout=600) for r in sent]
+            assert eng.kv_pool.in_use() == 0 and eng.compiles_after_warmup == 0
+            return out
+        finally:
+            eng.shutdown()
+
+    want = streams()
+    _force_kernel(monkeypatch)
+    for got, expected in zip(streams(), want):
+        assert np.array_equal(got, expected)
+
+
+# ------------------------------------------ the prefill chunk's flash kernel
+PAGE, KV_HEADS, DIM, TOP, COLS = 8, 2, 16, 64, 64   # a stand-in pool: 64 columns of 8 rows, blocks of 64
+
+
+@functools.lru_cache(maxsize=None)
+def _block_path(group, window):
+    """`WindowedPrograms._attend_chunk_blocks` over the stand-in pool, jitted:
+    the path off the TPU, which is the kernel's oracle."""
+    import jax
+    from types import SimpleNamespace
+
+    P = object.__new__(decode_mod.WindowedPrograms)
+    P.seq_ladder, P.pool = [16, TOP], SimpleNamespace(page_size=PAGE)
+    P._kv_heads, P._heads, P._head_dim, P._scale = KV_HEADS, KV_HEADS * group, DIM, DIM ** -0.5
+    return jax.jit(lambda *a: P._attend_chunk_blocks(*a, window))
+
+
+def _chunk_case(seed, group, size, start, window, dtype):
+    """A lane `start + size` rows deep in a pool of random rows, its table
+    as the scheduler leaves it before this chunk (the columns behind the
+    window of the chunk's first query released, reading 0), q for the chunk's
+    `size` positions. Every page the lane does not hold, the pad page among
+    them, is NaN."""
+    rng = np.random.default_rng(seed)
+    held = (start + size) // PAGE
+    table = np.zeros(COLS, np.int32)
+    table[:held] = rng.permutation(np.arange(1, 2 * COLS))[:held]
+    if window is not None:
+        table[:max(start - (window - 1), 0) // PAGE] = 0
+    pools = []
+    for _ in range(2):
+        rows = rng.standard_normal((2, 2 * COLS, PAGE, KV_HEADS * DIM)).astype(np.float32)
+        dead = np.ones(2 * COLS, bool)
+        dead[table[table > 0]] = False
+        rows[:, dead] = np.nan
+        pools.append(jnp.asarray(rows, dtype))
+    q = jnp.asarray(rng.standard_normal((size, KV_HEADS * group * DIM)), dtype)
+    return q, pools[0], pools[1], jnp.asarray(table)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_kernel(group, window, rows=16, per_step=2):
+    """The kernel, interpreted, `layer` and `start` traced: one trace serves
+    every `start`."""
+    import jax
+
+    return jax.jit(lambda q, kp, vp, li, table, start: kernel.gqa_chunk_attention(
+        q, kp, vp, li, table, start, kv_heads=KV_HEADS, scale=DIM ** -0.5, window=window,
+        interpret=True, rows=rows, per_step=per_step))
+
+
+# start: 0; a page short of a block's end (the smaller chunk: the block path
+# wants a chunk inside one block); a block; past the window; deep
+@pytest.mark.parametrize("size,start", [(8, 0), (8, 56), (8, 64), (8, 128), (8, 384),
+                                        (64, 0), (64, 64), (64, 128), (64, 384)])
+@pytest.mark.parametrize("window", [None, 32, 33])
+@pytest.mark.parametrize("dtype,group,tolerance", [("float32", 16, 2e-6), ("float32", 2, 2e-6),
+                                                   ("bfloat16", 16, 2e-2), ("bfloat16", 2, 2e-2)])
+def test_chunk_kernel_agrees_with_the_block_path(dtype, group, tolerance, size, window, start):
+    """Tiles of 16 queries, two table columns a grid step, `layer` and
+    `start` traced: the same keys by the same rule as the blocks of 64 under
+    `grouped_block`, the released columns and the pad page never read (they
+    are NaN here and the block path is given zeros there)."""
+    q, kp, vp, table = _chunk_case(start + size, group, size, start, window, dtype)
+    li, at = jnp.asarray(1, jnp.int32), jnp.asarray(start, jnp.int32)
+    got = _chunk_kernel(group, window)(q, kp, vp, li, table, at)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = _block_path(group, window)(q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), li, table, at)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= tolerance * np.abs(want).max()
+
+
+@pytest.mark.parametrize("rows,per_step", [(8, 1), (16, 4), (32, 3), (64, 2)])
+def test_chunk_kernel_gives_the_same_at_every_tile_size(rows, per_step):
+    """Tiles narrower than a page and wider, a column count the step does
+    not divide: what a step holds changes, the keys a query sees do not."""
+    q, kp, vp, table = _chunk_case(11, 4, 64, 192, 40, "float32")
+    args = (q, kp, vp, jnp.asarray(0, jnp.int32), table, jnp.asarray(192, jnp.int32))
+    got = _chunk_kernel(4, 40, rows, per_step)(*args)
+    want = _chunk_kernel(4, 40)(*args)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
+
+
+def test_chunk_kernel_without_a_window_reads_the_released_columns():
+    """The `released` control's premise: told no window over a table with
+    released columns, the kernel READS the pad page there (a released column
+    is live to a global layer), so what it returns is the pad page's, here
+    NaN; told the window it reads none of them."""
+    q, kp, vp, table = _chunk_case(12, 2, 16, 128, 32, "float32")
+    assert (np.asarray(table[:12]) == 0).all() and np.isnan(np.asarray(kp[0, 0])).all()
+    args = (q, kp, vp, jnp.asarray(0, jnp.int32), table, jnp.asarray(128, jnp.int32))
+    assert np.isnan(np.asarray(_chunk_kernel(2, None)(*args))).all()
+    assert np.isfinite(np.asarray(_chunk_kernel(2, 32)(*args))).all()
+
+
+@pytest.mark.parametrize("start,size,window,live", [
+    (0, 2048, 4096, 8), (0, 256, None, 1), (2048, 2048, 4096, 16), (6144, 2048, 4096, 24),
+    (30720, 2048, 4096, 24), (30720, 2048, None, 128), (4096, 256, 4096, 17), (4096, 256, 4097, 17),
+    (4352, 256, 4097, 17), (1792, 256, 4096, 8)])
+def test_chunk_columns_are_the_columns_some_query_of_the_chunk_sees(start, size, window, live):
+    first = 0 if window is None else max(start - (window - 1), 0) // 256
+    assert kernel.chunk_columns(start, size, window, 256) == live \
+        == (start + size - 1) // 256 - first + 1
 
 
 # ------------------------------------------------------ the cache manager
@@ -300,6 +434,13 @@ def test_steps_say_their_pages_of_both_kinds_and_their_pairs(engine):
     # a chunk reads every global page up to its end and the window behind
     # its first query: rows 0-31, 17-63 (from column 2), 49-69 (from column 6)
     assert [(a["pages_live"], a["window_pages_live"]) for a in chunks] == [(4, 4), (8, 6), (9, 3)]
+    # the columns the chunk kernel's calls visit, three window layers and a
+    # global one: rungs 32, 32, 8 at 0, 32, 64 under a window of 16 (the
+    # chunk's own and two behind); whole blocks of 32 rows would visit 1, 2, 2
+    # blocks of 4 columns a window layer and 1, 2, 3 the global one
+    assert [(a["attn_columns_live"], a["attn_columns_dense"]) for a in chunks] \
+        == [(3 * 4 + 4, 3 * 4 + 4), (3 * 6 + 8, 3 * 8 + 8), (3 * 3 + 9, 3 * 8 + 12)]
+    assert all("attn_columns_live" not in a for a in decodes)
     # decode at 70..73: global columns 0..8 (0..9 from 72), window from (p - 15) // 8
     assert [(a["pages_live"], a["window_pages_live"]) for a in decodes] \
         == [(9, 3), (9, 2), (10, 3), (10, 3)]
@@ -351,6 +492,46 @@ def test_with_the_kernel_decode_gathers_nothing_and_names_the_kernel(monkeypatch
     assert windows == [(4, 1)] * 3 + [(4, 4)]
     for e in _walk(closed.jaxpr):
         assert regions.ATTN_KV_GATHER not in str(e.source_info.name_stack)
+
+
+def test_with_the_kernel_a_prefill_chunk_scores_no_block_in_memory(monkeypatch, engine):
+    """One `gqa_chunk_attention` a layer under `attn/core`, the kernel under
+    its own name, nothing gathered, and no float32 tensor with a block of
+    keys (32 here) in its last dimension: a tile's scores stay in the kernel.
+    Off the TPU the same program holds them."""
+    import jax
+
+    from paddle_tpu.analysis.drift_check import _walk
+
+    P = engine.programs
+    key = ("prefill", 1, 32)
+
+    def scores(closed):
+        """Float32 `[chunk, query heads of a K/V head, block]` tensors."""
+        return [v.aval.shape for e in _walk(closed.jaxpr) if e.primitive.name != "pallas_call"
+                for v in e.outvars if getattr(v.aval, "dtype", None) == jnp.float32
+                and len(v.aval.shape) >= 3 and v.aval.shape[-1] == 32 == v.aval.shape[-3]]
+
+    args = (P.params, *P.pool.arrays(), *P._zero_args(key))
+    assert scores(jax.make_jaxpr(lambda *a: P._prefill_fn(*a))(*args))
+    _force_kernel(monkeypatch)
+    closed = jax.make_jaxpr(lambda *a: P._prefill_fn(*a))(*args)
+    calls = [e for e in closed.jaxpr.eqns if e.params.get("name") == "gqa_chunk_attention"]
+    assert len(calls) == 4                                       # one a layer
+    grids = []
+    for e in calls:
+        assert str(e.source_info.name_stack).endswith(regions.ATTN_CORE)
+        (call,) = [x for x in _walk(e.params["jaxpr"].jaxpr) if x.primitive.name == "pallas_call"]
+        assert str(call.source_info.name_stack) == regions.GQA_CHUNK_ATTN
+        grids.append(tuple(call.params["grid_mapping"].grid))
+    # (K/V heads, tiles, steps of so many columns): a window layer over the
+    # chunk's 4 columns and the 2 behind them, the global one over all 16
+    per = kernel.CHUNK_PAGES
+    assert grids == [(2, 1, -(-6 // per))] * 3 + [(2, 1, -(-16 // per))]
+    assert not scores(closed)
+    for e in _walk(closed.jaxpr):
+        assert regions.ATTN_KV_GATHER not in str(e.source_info.name_stack)
+    assert regions.GQA_CHUNK_ATTN in regions.KERNELS and regions.GQA_CHUNK_ATTN != regions.GQA_ATTN
 
 
 # ------------------------------------------------- the tolerances, and faults

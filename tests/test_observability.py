@@ -621,7 +621,8 @@ class TestProgramRegions:
         assert regions.ROOTS == ("prefill", "decode", "draft", "verify")
         assert regions.KERNELS == ("flash_fwd", "flash_bwd_dq",
                                    "flash_bwd_dkv", "retn_step", "paged_attn",
-                                   "latent_paged_attn", "gqa_paged_attn")
+                                   "latent_paged_attn", "gqa_paged_attn",
+                                   "gqa_chunk_attn")
         assert {"attn/latent_proj", "attn/expand", "moe/route", "moe/experts",
                 "moe/shared"} <= set(regions.LATENT_MOE)
         assert {"retn/gate", "retn/chunk", "retn/state", "norm",
