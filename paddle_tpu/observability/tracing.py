@@ -146,6 +146,35 @@ def _load_xla_chrome_trace(log_dir: str) -> Optional[dict]:
         return json.load(f)
 
 
+def _is_modules(thread_name) -> bool:
+    """The device lane with one event a program run."""
+    return str(thread_name or "").endswith("XLA Modules")
+
+
+def _device_skew_us(xs: List[dict], threads: dict) -> float:
+    """How much later the device's events have to sit, by causality: the
+    runtime's ``CompleteCallbacks`` event of a run (a host thread,
+    ``args.run_id``) cannot start before the device finished that run
+    (the ``XLA Modules`` event with the same ``run_id``), so the least of
+    (callback start - program end) over the runs both sides name is what
+    the device's clock lags by, less the callback's own latency (tens of
+    microseconds). The bound ``benchmark/trace_reduce.device_skew_ns``
+    applies to the benchmark's captures. 0 where no run is named on both
+    sides."""
+    ends, least = {}, None
+    for e in xs:
+        run = (e.get("args") or {}).get("run_id")
+        if run is not None and _is_modules(threads.get((e.get("pid"), e.get("tid")))):
+            end = float(e["ts"]) + float(e.get("dur", 0.0))
+            ends[str(run)] = max(end, ends.get(str(run), end))
+    for e in xs:
+        if e.get("name") == "CompleteCallbacks":
+            end = ends.get(str((e.get("args") or {}).get("run_id")))
+            if end is not None and (least is None or float(e["ts"]) - end < least):
+                least = float(e["ts"]) - end
+    return least or 0.0
+
+
 def _normalize_device_events(trace: dict, t_sync_us: float,
                              include_python: bool = False) -> List[tuple]:
     """XLA chrome-trace events → this tracer's event tuples on
@@ -154,7 +183,15 @@ def _normalize_device_events(trace: dict, t_sync_us: float,
     (``perf_counter`` read inside it), so every event moves by the
     difference. A capture without the annotation yields nothing, with a
     warning. The profiler's python-callstack lane duplicates what the
-    host tracks already carry; it is dropped unless ``include_python``."""
+    host tracks already carry; it is dropped unless ``include_python``.
+
+    The chip's clock runs a millisecond or so apart from the host's
+    within one capture (a program "starts" before its dispatch), so the
+    device's lanes (every event of a process that has an ``XLA Modules``
+    lane) move later by :func:`_device_skew_us` as well, where the
+    capture lets it be bound. A capture whose events carry no ``run_id``
+    on both sides (no ``XLA Modules`` lane, as on the CPU) keeps the
+    sync shift alone."""
     events = trace.get("traceEvents", []) if trace else []
     threads = {}
     for e in events:
@@ -172,6 +209,8 @@ def _normalize_device_events(trace: dict, t_sync_us: float,
                 "fused into the timeline", SYNC_NAME, len(xs))
         return []
     shift = t_sync_us - float(sync["ts"])
+    skew = _device_skew_us(xs, threads)
+    chips = {pid for (pid, _), name in threads.items() if _is_modules(name)}
     out = []
     for e in xs:
         if e is sync:
@@ -180,8 +219,9 @@ def _normalize_device_events(trace: dict, t_sync_us: float,
                             f"tid{e.get('tid')}")
         if not include_python and tname == "python":
             continue
+        late = skew if e.get("pid") in chips else 0.0
         out.append(("X", e.get("name", "?"), f"device.{tname}",
-                    float(e["ts"]) + shift, float(e.get("dur", 0.0)),
+                    float(e["ts"]) + shift + late, float(e.get("dur", 0.0)),
                     e.get("args") or None, None, None))
     out.sort(key=lambda ev: ev[3])
     return out

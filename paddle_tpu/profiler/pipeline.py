@@ -133,18 +133,28 @@ class ServingStats:
             #                                  "lat": bounded ring like _lat}
             self._t_first = None
             self._t_last = None
-            # decode tier (serving/decode.py): per-step prefill-vs-decode
-            # latency split, emitted-token throughput, slot occupancy
+            # decode tier (serving/decode.py): per-call prefill-vs-decode
+            # latency split, emitted-token throughput, slot occupancy.
+            # Every key is read by summary()["decode"] (the draft and
+            # verify keys by its speculation block), so by /metrics
+            # (paddle_serving_decode_*) and serving_report(); the
+            # benchmark's closed-loop drivers print *_steps and
+            # *_p50_ms/_p99_ms, tools/chaos reads spec_rounds.
             self._decode = {
                 "prefill_steps": 0, "decode_steps": 0,
-                "prefill_s": 0.0, "decode_s": 0.0,
-                "prefill_ms": [], "decode_ms": [],   # bounded rings
+                # a call's own time, dispatch to tokens on the host, and
+                # the part of it inside the dispatch: bounded rings
+                "prefill_ms": [], "decode_ms": [],
+                "prefill_dispatch_ms": [], "decode_dispatch_ms": [],
                 # self-speculation split (ISSUE 20): draft/verify program
                 # calls keyed like the other step kinds, plus per-round
                 # acceptance accounting
                 "draft_steps": 0, "verify_steps": 0,
-                "draft_s": 0.0, "verify_s": 0.0,
                 "draft_ms": [], "verify_ms": [],     # bounded rings
+                "draft_dispatch_ms": [], "verify_dispatch_ms": [],
+                # seconds the scheduler's thread spent inside dispatches
+                # and waiting inside reads, all kinds
+                "dispatch_s": 0.0, "read_wait_s": 0.0,
                 "spec_rounds": 0, "spec_proposed": 0,
                 "spec_accepted": 0, "spec_committed": 0,
                 # program calls that sorted the vocabulary (a lane sampled)
@@ -182,17 +192,12 @@ class ServingStats:
             self.samples += int(n)
             lat = (t_complete - t_enqueue, t_dispatch - t_admit,
                    t_complete - t_dispatch)
-            self._lat.append(lat)
-            if len(self._lat) > self._max_samples:
-                del self._lat[: len(self._lat) - self._max_samples]
+            self._ring(self._lat, lat)
             if tenant is not None:
                 cell = self._tenant_cell(tenant)
                 cell["requests"] += 1
                 cell["samples"] += int(n)
-                ring = cell["lat"]
-                ring.append(lat)
-                if len(ring) > self._max_samples:
-                    del ring[: len(ring) - self._max_samples]
+                self._ring(cell["lat"], lat)
             if self._t_first is None:
                 self._t_first = t_enqueue
             self._t_last = max(self._t_last or t_complete, t_complete)
@@ -220,26 +225,51 @@ class ServingStats:
             return self._tenants.pop(tenant, None) is not None
 
     def record_decode_step(self, kind: str, seconds: float, n_lanes: int,
-                           n_tokens: int):
-        """One decode-tier program call: ``kind`` is ``"prefill"``,
-        ``"decode"``, ``"draft"`` or ``"verify"``; ``n_tokens`` real
-        tokens were emitted by ``n_lanes`` real lanes (pad lanes
-        excluded — a draft call emits 0, its round's committed tokens
-        land on the verify call). Feeds the per-kind latency split and
-        tokens/sec."""
-        now = time.perf_counter()
+                           n_tokens: int, *, t_end: float, dispatch_s: float,
+                           read_wait_s: float, overlapped: bool = None,
+                           lanes_carried: int = 0):
+        """One decode-tier program call, recorded when its tokens were
+        read: ``kind`` is ``"prefill"``, ``"decode"``, ``"draft"`` or
+        ``"verify"``; ``seconds`` is the call's own time, from just before
+        its dispatch to its tokens on the host at ``t_end``
+        (``perf_counter``); ``n_tokens`` real tokens were emitted by
+        ``n_lanes`` real lanes (pad lanes excluded — a draft call emits
+        0, its round's committed tokens land on the verify call).
+        ``dispatch_s`` of it passed inside the program call until it
+        returned, ``read_wait_s`` waiting for the tokens: the scheduler's
+        thread did nothing else meanwhile, so over the window from the
+        first call's dispatch to the last one's read the two sums are
+        shares that add up to at most 1. ``overlapped`` says how the
+        call's tokens were read, and is left out where the read is not
+        one of its own (a speculation round counts one read, with its
+        verify call): the scheduler had dispatched the next call first
+        (the device went on working), else it waited the read out with
+        nothing queued behind it (nothing to dispatch, a drain, or a
+        speculation round); ``lanes_carried`` lanes of that next call
+        took their input token from the unread call's output on the
+        device. The share of reads that overlapped says how often the
+        host's round trip is hidden. Feeds the per-kind latency split,
+        tokens/sec, ``dispatch_share`` and ``read_wait_share``."""
         with self._lock:
             cell = self._decode
             cell[f"{kind}_steps"] += 1
-            cell[f"{kind}_s"] += float(seconds)
-            ring = cell[f"{kind}_ms"]
-            ring.append(float(seconds) * 1e3)
-            if len(ring) > self._max_samples:
-                del ring[: len(ring) - self._max_samples]
+            self._ring(cell[f"{kind}_ms"], float(seconds) * 1e3)
+            self._ring(cell[f"{kind}_dispatch_ms"], float(dispatch_s) * 1e3)
+            cell["dispatch_s"] += float(dispatch_s)
+            cell["read_wait_s"] += float(read_wait_s)
+            if overlapped is not None:
+                cell["reads_overlapped" if overlapped else "reads_flushed"] += 1
+                cell["lanes_carried"] += int(lanes_carried)
             cell["tokens"] += int(n_tokens)
             if cell["t_first"] is None:
-                cell["t_first"] = now - seconds
-            cell["t_last"] = now
+                cell["t_first"] = t_end - seconds
+            cell["t_last"] = t_end
+
+    def _ring(self, ring: list, value) -> None:
+        # one more sample in a bounded ring; caller holds the lock
+        ring.append(value)
+        if len(ring) > self._max_samples:
+            del ring[: len(ring) - self._max_samples]
 
     def record_sample_sort(self, calls: int):
         """``calls`` program calls of one step carried a lane with
@@ -259,20 +289,6 @@ class ServingStats:
         with self._lock:
             self._decode["pages_live"] += int(pages_live)
             self._decode["pages_table"] += int(pages_table)
-
-    def record_read(self, overlapped: bool, lanes_carried: int = 0):
-        """The decode scheduler read one call's tokens. ``overlapped``: it
-        had dispatched the next call first (the read was of the call
-        before, and the device went on working); else the beat waited the
-        read out with nothing queued behind it (nothing to dispatch, a
-        drain, or a speculation round, counted once a round).
-        ``lanes_carried`` lanes of that next call took their input token
-        from the unread call's output on the device. The share of reads
-        that overlapped says how often the host's round trip is hidden."""
-        with self._lock:
-            cell = self._decode
-            cell["reads_overlapped" if overlapped else "reads_flushed"] += 1
-            cell["lanes_carried"] += int(lanes_carried)
 
     def record_spec_round(self, proposed: int, accepted: int,
                           committed: int):
@@ -381,6 +397,8 @@ class ServingStats:
                   if cell["t_first"] is not None else 0.0)
         prefill = sorted(cell["prefill_ms"])
         decode = sorted(cell["decode_ms"])
+        dispatch = sorted(cell["decode_dispatch_ms"])
+        prefill_dispatch = sorted(cell["prefill_dispatch_ms"])
 
         def pct(vals, q):
             v = self._pct(vals, q)
@@ -406,6 +424,18 @@ class ServingStats:
             "prefill_p99_ms": pct(prefill, 0.99),
             "decode_p50_ms": pct(decode, 0.50),
             "decode_p99_ms": pct(decode, 0.99),
+            # a decode call's dispatch (until the program call returned),
+            # and a prefill call's
+            "dispatch_p50_ms": pct(dispatch, 0.50),
+            "dispatch_p99_ms": pct(dispatch, 0.99),
+            "prefill_dispatch_p50_ms": pct(prefill_dispatch, 0.50),
+            "prefill_dispatch_p99_ms": pct(prefill_dispatch, 0.99),
+            # of the scheduler thread's time: near 1 in dispatches, the
+            # host sets the pace; near 1 waiting in reads, the device does
+            "dispatch_share": (round(cell["dispatch_s"] / window, 4)
+                               if window > 0 else None),
+            "read_wait_share": (round(cell["read_wait_s"] / window, 4)
+                                if window > 0 else None),
             "tokens": cell["tokens"],
             "tokens_per_sec": (round(cell["tokens"] / window, 1)
                                if window > 0 else None),
@@ -435,6 +465,12 @@ class ServingStats:
                 verify_steps=cell["verify_steps"],
                 draft_p50_ms=pct(draft, 0.50),
                 verify_p50_ms=pct(verify, 0.50),
+                # of which inside the dispatch, as dispatch_p50_ms is of
+                # a decode call
+                draft_dispatch_p50_ms=pct(
+                    sorted(cell["draft_dispatch_ms"]), 0.50),
+                verify_dispatch_p50_ms=pct(
+                    sorted(cell["verify_dispatch_ms"]), 0.50),
             )
         return out
 

@@ -2339,3 +2339,13 @@ class DecodeEngine(EngineBase):
                     spec_enabled=self._scheduler.spec_enabled,
                 )
         return report
+
+
+def executable_name(programs: DecodePrograms, kind: str) -> str:
+    """The name the runtime gives the executable of ``programs``' program
+    ``kind`` (``jit_<function>``): what a device trace's ``XLA Modules``
+    line prints for each of its executions, less the parenthesis, so a
+    call's span can be tied to the device's executions of it. (Down here,
+    below every program body: a Pallas kernel's cache key holds the line
+    numbers of its callers in this file.)"""
+    return "jit_" + programs._jitted((kind,)).__name__

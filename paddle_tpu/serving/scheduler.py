@@ -26,6 +26,7 @@ from ..observability.tracing import _NULL_SPAN
 from .request_queue import Request, RequestQueue
 
 _TRACK = "serving.scheduler"     # the decode scheduler's beat spans
+_CALLS = "serving.calls"         # one span a program call, dispatch to read
 _REQUESTS = "serving.requests"   # request phases, sharing request=<id>
 
 
@@ -243,10 +244,21 @@ class _Call:
     call itself (``fn``, run under the fault point), and what its
     ``serving.decode`` span says of it. Dispatched, it holds its outputs,
     still on the device (``toks``, and ``extra``: what the program says of
-    its step beside the tokens)."""
+    its step beside the tokens).
+
+    Its stamps are always taken (``perf_counter``): ``t_dispatch`` just
+    before the program call, ``t_enqueued`` when it returned (the device
+    may still be running), ``t_read0`` and ``t_read`` around the wait for
+    its tokens on the host. ``seq`` counts the calls this scheduler has
+    enqueued on the device, in the order the device runs them, and
+    ``overlapped`` says that the next call went out before this one was
+    read. ``sent_by`` is the id of the ``serving.dispatch`` span that
+    sent it, the parent of its ``serving.call`` span; None for a call
+    dispatched with the tracer off."""
 
     __slots__ = ("kind", "rung", "lanes", "fn", "emits", "rows", "carried",
-                 "says", "beat", "toks", "extra")
+                 "says", "beat", "toks", "extra", "seq", "overlapped",
+                 "sent_by", "t_dispatch", "t_enqueued", "t_read0", "t_read")
 
     def __init__(self, kind: str, rung, lanes, fn, *, emits: bool = True,
                  rows: Optional[int] = None, carried: int = 0, **says):
@@ -261,6 +273,9 @@ class _Call:
         self.beat = None
         self.toks = None
         self.extra = ()
+        self.seq = self.sent_by = None
+        self.overlapped = False
+        self.t_dispatch = self.t_enqueued = self.t_read0 = self.t_read = None
 
 
 class DecodeScheduler:
@@ -332,6 +347,8 @@ class DecodeScheduler:
         self._owe_decode = False  # a prompt's chunk just ran: the lanes decode next
         self.shed_count = 0
         self._beat = 0            # running index of scheduler beats
+        self._seq = 0             # running index of calls enqueued on the device
+        self._sending = False     # inside a call's ``serving.dispatch`` span
         self._beat_kind = "idle"  # what this beat ran (set by the step)
         self._trace = None        # the tracer while this beat records, else None
         self._beat_id = None      # this beat's span id (parent of request phases)
@@ -405,7 +422,8 @@ class DecodeScheduler:
         ``serving.absorb`` (of that call). ``kind``, ``rung``, ``lanes``
         and the rest of ``serving.decode`` describe the call dispatched
         in it; a beat that only reads takes the kind of the call it
-        reads, with no lanes."""
+        reads, with no lanes. A call itself, which lives across two
+        beats, is one ``serving.call`` span (:meth:`_read`)."""
         from ..observability.tracing import tracer
 
         self._beat += 1
@@ -476,6 +494,16 @@ class DecodeScheduler:
             return _NULL_SPAN
         return self._trace.span(name, track=_TRACK, **args)
 
+    def _dispatch_part(self, name: str):
+        """A child of the open ``serving.dispatch`` span, for a piece of a
+        dispatch that is the engine's own code (what is left of the span
+        is the jitted call's argument handling and the runtime); the
+        no-op outside a dispatch, where the same code runs under
+        ``serving.build``."""
+        if not self._sending:
+            return _NULL_SPAN
+        return self._span("serving.dispatch." + name)
+
     def _step_span(self, kind: str, rung, lanes, **args):
         """The ``serving.decode`` span of one step. Its name and its
         ``kind``/``rung``/``lanes`` arguments are what the benchmark's
@@ -503,7 +531,6 @@ class DecodeScheduler:
         prev = self._flight
         if call is None and prev is None:
             return    # the build has to wait, and nothing is in flight
-        t0 = time.perf_counter()
         # a beat that only reads takes the kind of the call it reads
         kind, rung, lanes, says = ((call.kind, call.rung, call.lanes, call.says)
                                    if call is not None else (prev.kind, None, (), {}))
@@ -513,22 +540,32 @@ class DecodeScheduler:
                 if sp.id is not None:
                     call.says = sp.args   # as recorded: the read completes it
                 self._launch(call)
+            if prev is not None:
+                prev.overlapped = call is not None
             toks = self._read(prev)
             self._flight = call
-        if prev is not None and self.stats is not None:
-            self.stats.record_read(overlapped=call is not None,
-                                   lanes_carried=call.carried if call else 0)
-        self._absorb_traced(prev, toks, time.perf_counter() - t0)
+        self._absorb_traced(prev, toks)
 
     def _dispatch(self, call: Optional[_Call]) -> None:
         """``serving.dispatch``: the program call, until it returns (the
         device may still be running); then the pool commit, and the copy
         of its small outputs to the host started, so that the read a beat
-        later is a wait for nothing."""
-        with self._span("serving.dispatch", program=call and call.kind):
+        later is a wait for nothing. Its children are the engine's own
+        pieces of it: ``serving.dispatch.carry`` (:meth:`_fed`) and, from
+        the paged schedulers, ``serving.dispatch.sample_args``."""
+        with self._span("serving.dispatch", program=call and call.kind) as sp:
             if call is None:
                 return
-            out = self._program_call(call.fn)
+            call.sent_by = sp.id
+            self._sending = self._trace is not None
+            call.t_dispatch = time.perf_counter()
+            try:
+                out = self._program_call(call.fn)
+            finally:
+                self._sending = False
+            call.t_enqueued = time.perf_counter()
+        self._seq += 1
+        call.seq = self._seq
         held = len(self.pool.arrays())
         self.pool.commit(*out[:held])
         call.toks, *call.extra = out[held:]
@@ -562,16 +599,47 @@ class DecodeScheduler:
         nothing when the call after it went out first), and what the
         program says of its step beside them, which completes that call's
         own ``serving.decode`` span (the tracer keeps a span's arguments
-        by reference) and the programs' counters."""
+        by reference) and the programs' counters.
+
+        Traced, the call then gets its own span, ``serving.call`` on the
+        track ``serving.calls``, from its dispatch to its tokens on the
+        host, under the ``serving.dispatch`` span that sent it. One span
+        says all a reader needs of the call: ``seq`` (the device runs
+        calls in that order), ``beat`` and ``read_beat``, ``kind``,
+        ``rung``, ``lanes``, ``overlapped``, ``program`` (the
+        executable's name as a device trace prints it) and ``executions``
+        (program executions it enqueued: 2 when the token carry ran in
+        front), ``enqueue_ms`` and ``read_wait_ms``, and what its step's
+        span says of it after this read. A call dispatched before the
+        tracer came on has its span too, with no parent: its stamps are
+        always there, and a capture's first execution on the device is
+        that call's. A call the fault wall took never gets here and has
+        none."""
         with self._span("serving.read", program=call and call.kind,
                         of_beat=call and call.beat):
             if call is None:
                 return None
+            call.t_read0 = time.perf_counter()
             toks = np.asarray(call.toks)
+            call.t_read = time.perf_counter()
             extra = [np.asarray(a) for a in call.extra]
         call.toks, call.extra = None, ()
         if extra:
             call.says.update(self.programs.note_step(*extra, tokens=call.rows))
+        if self._trace is not None:
+            from .decode import executable_name
+
+            self._trace.emit(
+                "serving.call", call.t_dispatch, call.t_read - call.t_dispatch,
+                track=_CALLS, parent=call.sent_by,
+                **{**call.says, "seq": call.seq, "beat": call.beat,
+                   "read_beat": self._beat, "kind": call.kind,
+                   "rung": call.rung, "lanes": len(call.lanes),
+                   "overlapped": call.overlapped,
+                   "program": executable_name(self.programs, call.kind),
+                   "executions": 2 if call.carried else 1,
+                   "enqueue_ms": 1e3 * (call.t_enqueued - call.t_dispatch),
+                   "read_wait_ms": 1e3 * (call.t_read - call.t_read0)})
         return toks
 
     def _fed(self, tokens, carried: int):
@@ -581,7 +649,8 @@ class DecodeScheduler:
         call, so inside ``serving.dispatch`` and the fault point."""
         if not carried:
             return tokens
-        return self.programs.carry(self._flight.toks, tokens)
+        with self._dispatch_part("carry"):
+            return self.programs.carry(self._flight.toks, tokens)
 
     def _token_of(self, r) -> int:
         """What a decode call is told of a lane's input token: the token,
@@ -595,14 +664,13 @@ class DecodeScheduler:
         sequence's capacity."""
         return r.sent >= r.max_new_tokens or r.position >= self.max_seq
 
-    def _absorb_traced(self, call: Optional[_Call], toks,
-                       seconds: float) -> None:
+    def _absorb_traced(self, call: Optional[_Call], toks) -> None:
         """``serving.absorb`` of the call just read (none: an empty span,
         the beat's children still tile it)."""
         with self._span("serving.absorb", retired=0) as sp:
             if call is None:
                 return
-            retired = self._absorb(call, toks, seconds)
+            retired = self._absorb(call, toks)
             if sp.id is not None:
                 sp.args["retired"] = retired
 
@@ -833,15 +901,16 @@ class DecodeScheduler:
                          slots, positions),
                      carried=carried)
 
-    def _absorb(self, call: _Call, toks, seconds: float) -> int:
+    def _absorb(self, call: _Call, toks) -> int:
         """Scatter one call's emitted tokens back to their requests, one
         beat after it was dispatched: retire finished sequences (slot
         released, future resolved), the rest decode on (they may already
         ride the call after). A lane resolved since — retired at its eos a
         beat ago, shed, or failed with a later call — emits nothing.
-        Returns how many retired."""
+        The stats take the call's own times, dispatch to tokens on the
+        host, under its own kind. Returns how many retired."""
         self._step_lanes = []  # both calls got through: nothing to fail
-        now = time.perf_counter()  # the first-token stamp of new lanes
+        now = call.t_read  # the first-token stamp of new lanes
         lanes = [(i, r) for i, r in enumerate(call.lanes) if not r.done()]
         if self.breakers is not None:
             for tenant in {r.tenant for _, r in lanes}:
@@ -860,12 +929,25 @@ class DecodeScheduler:
                 retired += 1
         occupancy = self._absorbed(r for _, r in lanes)
         if self.stats is not None:
-            self.stats.record_decode_step(call.kind, seconds, len(call.lanes),
-                                          emitted)
+            # overlapped: the call in flight now went out before this read
+            self._record_call(call, emitted, overlapped=call.overlapped,
+                              lanes_carried=self._flight.carried
+                              if call.overlapped else 0)
             self.stats.record_slot_occupancy(*occupancy)
         if self.on_step is not None:
             self.on_step(call.kind, len(call.lanes), call.rung, emitted)
         return retired
+
+    def _record_call(self, call: _Call, emitted: int, **read) -> None:
+        """One read call in the stats, in one take of their lock: its own
+        dispatch-to-read time under its own kind, the parts of it in which
+        this thread was inside the dispatch and inside the read's wait,
+        and (``read``) how it was read."""
+        self.stats.record_decode_step(
+            call.kind, call.t_read - call.t_dispatch, len(call.lanes),
+            emitted, t_end=call.t_read,
+            dispatch_s=call.t_enqueued - call.t_dispatch,
+            read_wait_s=call.t_read - call.t_read0, **read)
 
     def _absorbed(self, lanes):
         """The residency's bookkeeping after an absorb of ``lanes``: the
@@ -1159,17 +1241,19 @@ class PagedDecodeScheduler(DecodeScheduler):
         may not have read the newest). Pad lanes carry temperature 0: a
         call whose every lane does runs no vocabulary sort, and a call
         with one sampling lane runs it for every lane, pads included
-        (``_choose_tokens``)."""
-        temps = np.zeros(b_rung, np.float32)
-        top_ks = np.zeros(b_rung, np.int32)
-        top_ps = np.ones(b_rung, np.float32)
-        rkeys = np.zeros((b_rung, 2), np.uint32)
-        for i, r in enumerate(lanes):
-            temps[i] = r.temperature
-            top_ks[i] = r.top_k
-            top_ps[i] = r.top_p
-            rkeys[i] = (np.uint32(r.seed & 0xFFFFFFFF), np.uint32(r.sent))
-        return temps, top_ks, top_ps, rkeys
+        (``_choose_tokens``). Built inside a program call it is the
+        ``serving.dispatch.sample_args`` part of the dispatch."""
+        with self._dispatch_part("sample_args"):
+            temps = np.zeros(b_rung, np.float32)
+            top_ks = np.zeros(b_rung, np.int32)
+            top_ps = np.ones(b_rung, np.float32)
+            rkeys = np.zeros((b_rung, 2), np.uint32)
+            for i, r in enumerate(lanes):
+                temps[i] = r.temperature
+                top_ks[i] = r.top_k
+                top_ps[i] = r.top_p
+                rkeys[i] = (np.uint32(r.seed & 0xFFFFFFFF), np.uint32(r.sent))
+            return temps, top_ks, top_ps, rkeys
 
     def _step_span(self, kind: str, rung, lanes, **args):
         """The base class's span plus ``sampling``, the lanes of the
@@ -1289,29 +1373,24 @@ class PagedDecodeScheduler(DecodeScheduler):
                 sp.args.update(lanes=len(lanes), rung=rung)
         sample = self._sample_args(lanes, rung[0])
         with self._step_span("speculate", rung, lanes, k=k, **pages):
-            t0 = time.perf_counter()
             draft = _Call("draft", rung, lanes, lambda: self.programs.draft(
                 self.pool.k, self.pool.v, tokens, tables, positions, *sample))
             self._dispatch(draft)
             drafts = self._read(draft)        # [b_rung, k] proposals
-            t_draft = time.perf_counter() - t0
             vin = np.zeros((len(tokens), k + 1), np.int32)
             vin[:, 0] = tokens                # last committed token at p
             vin[:, 1:] = drafts               # proposals at p+1..p+k
-            t1 = time.perf_counter()
             verify = _Call("verify", rung, lanes, lambda: self.programs.verify(
                 self.pool.k, self.pool.v, vin, tables, positions, *sample))
             self._dispatch(verify)
             vtoks = self._read(verify)        # [b_rung, k+1] true tokens
-            t_verify = time.perf_counter() - t1
         with self._span("serving.absorb", retired=0) as sp:
-            retired = self._absorb_spec(lanes, drafts, vtoks, t_draft=t_draft,
-                                        t_verify=t_verify, rung=rung)
+            retired = self._absorb_spec(draft, verify, drafts, vtoks)
             if sp.id is not None:
                 sp.args["retired"] = retired
 
-    def _absorb_spec(self, lanes, drafts, vtoks, *, t_draft: float,
-                     t_verify: float, rung) -> int:
+    def _absorb_spec(self, draft: _Call, verify: _Call, drafts,
+                     vtoks) -> int:
         """Acceptance + commit + rollback for one speculation round.
         Lane i's accepted prefix length m is the longest run of draft
         proposals the verify pass reproduced; verify tokens 0..m commit
@@ -1321,6 +1400,7 @@ class PagedDecodeScheduler(DecodeScheduler):
         position — grown for the speculative suffix — release back to
         the free-list: the rollback contract. Returns how many retired."""
         self._step_lanes = []  # the calls succeeded: nothing to fail
+        lanes = verify.lanes
         if self.breakers is not None:
             for tenant in {r.tenant for r in lanes}:
                 self.breakers.record_success(tenant)
@@ -1363,14 +1443,12 @@ class PagedDecodeScheduler(DecodeScheduler):
                 self._active[r.id] = r
         occupancy = self._absorbed(())
         if self.stats is not None:
-            self.stats.record_read(overlapped=False)
-            self.stats.record_decode_step("draft", t_draft, len(lanes), 0)
-            self.stats.record_decode_step("verify", t_verify, len(lanes),
-                                          committed)
+            self._record_call(draft, 0, overlapped=False)  # one flush a round
+            self._record_call(verify, committed)
             self.stats.record_spec_round(proposed, accepted, committed)
             self.stats.record_slot_occupancy(*occupancy)
         if self.on_step is not None:
-            self.on_step("speculate", len(lanes), rung, committed)
+            self.on_step("speculate", len(lanes), verify.rung, committed)
         return retired
 
     def _absorbed(self, lanes):

@@ -405,10 +405,11 @@ def test_the_counters_reach_the_metrics_page():
     stats = ServingStats()
     reg = MetricsRegistry()
     reg.register_collector("serving", stats.summary)
-    stats.record_decode_step("decode", 0.001, 3, 3)
-    for _ in range(3):
-        stats.record_read(True, lanes_carried=3)
-    stats.record_read(False)
+    took = dict(dispatch_s=0.0004, read_wait_s=0.0002)
+    for i in range(3):
+        stats.record_decode_step("decode", 0.001, 3, 3, t_end=1.0 + i, **took,
+                                 overlapped=True, lanes_carried=3)
+    stats.record_decode_step("decode", 0.001, 3, 3, t_end=4.0, **took, overlapped=False)
     lines = prometheus_text(reg.snapshot()).splitlines()
     assert "paddle_serving_decode_reads_overlapped 3" in lines
     assert "paddle_serving_decode_reads_flushed 1" in lines

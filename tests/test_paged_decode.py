@@ -836,7 +836,8 @@ class TestPagesLiveCounter:
         reg = MetricsRegistry()
         reg.register_collector("serving", stats.summary)
         assert "pages_live" not in prometheus_text(reg.snapshot())
-        stats.record_decode_step("decode", 0.001, 3, 3)
+        stats.record_decode_step("decode", 0.001, 3, 3, t_end=1.0,
+                                 dispatch_s=0.0004, read_wait_s=0.0002)
         stats.record_pages(5, 8)
         stats.record_pages(6, 8)
         lines = prometheus_text(reg.snapshot()).splitlines()

@@ -26,6 +26,16 @@ import paddle_tpu as P
 from paddle_tpu.ops import registry
 from paddle_tpu.ops.op_defs import OP_DEFS
 
+
+@pytest.fixture(scope="module", autouse=True)
+def _flags_as_found():
+    """The sweep runs `enable_check_model_nan_inf` as one more op, in
+    whatever order its cases come: leave FLAGS_check_nan_inf as it was,
+    or the next file on this worker raises on a legitimate -inf."""
+    was = P.get_flags(["check_nan_inf"])
+    yield
+    P.set_flags(was)
+
 sp = pytest.importorskip("scipy.special")
 
 RS = np.random.RandomState(1234)

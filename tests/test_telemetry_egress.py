@@ -838,6 +838,50 @@ class TestDeviceTraceFusion:
                if e.get("cat", "").startswith("device.")}
         assert dev == {"fusion.1": 1700.0, "copy.2": 1720.0}
 
+    def test_device_lanes_move_later_by_the_causality_bound(
+            self, tmp_path, fresh_tracer):
+        """The chip's clock lags the host's inside one capture: a run's
+        completion callback (host) cannot start before its program's end
+        (device), so the device's lanes move later by the least such gap
+        over the runs both sides name; the host's lanes stay."""
+        events = self._fake_events() + [
+            {"ph": "M", "name": "thread_name", "pid": 2, "tid": 20,
+             "args": {"name": "XLA Modules"}},
+            {"ph": "M", "name": "thread_name", "pid": 2, "tid": 21,
+             "args": {"name": "XLA Ops"}},
+            {"ph": "M", "name": "thread_name", "pid": 1, "tid": 12,
+             "args": {"name": "tpu-callbacks"}},
+            {"ph": "X", "name": "jit_step(1)", "pid": 2, "tid": 20,
+             "ts": 5100.0, "dur": 50.0, "args": {"run_id": 6}},
+            {"ph": "X", "name": "jit_step(1)", "pid": 2, "tid": 20,
+             "ts": 5200.0, "dur": 50.0, "args": {"run_id": "7"}},
+            {"ph": "X", "name": "fusion.9", "pid": 2, "tid": 21,
+             "ts": 5105.0, "dur": 40.0},
+            # run 6's callback 1,100 us after its program's end as the
+            # capture has it, run 7's 1,140: the device is 1,100 us early
+            {"ph": "X", "name": "CompleteCallbacks", "pid": 1, "tid": 12,
+             "ts": 6250.0, "dur": 5.0, "args": {"run_id": "6"}},
+            {"ph": "X", "name": "CompleteCallbacks", "pid": 1, "tid": 12,
+             "ts": 6390.0, "dur": 5.0, "args": {"run_id": 7}},
+            # a callback of a run the device lane does not name: no bound
+            {"ph": "X", "name": "CompleteCallbacks", "pid": 1, "tid": 12,
+             "ts": 5000.0, "dur": 5.0, "args": {"run_id": 99}},
+        ]
+        _write_fake_xla_trace(str(tmp_path), events)
+        fresh_tracer.ingest_device_trace_dir(str(tmp_path), 1007.0)
+        dev = {(e["name"], e["ts"])
+               for e in fresh_tracer.to_chrome_trace()["traceEvents"]
+               if e.get("cat", "").startswith("device.")}
+        shift, skew = 1007.0 - 5007.0, 1100.0
+        assert ("jit_step(1)", 5100.0 + shift + skew) in dev
+        assert ("jit_step(1)", 5200.0 + shift + skew) in dev
+        assert ("fusion.9", 5105.0 + shift + skew) in dev
+        # run 6's program now ends exactly where its callback starts
+        assert ("CompleteCallbacks", 6250.0 + shift) in dev
+        assert 5100.0 + 50.0 + skew == 6250.0
+        # a process without an XLA Modules lane keeps the sync shift alone
+        assert ("fusion.1", 1000.0) in dev and ("copy.2", 1020.0) in dev
+
     def test_capture_without_the_annotation_is_not_ingested(
             self, tmp_path, fresh_tracer):
         events = [e for e in self._fake_events()
